@@ -360,9 +360,9 @@ class BareExceptRule(Rule):
     """BARE-EXCEPT-SWALLOW: no `except Exception: pass` in the
     replication/apply paths.
 
-    probe_backend() caching failures forever (ADVICE.md round 5) and the
-    close-window zombie link (PR 2) both hid behind broad swallowed
-    excepts.  In replica/, server/, parallel/ and persist/, a bare /
+    A backend check that cached its failure forever (ADVICE.md round 5)
+    and the close-window zombie link (PR 2) both hid behind broad
+    swallowed excepts.  In replica/, server/, parallel/ and persist/, a bare /
     Exception / BaseException handler whose body is only `pass` is an
     error — narrow it to the exceptions the cleanup can actually raise,
     or at minimum log.  `__del__` is exempt (raising there is worse)."""
@@ -402,7 +402,7 @@ class BareExceptRule(Rule):
                         ctx, node, qual, "except-pass",
                         "broad except swallowing every error in a "
                         "replication/apply path hides real failures "
-                        "(the probe_backend / zombie-link bug class)")
+                        "(the zombie-link bug class)")
 
 
 class ForkCaptureRule(Rule):

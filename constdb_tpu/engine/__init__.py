@@ -3,13 +3,3 @@ from .cpu import CpuMergeEngine
 
 __all__ = ["ColumnarBatch", "MergeEngine", "MergeStats", "batch_from_keyspace", "CpuMergeEngine"]
 
-
-def default_engine():
-    """The engine used for bulk merges: batched JAX engine when available,
-    CPU reference engine otherwise."""
-    try:
-        from .tpu import TpuMergeEngine
-
-        return TpuMergeEngine()
-    except Exception:  # jax missing or device init failure
-        return CpuMergeEngine()

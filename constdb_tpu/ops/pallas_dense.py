@@ -29,6 +29,13 @@ is exact mod 2^64 (host sums are int64, so no real sum can wrap).
 the Pallas interpreter on CPU — that is how tests/test_pallas_dense.py
 differential-tests them against ops/dense.py, ops/bulk.py, and the host
 reference without TPU hardware.
+
+On the chip: the fold kernels and `tensor_reduce` compile through Mosaic
+for the v5e; `scatter_pair_src_split` and `segment_sum` do not — both
+walk (1, 1) blocks over (N, 1) column planes, one grid step per row,
+which the (8, 128) tile rule refuses and a lane-dense redesign would
+have to replace — so the engine selects their XLA twins there
+(engine/tpu.py AUTO_TPU_KERNELS) and they run in interpret mode only.
 """
 
 from __future__ import annotations
@@ -36,20 +43,19 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import numpy as np
 
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402
 from jax.experimental import pallas as pl  # noqa: E402
-
-try:  # TPU backends
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 BLOCK_S = 512
-_I32_MIN = jnp.iinfo(jnp.int32).min
+_I32_MIN = np.int32(np.iinfo(np.int32).min)
+# index-map literal: a bare Python 0 traces as int64 under x64, which
+# Mosaic does not take
+_Z = np.int32(0)
 
 
 def _split64(x):
@@ -62,15 +68,32 @@ def _join64(hi, lo):
     return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
 
 
-def _lex_mask(hi, lo, mask, lo_zero):
-    """Among rows where `mask`, the rows achieving the (hi, lo) lex max.
-    -> (new_mask, m_hi [S], m_lo [S])."""
+def _split64_ord(x):
+    """int64 -> (hi int32, lo int32 with its sign bit flipped): SIGNED
+    lex order on the pair == int64 order.  The fold kernels reduce along
+    sublanes, and Mosaic has no unsigned reduction ("Reductions over
+    unsigned integers not implemented"), so their lo halves travel
+    order-preserved as int32."""
+    hi, lo = _split64(x)
+    return hi, jax.lax.bitcast_convert_type(lo ^ jnp.uint32(1 << 31),
+                                            jnp.int32)
+
+
+def _join64_ord(hi, lo):
+    return _join64(hi, jax.lax.bitcast_convert_type(lo, jnp.uint32)
+                   ^ jnp.uint32(1 << 31))
+
+
+def _lex_mask(hi, lo, mask):
+    """Among rows where `mask`, the rows achieving the (hi, lo) lex max
+    (`_split64_ord` halves: both signed).
+    -> (new_mask, m_hi [1, S], m_lo [1, S])."""
     hi_c = jnp.where(mask, hi, _I32_MIN)
-    m_hi = jnp.max(hi_c, axis=0)
-    mask = mask & (hi == m_hi[None, :])
-    lo_c = jnp.where(mask, lo, lo_zero)
-    m_lo = jnp.max(lo_c, axis=0)
-    mask = mask & (lo == m_lo[None, :])
+    m_hi = jnp.max(hi_c, axis=0, keepdims=True)
+    mask = mask & (hi == m_hi)
+    lo_c = jnp.where(mask, lo, _I32_MIN)
+    m_lo = jnp.max(lo_c, axis=0, keepdims=True)
+    mask = mask & (lo == m_lo)
     return mask, m_hi, m_lo
 
 
@@ -79,26 +102,25 @@ def _elems_kernel(at_hi, at_lo, an_hi, an_lo, dt_hi, dt_lo,
                   o_win):
     R = at_hi.shape[0]
     full = jnp.ones(at_hi.shape, dtype=jnp.bool_)
-    zero_u = jnp.uint32(0)
 
     # 4-level lexicographic winner: (at_hi, at_lo, an_hi, an_lo)
-    m, ah, al = _lex_mask(at_hi[:], at_lo[:], full, zero_u)
-    m, nh, nl = _lex_mask(an_hi[:], an_lo[:], m, zero_u)
+    m, ah, al = _lex_mask(at_hi[...], at_lo[...], full)
+    m, nh, nl = _lex_mask(an_hi[...], an_lo[...], m)
 
     # first winning row (ties share identical (t, node) == the same write)
     rows = jax.lax.broadcasted_iota(jnp.int32, at_hi.shape, 0)
-    win = jnp.min(jnp.where(m, rows, R), axis=0)
+    win = jnp.min(jnp.where(m, rows, jnp.int32(R)), axis=0, keepdims=True)
 
     # del side: independent 2-level max
-    _, dh, dl = _lex_mask(dt_hi[:], dt_lo[:], full, zero_u)
+    _, dh, dl = _lex_mask(dt_hi[...], dt_lo[...], full)
 
-    o_at_hi[:] = ah[None, :]
-    o_at_lo[:] = al[None, :]
-    o_an_hi[:] = nh[None, :]
-    o_an_lo[:] = nl[None, :]
-    o_dt_hi[:] = dh[None, :]
-    o_dt_lo[:] = dl[None, :]
-    o_win[:] = win[None, :]
+    o_at_hi[...] = ah
+    o_at_lo[...] = al
+    o_an_hi[...] = nh
+    o_an_lo[...] = nl
+    o_dt_hi[...] = dh
+    o_dt_lo[...] = dl
+    o_win[...] = win
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -114,15 +136,13 @@ def merge_elems(at, an, dt, interpret: bool = False):
         if sp != S:
             x = jnp.concatenate(
                 [x, jnp.full((R, sp - S), fill, dtype=jnp.int64)], axis=1)
-        return _split64(x)
+        return _split64_ord(x)
 
     planes = [*prep(at, neutral), *prep(an, neutral), *prep(dt, 0)]
     grid = (sp // BLOCK_S,)
-    in_spec = pl.BlockSpec((R, BLOCK_S), lambda i: (0, i))
-    out_spec = pl.BlockSpec((1, BLOCK_S), lambda i: (0, i))
-    shapes = ([jax.ShapeDtypeStruct((1, sp), jnp.int32),
-               jax.ShapeDtypeStruct((1, sp), jnp.uint32)] * 3
-              + [jax.ShapeDtypeStruct((1, sp), jnp.int32)])
+    in_spec = pl.BlockSpec((R, BLOCK_S), lambda i: (_Z, i))
+    out_spec = pl.BlockSpec((1, BLOCK_S), lambda i: (_Z, i))
+    shapes = [jax.ShapeDtypeStruct((1, sp), jnp.int32)] * 7
     out = pl.pallas_call(
         _elems_kernel,
         grid=grid,
@@ -132,20 +152,19 @@ def merge_elems(at, an, dt, interpret: bool = False):
         interpret=interpret,
     )(*planes)
     ah, al, nh, nl, dh, dl, win = (o[0] for o in out)
-    return (_join64(ah, al)[:S], _join64(nh, nl)[:S],
-            _join64(dh, dl)[:S], win.astype(jnp.int64)[:S])
+    return (_join64_ord(ah, al)[:S], _join64_ord(nh, nl)[:S],
+            _join64_ord(dh, dl)[:S], win.astype(jnp.int64)[:S])
 
 
 def _counters_kernel(v_hi, v_lo, t_hi, t_lo, o_v_hi, o_v_lo, o_t_hi, o_t_lo):
     full = jnp.ones(v_hi.shape, dtype=jnp.bool_)
-    zero_u = jnp.uint32(0)
     # (uuid, value) lexicographic max == LWW with max-value tie-break
-    m, th, tl = _lex_mask(t_hi[:], t_lo[:], full, zero_u)
-    _, vh, vl = _lex_mask(v_hi[:], v_lo[:], m, zero_u)
-    o_v_hi[:] = vh[None, :]
-    o_v_lo[:] = vl[None, :]
-    o_t_hi[:] = th[None, :]
-    o_t_lo[:] = tl[None, :]
+    m, th, tl = _lex_mask(t_hi[...], t_lo[...], full)
+    _, vh, vl = _lex_mask(v_hi[...], v_lo[...], m)
+    o_v_hi[...] = vh
+    o_v_lo[...] = vl
+    o_t_hi[...] = th
+    o_t_lo[...] = tl
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -160,13 +179,12 @@ def merge_counters(vals, ts, interpret: bool = False):
         if sp != S:
             x = jnp.concatenate(
                 [x, jnp.full((R, sp - S), fill, dtype=jnp.int64)], axis=1)
-        return _split64(x)
+        return _split64_ord(x)
 
     planes = [*prep(vals, neutral), *prep(ts, neutral)]
-    in_spec = pl.BlockSpec((R, BLOCK_S), lambda i: (0, i))
-    out_spec = pl.BlockSpec((1, BLOCK_S), lambda i: (0, i))
-    shapes = [jax.ShapeDtypeStruct((1, sp), jnp.int32),
-              jax.ShapeDtypeStruct((1, sp), jnp.uint32)] * 2
+    in_spec = pl.BlockSpec((R, BLOCK_S), lambda i: (_Z, i))
+    out_spec = pl.BlockSpec((1, BLOCK_S), lambda i: (_Z, i))
+    shapes = [jax.ShapeDtypeStruct((1, sp), jnp.int32)] * 4
     out = pl.pallas_call(
         _counters_kernel,
         grid=(sp // BLOCK_S,),
@@ -176,7 +194,7 @@ def merge_counters(vals, ts, interpret: bool = False):
         interpret=interpret,
     )(*planes)
     vh, vl, th, tl = (o[0] for o in out)
-    return _join64(vh, vl)[:S], _join64(th, tl)[:S]
+    return _join64_ord(vh, vl)[:S], _join64_ord(th, tl)[:S]
 
 
 # ------------------------------------------------------- resident scatter
@@ -338,42 +356,42 @@ def scatter_pair_src(p, s, src, idx, bp, bs, base, interpret: bool = False):
 TENSOR_BLOCK = 512
 
 
-def _tensor_reduce_kernel(mat, cnts, div, out, *, strat: int, n: int):
+def _tensor_reduce_kernel(div, mat, out, *, strat: int, n: int):
     # avg never reaches the kernel: its multiply-add chain would FMA-
     # contract (no intermediate rounding — diverging from the host's
     # rounded products), so it composes as scale → STRAT_SUM → divide
     # across dispatch boundaries (ops/dense.py tensor_scale docstring).
-    # `div` is the trimmed divisor as a RUNTIME operand — a constant
-    # divisor gets strength-reduced to a reciprocal multiply, which
-    # rounds differently from the host's true division.
+    # `div` is the trimmed divisor as a RUNTIME operand (an SMEM scalar)
+    # — a constant divisor gets strength-reduced to a reciprocal
+    # multiply, which rounds differently from the host's true division.
     from ..crdt.tensor import STRAT_MAXMAG, STRAT_SUM, STRAT_TRIMMED
-    del cnts
     if strat == STRAT_SUM:
-        acc = mat[0, 0, :]
+        acc = mat[0, 0:1, :]
         for i in range(1, n):
-            acc = acc + mat[0, i, :]
+            acc = acc + mat[0, i:i + 1, :]
     elif strat == STRAT_MAXMAG:
-        acc = mat[0, 0, :]
+        acc = mat[0, 0:1, :]
         for i in range(1, n):
-            acc = jnp.where(jnp.abs(mat[0, i, :]) > jnp.abs(acc),
-                            mat[0, i, :], acc)
+            row = mat[0, i:i + 1, :]
+            acc = jnp.where(jnp.abs(row) > jnp.abs(acc), row, acc)
     elif strat == STRAT_TRIMMED and n <= 2:
-        acc = mat[0, 0, :]
+        acc = mat[0, 0:1, :]
         for i in range(1, n):
-            acc = acc + mat[0, i, :]
-        acc = acc / div[0, 0]
+            acc = acc + mat[0, i:i + 1, :]
+        acc = acc / div[0]
     elif strat == STRAT_TRIMMED:
-        s = mat[0, 0, :]
-        mn = mat[0, 0, :]
-        mx = mat[0, 0, :]
+        s = mat[0, 0:1, :]
+        mn = s
+        mx = s
         for i in range(1, n):
-            s = s + mat[0, i, :]
-            mn = jnp.minimum(mn, mat[0, i, :])
-            mx = jnp.maximum(mx, mat[0, i, :])
-        acc = (s - mn - mx) / div[0, 0]
+            row = mat[0, i:i + 1, :]
+            s = s + row
+            mn = jnp.minimum(mn, row)
+            mx = jnp.maximum(mx, row)
+        acc = (s - mn - mx) / div[0]
     else:
         raise ValueError(f"tensor_reduce kernel: strategy {strat}")
-    out[0, :] = acc
+    out[0] = acc
 
 
 @partial(jax.jit, static_argnames=("strat", "n", "interpret"))
@@ -381,28 +399,36 @@ def tensor_reduce(mat, cnts, div, *, strat: int, n: int,
                   interpret: bool = False):
     """[G, n, Kp] f32 contributor stacks (canonical (node, uuid) row
     order, Kp a TENSOR_BLOCK multiple) -> [G, Kp] strategy reduction;
-    `cnts` [G, n] f32; `div` the trimmed divisor as a runtime f32
-    scalar.  Bit-identical to ops/dense.py tensor_reduce and
+    `cnts` [G, n] f32 (counts only weight avg, which composes outside —
+    accepted for signature parity with the XLA twin, never shipped to
+    the kernel); `div` the trimmed divisor as a runtime f32 scalar.
+    Bit-identical to ops/dense.py tensor_reduce and
     crdt.tensor.reduce_rows."""
+    del cnts
     G, n_, Kp = mat.shape
     assert n_ == n and Kp % TENSOR_BLOCK == 0
     assert mat.dtype == jnp.float32, "pallas tensor_reduce is f32-only"
-    grid = (G, Kp // TENSOR_BLOCK)
-    return pl.pallas_call(
+    # the [G, Kp] result travels as [G, 1, Kp]: a (1, BLOCK) block over
+    # a [G, Kp] array breaks the (8, 128) tile rule unless G == 1
+    out = pl.pallas_call(
         partial(_tensor_reduce_kernel, strat=strat, n=n),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, n, TENSOR_BLOCK), lambda g, k: (g, 0, k)),
-                  pl.BlockSpec((1, n), lambda g, k: (g, 0)),
-                  pl.BlockSpec((1, 1), lambda g, k: (0, 0))],
-        out_specs=pl.BlockSpec((1, TENSOR_BLOCK), lambda g, k: (g, k)),
-        out_shape=jax.ShapeDtypeStruct((G, Kp), jnp.float32),
+        grid=(G, Kp // TENSOR_BLOCK),
+        in_specs=[pl.BlockSpec((1,), lambda g, k: (_Z,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, n, TENSOR_BLOCK),
+                               lambda g, k: (g, _Z, k))],
+        out_specs=pl.BlockSpec((1, 1, TENSOR_BLOCK),
+                               lambda g, k: (g, _Z, k)),
+        out_shape=jax.ShapeDtypeStruct((G, 1, Kp), jnp.float32),
         interpret=interpret,
-    )(mat, cnts, jnp.reshape(div, (1, 1)))
+    )(jnp.reshape(div, (1,)), mat)
+    return out[:, 0, :]
 
 
-# per-key counter-sum scratch cap: two (1, n_seg) int32 planes must fit
-# VMEM alongside the blocks — 2^20 segments = 8 MB, a safe ceiling; the
-# engine routes larger keyspaces onto the XLA twin (ops/dense.py)
+# per-key counter-sum scratch cap: two (1, n_seg) 32-bit planes.  Never
+# sized against a real VMEM (a (1, N) plane pads to 8 sublanes there);
+# the kernel runs interpreted only, and the engine routes larger
+# keyspaces onto the XLA twin (ops/dense.py)
 SEGMENT_SUM_MAX_SEG = 1 << 20
 
 
@@ -415,13 +441,12 @@ def _segment_sum_kernel(ids_ref, v_hi, v_lo, o_hi, o_lo, acc_hi, acc_lo):
         acc_hi[...] = jnp.zeros_like(acc_hi)
         acc_lo[...] = jnp.zeros_like(acc_lo)
 
-    s = ids_ref[i]
-    sl = (pl.dslice(jnp.int32(0), 1), pl.dslice(s, 1))
-    cur_lo = pl.load(acc_lo, sl)
+    sl = (slice(None), pl.ds(ids_ref[i], 1))
+    cur_lo = acc_lo[sl]
     new_lo = cur_lo + v_lo[0, 0]          # uint32: wraps mod 2^32
     carry = (new_lo < cur_lo).astype(jnp.int32)
-    pl.store(acc_lo, sl, new_lo)
-    pl.store(acc_hi, sl, pl.load(acc_hi, sl) + v_hi[0, 0] + carry)
+    acc_lo[sl] = new_lo
+    acc_hi[sl] = acc_hi[sl] + v_hi[0, 0] + carry
 
     @pl.when(i == n - 1)
     def _emit():

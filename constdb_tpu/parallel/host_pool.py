@@ -8,8 +8,12 @@ counter ranks, and set members are independent across keys (per-key CRDT
 merges commute), so the host side shards embarrassingly by key hash.
 
 This module runs N shard WORKERS, each a separate process owning one
-`KeySpace` + `MergeEngine` pair, so staging, native-table assigns, and
-flush apply all scale with cores instead of fighting the GIL:
+`KeySpace` + `CpuMergeEngine` pair, so staging, native-table assigns, and
+flush apply all scale with cores instead of fighting the GIL.  Workers
+build the CPU engine and nothing else: a chip belongs to ONE process, so
+a child that initialized a device backend while its parent held the chip
+would fail or hang.  A node whose engine is the device engine ingests
+in-process (server/io.py snapshot_ingest_shards).
 
   * workers come from a **forkserver** context: they are forked from a
     clean helper process, never from the (possibly JAX-threaded) parent —
@@ -46,34 +50,13 @@ def _attach_shm(name: str):
     return shared_memory.SharedMemory(name=name)
 
 
-def _make_engine(spec: str):
-    """Engine factory by spec string (must stay import-lazy: "cpu"
-    workers never pay a JAX import).  CONSTDB_SHARD_FOLD carries the
-    dense-fold strategy across the process boundary (workers can't take
-    a closure), so e.g. bench.py's CONSTDB_BENCH_FOLD stays honored
-    under --shards instead of silently reverting to "auto"."""
-    if spec == "cpu":
-        from ..engine.cpu import CpuMergeEngine
-        return CpuMergeEngine()
-    from ..conf import env_str
-    fold = env_str("CONSTDB_SHARD_FOLD", "auto")
-    if spec in ("tpu", "tpu-resident"):
-        from ..engine.tpu import TpuMergeEngine
-        return TpuMergeEngine(resident=True, dense_fold=fold)
-    if spec == "tpu-nonresident":
-        from ..engine.tpu import TpuMergeEngine
-        return TpuMergeEngine(resident=False, dense_fold=fold)
-    raise ValueError(f"unknown shard engine spec {spec!r}")
-
-
-def _worker_main(conn, shard: int, n_shards: int, engine_spec: str,
-                 env: dict) -> None:
-    """Shard worker loop: one KeySpace + one lazily-built MergeEngine."""
-    # env BEFORE any jax import: the parent's platform pins (JAX_PLATFORMS
-    # etc.) were captured at pool creation, which may post-date the
-    # forkserver's inherited environment
+def _worker_main(conn, shard: int, n_shards: int, env: dict) -> None:
+    """Shard worker loop: one KeySpace + one lazily-built CpuMergeEngine."""
+    # the parent's CONSTDB_* settings were captured at pool creation,
+    # which may post-date the forkserver's inherited environment
     os.environ.update(env)
     from ..engine.base import batch_from_keyspace
+    from ..engine.cpu import CpuMergeEngine
     from ..persist.snapshot import (_decode_batch, _encode_batch,
                                     _read_bytes_list)
     from ..store.keyspace import KeySpace
@@ -88,7 +71,7 @@ def _worker_main(conn, shard: int, n_shards: int, engine_spec: str,
     def ensure_engine():
         nonlocal engine
         if engine is None:
-            engine = _make_engine(engine_spec)
+            engine = CpuMergeEngine()
         return engine
 
     def flushed_store():
@@ -223,7 +206,7 @@ def _worker_main(conn, shard: int, n_shards: int, engine_spec: str,
     conn.close()
 
 
-_ENV_PREFIXES = ("JAX_", "XLA_", "CONSTDB_", "PALLAS_", "TPU_")
+_ENV_PREFIXES = ("JAX_", "XLA_", "CONSTDB_", "TPU_")
 
 
 def _capture_env() -> dict:
@@ -242,15 +225,14 @@ class HostShardPool:
     completions as they land instead of barriering per group.
     """
 
-    def __init__(self, n_shards: int, engine_spec: str = "tpu",
-                 max_inflight: int = 2, env: Optional[dict] = None,
+    def __init__(self, n_shards: int, max_inflight: int = 2,
+                 env: Optional[dict] = None,
                  start_method: str = "forkserver"):
         import multiprocessing as mp
 
         if n_shards < 1:
             raise ValueError("n_shards must be >= 1")
         self.n_shards = n_shards
-        self.engine_spec = engine_spec
         self.max_inflight = max(1, max_inflight)
         wenv = _capture_env()
         if env:
@@ -264,7 +246,7 @@ class HostShardPool:
         for s in range(n_shards):
             parent, child = ctx.Pipe()
             p = ctx.Process(target=_worker_main,
-                            args=(child, s, n_shards, engine_spec, wenv),
+                            args=(child, s, n_shards, wenv),
                             daemon=True)
             p.start()
             child.close()

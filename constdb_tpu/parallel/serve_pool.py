@@ -42,7 +42,7 @@ import traceback
 from collections import deque
 from typing import Optional
 
-from .host_pool import _capture_env, _make_engine
+from .host_pool import _capture_env
 
 
 class _TapLog:
@@ -107,7 +107,7 @@ def _worker_stats(node) -> dict:
     }
 
 
-def _serve_worker_main(conn, shard: int, n_shards: int, engine_spec: str,
+def _serve_worker_main(conn, shard: int, n_shards: int,
                        env: dict, node_id: int, alias: str,
                        serve_batch: int, maxmemory=None,
                        maxmemory_soft_pct=None) -> None:
@@ -126,8 +126,9 @@ def _serve_worker_main(conn, shard: int, n_shards: int, engine_spec: str,
     from ..server.serve import ServeCoalescer
     from ..store.sharded_keyspace import keyspace_state_bytes
 
-    node = Node(node_id=node_id, alias=alias,
-                engine=_make_engine(engine_spec))
+    # Node's default engine, the CPU engine, like every worker process:
+    # a chip belongs to ONE process (parallel/host_pool.py)
+    node = Node(node_id=node_id, alias=alias)
     if maxmemory is not None or maxmemory_soft_pct is not None:
         # each worker governs its slice of the node cap (the plane
         # passed maxmemory // n_shards): the keys are hash-partitioned,
@@ -287,7 +288,7 @@ class ServeShardPool:
     serialization point exactly like the single event loop was — for
     its shard only."""
 
-    def __init__(self, n_shards: int, engine_spec: str = "cpu",
+    def __init__(self, n_shards: int,
                  node_id: int = 0, alias: str = "", serve_batch: int = 512,
                  env: Optional[dict] = None,
                  start_method: str = "forkserver",
@@ -313,7 +314,7 @@ class ServeShardPool:
         for s in range(n_shards):
             parent, child = ctx.Pipe()
             p = ctx.Process(target=_serve_worker_main,
-                            args=(child, s, n_shards, engine_spec, wenv,
+                            args=(child, s, n_shards, wenv,
                                   node_id, alias, serve_batch,
                                   maxmemory, maxmemory_soft_pct),
                             daemon=True)

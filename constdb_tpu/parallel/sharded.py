@@ -26,15 +26,10 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax import lax  # noqa: E402
+from jax import lax, shard_map  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 from ..ops.segment import NEUTRAL_T  # noqa: E402
-
-try:  # jax >= 0.8: top-level function
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def make_mesh(n_devices: Optional[int] = None, rep: int = 1) -> Mesh:

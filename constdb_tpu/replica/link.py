@@ -1878,11 +1878,8 @@ class ReplicaLink:
         from ..store.sharded_keyspace import ShardedKeySpace
         node = self.node
         loop = asyncio.get_running_loop()
-        from ..conf import env_str
-        spec = env_str("CONSTDB_SHARD_ENGINE") or \
-            ("tpu" if getattr(node.engine, "name", "") == "tpu" else "cpu")
         sks = ShardedKeySpace(n_shards=shards, mode="process",
-                              engine_spec=spec,
+                              engine_spec="cpu",
                               group=max(1, self.app.sync_merge_group))
         x = node.stats.extra
         x["sharded_ingests"] = x.get("sharded_ingests", 0) + 1

@@ -239,12 +239,21 @@ def _section_stats(node, out):
     if v is not None:
         out.append(("tns_pool_bytes", v))
     out.append(("engine", node.engine.name))
-    degraded = getattr(node.engine, "degraded", None)
-    if degraded:
-        # conf.build_engine fell back from a requested accelerator — make
-        # the orders-of-magnitude merge slowdown visible to operators, not
-        # just a boot-log line (advisor round-4 finding)
-        out.append(("engine_degraded", degraded))
+    # what the engine actually runs on, as JAX reports it — never
+    # inferred from the engine's name ("none": the engine never
+    # touches JAX)
+    info = getattr(node.engine, "device_info", None)
+    platform, kind, count = info() if info else ("none", "none", 0)
+    out.append(("jax_backend", platform))
+    out.append(("device_kind", kind))
+    out.append(("device_count", count))
+    from ..conf import COMPILE_CACHE
+    if COMPILE_CACHE["dir"]:
+        # conf.enable_compile_cache: where this process keeps compiled
+        # programs, and how many compiles it loaded vs made
+        out.append(("compile_cache_dir", COMPILE_CACHE["dir"]))
+        out.append(("compile_cache_hits", COMPILE_CACHE["hits"]))
+        out.append(("compile_cache_misses", COMPILE_CACHE["misses"]))
     out.append(("gc_freed", st.gc_freed))
     for k, v in sorted(st.extra.items()):
         out.append((k, v))

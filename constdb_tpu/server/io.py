@@ -318,8 +318,12 @@ class ServerApp:
 
     def snapshot_ingest_shards(self, size: int) -> int:
         """How many hash shards a downloaded snapshot of `size` bytes
-        should fan out over (1 = plain single-keyspace path)."""
-        if size < self.ingest_shard_min_bytes:
+        should fan out over (1 = plain single-keyspace path).  Shard
+        workers are CPU-engine processes (parallel/host_pool.py), so
+        only a CPU-engine node fans out: a node whose engine batches on
+        a device ingests in-process, through that engine."""
+        if size < self.ingest_shard_min_bytes or \
+                self.node.engine.name != "cpu":
             return 1
         n = self.ingest_shards
         if n == 0:
@@ -340,11 +344,8 @@ class ServerApp:
         if self.serve_shards > 1:
             # spawn the shard workers BEFORE the listener opens (they
             # need the final node_id — workers stamp it into writes)
-            from ..conf import env_str
             from .serve_shards import ServeShardPlane
-            spec = env_str("CONSTDB_SHARD_ENGINE") or "cpu"
-            self.serve_plane = ServeShardPlane(self, self.serve_shards,
-                                               engine_spec=spec)
+            self.serve_plane = ServeShardPlane(self, self.serve_shards)
             await self.serve_plane.start()
         # bind (resolving an ephemeral port — advertised_addr is live
         # from here) but do NOT accept yet: the boot restore below must

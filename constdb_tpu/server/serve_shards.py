@@ -78,13 +78,12 @@ class ServeShardPlane:
     """Parent-side router + authority for a shard-per-core serving node
     (see module docstring)."""
 
-    def __init__(self, app, n_shards: int, engine_spec: str = "cpu"):
+    def __init__(self, app, n_shards: int):
         if not 2 <= n_shards <= MAX_SHARDS:
             raise ValueError(f"serve_shards must be in [2, {MAX_SHARDS}]")
         self.app = app
         self.node = app.node
         self.n_shards = n_shards
-        self.engine_spec = engine_spec
         self.pool = None
         self.merged = MergedReplLog(n_shards,
                                     cap_bytes=self.node.repl_log.cap)
@@ -119,7 +118,6 @@ class ServeShardPlane:
         node = self.node
         gov = node.governor
         self.pool = ServeShardPool(self.n_shards,
-                                   engine_spec=self.engine_spec,
                                    node_id=node.node_id, alias=node.alias,
                                    serve_batch=self.app.serve_batch,
                                    # each worker governs its slice of
@@ -134,8 +132,8 @@ class ServeShardPlane:
         x["serve_shards"] = self.n_shards
         x["serve_shard_map"] = f"crc32(key)%{self.n_shards}"
         x.setdefault("serve_xshard_barriers", 0)
-        log.info("serve plane up: %d shard workers (engine=%s)",
-                 self.n_shards, self.engine_spec)
+        log.info("serve plane up: %d shard workers (engine=cpu)",
+                 self.n_shards)
 
     async def close(self) -> None:
         if self.pool is not None:

@@ -26,6 +26,9 @@ Layout:
         engine/tpu.py's ShardDispatcher (one device queue, interleaved).
       - "process": N worker processes (parallel/host_pool.py) — the whole
         host critical path scales with cores instead of fighting the GIL.
+        CPU engines only (engine_spec="cpu"): a chip belongs to one
+        process, so a device engine shards in-process ("local") or not
+        at all; asking for anything else is an error, not a hang.
 
 Ingest cadence: `submit(batch)` buffers `group` chunks, then ships the
 group — process mode broadcasts ONE shared-memory segment to every worker
@@ -237,9 +240,14 @@ class ShardedKeySpace:
             self._engine = engine_factory() if engine_factory is not None \
                 else self._default_engine()
         elif self.mode == "process":
+            if engine_spec != "cpu" or engine_factory is not None:
+                raise ValueError(
+                    "process-mode shard workers build the CPU engine only "
+                    f"(engine_spec={engine_spec!r}): a chip belongs to one "
+                    "process — pass engine_spec=\"cpu\", or mode=\"local\" "
+                    "to shard a device engine in-process")
             from ..parallel.host_pool import HostShardPool
             self.pool = HostShardPool(self.n_shards,
-                                      engine_spec=engine_spec,
                                       max_inflight=max_inflight, env=env)
         elif self.mode == "local":
             from ..engine.tpu import ShardDispatcher

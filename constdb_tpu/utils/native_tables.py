@@ -81,8 +81,9 @@ def load_ext():
     the pure-Python tiers (A/B floor measurement — opbench.py).  A .so
     whose compiled-in ABI stamp does not match the native/*.cpp sources
     on disk is refused LOUDLY (stale build: its row layouts may disagree
-    with what serve.py expects) — rebuild with `make -C native`, or let
-    bench.py's ensure_native (CONSTDB_AUTO_NATIVE, default on) do it."""
+    with what serve.py expects) — rebuild with `make -C native`
+    (`build_native` below does, for bench.py, the tests and the chip
+    smoke)."""
     global _ext
     from ..conf import env_str
     if env_str("CONSTDB_NO_NATIVE"):
@@ -107,9 +108,7 @@ def load_ext():
                 import logging
                 logging.getLogger("constdb.native").warning(
                     "stale cst_ext.so at %s (abi stamp %s != sources %s): "
-                    "refusing to load it — rebuild with `make -C native` "
-                    "(bench.py ensure_native rebuilds automatically unless "
-                    "CONSTDB_AUTO_NATIVE=0)",
+                    "refusing to load it — rebuild with `make -C native`",
                     cand, (got or "<unstamped>")[:12], want[:12])
                 continue
             _ext = mod
@@ -120,13 +119,39 @@ def load_ext():
 
 def reload_tiers() -> bool:
     """Forget the (possibly negative) loader caches and retry — the public
-    hook for callers that build the native artifacts at runtime (bench.py
-    ensure_native).  Returns True when the CPython extension loads."""
+    hook for callers that build the native artifacts at runtime
+    (build_native).  Returns True when the CPython extension loads."""
     global _ext, _lib
     _ext = None
     _lib = None
     _ABI_STAMP_CACHE.clear()
     return load_ext() is not None
+
+
+def build_native(timeout: float = 600.0) -> None:
+    """Build the native artifacts from the tracked sources (`make -C
+    native`; the .so files are gitignored) unless the extension already
+    loads, then reload the tiers.  Raises RuntimeError when the build
+    fails or its product does not load — callers decide whether that
+    is fatal (chip_smoke.py) or a loud degrade to the pure tiers
+    (bench.py ensure_native, tests/conftest.py)."""
+    if load_ext() is not None:
+        return
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = os.path.join(os.path.dirname(here), "native")
+    try:
+        r = subprocess.run(["make", "-C", src, f"PYTHON={sys.executable}"],
+                           capture_output=True, timeout=timeout, text=True)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(f"native build did not run: {e}") from e
+    if r.returncode != 0:
+        raise RuntimeError(f"native build failed rc={r.returncode}:\n"
+                           f"{(r.stderr or r.stdout)[-2000:]}")
+    if not reload_tiers():
+        raise RuntimeError("native build succeeded but cst_ext.so does "
+                           "not load (see the constdb.native log)")
 
 
 def load_native() -> Optional[ctypes.CDLL]:

@@ -32,7 +32,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from bench import _uuids, chunk_batches, time_engine, verify_store  # noqa: E402
+from bench import (_uuids, chunk_batches, require_device,  # noqa: E402
+                   time_engine, verify_store)
 from constdb_tpu.crdt import semantics as S  # noqa: E402
 from constdb_tpu.engine.base import ColumnarBatch  # noqa: E402
 from constdb_tpu.engine.cpu import CpuMergeEngine  # noqa: E402
@@ -167,24 +168,9 @@ def main() -> None:
     ap.add_argument("--chunk", type=int, default=1 << 17)
     ns = ap.parse_args()
 
-    from constdb_tpu.utils.backend import force_cpu_platform, probe_backend
-    probe = probe_backend()
-    if not probe.ok:
-        print(f"[ladder] WARNING: no device backend ({probe.error}); "
-              "XLA-on-CPU", file=sys.stderr)
-        force_cpu_platform()
+    # every rung is a device leg: no accelerator, no ladder
+    _, device = require_device()
     from constdb_tpu.engine.tpu import TpuMergeEngine
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ.get("CONSTDB_JAX_CACHE",
-                                         "/tmp/constdb_jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:
-        pass
-    backend = jax.default_backend()
-    print(f"[ladder] backend: {backend} devices={jax.devices()}",
-          file=sys.stderr)
 
     results = []
     for name, gen, n_keys, n_rep in CONFIGS:
@@ -209,7 +195,7 @@ def main() -> None:
                "device_wall_s": round(dev_t, 2),
                "speedup": round(dev_rate / cpu_rate, 2),
                "verified": ok, "verify_keys": n_checked,
-               "backend": backend}
+               "device": device}
         results.append(row)
         print(f"[ladder] {name}: cpu {cpu_rate:,.0f} k/s (at {n_cpu}), "
               f"device {dev_rate:,.0f} k/s ({dev_t:.2f}s), "
@@ -220,7 +206,7 @@ def main() -> None:
                               "results": results}))
             sys.exit(1)
 
-    out = {"metric": "family_ladder_keys_per_sec", "backend": backend,
+    out = {"metric": "family_ladder_keys_per_sec", "device": device,
            "results": results}
     print(json.dumps(out))
     if ns.out:

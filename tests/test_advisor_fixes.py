@@ -1,7 +1,7 @@
 """Regression tests for the round-2 advisor findings (ADVICE.md).
 
-Each test pins one fixed behavior: engine fallback instead of boot
-failure, snapshot-dump invalidation on bulk ingest, redis LPUSH order,
+Each test pins one fixed behavior: engine='auto' boots on any host,
+snapshot-dump invalidation on bulk ingest, redis LPUSH order,
 the RESP fast-path bulk cap, and the structured FORGOTTEN error code.
 """
 
@@ -24,18 +24,13 @@ def _cmd(node, *parts):
 # --------------------------------------------------------------- 1: engine
 
 
-def test_engine_tpu_falls_back_instead_of_raising(monkeypatch, caplog):
-    """engine='tpu' on a backend-less host degrades to a working engine
-    with a warning — a node must boot and serve either way."""
+def test_engine_auto_is_the_portable_default():
+    """engine='auto' on a host with no accelerator builds the pure-CPU
+    engine — a node boots and serves on any host, under its own name."""
     import constdb_tpu.conf as conf
-    from constdb_tpu.utils import backend as bk
 
-    monkeypatch.setattr(
-        bk, "probe_backend",
-        lambda timeout=90.0: bk.BackendProbe(False,
-                                             error="simulated: no device"))
-    eng = conf.build_engine("tpu")
-    assert eng is not None and hasattr(eng, "merge")
+    eng = conf.build_engine("auto")
+    assert eng.name == "cpu" and hasattr(eng, "merge")
 
 
 # ----------------------------------------------------- 2: dump invalidation

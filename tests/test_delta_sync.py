@@ -549,13 +549,12 @@ def test_midstream_falloff_resyncs_by_delta(tmp_path):
 
 
 @pytest.mark.slow
-def test_delta_resync_from_sharded_pusher(tmp_path, monkeypatch):
+def test_delta_resync_from_sharded_pusher(tmp_path):
     """A shard-per-core node (CONSTDB_SERVE_SHARDS=2) answers the same
     protocol: worker digests sum into the plane matrix, divergent
     buckets export worker-encoded, and the plain peer converges by
     delta."""
     from cluster_util import Client, close_cluster, converge, make_cluster
-    monkeypatch.setenv("CONSTDB_SHARD_ENGINE", "cpu")
 
     async def main():
         apps = await make_cluster(2, str(tmp_path), repl_log_cap=3000,

@@ -57,7 +57,7 @@ def test_no_leak_after_worker_crash_mid_job():
     surfaces the dead pipe as an error and close() still unlinks every
     job segment."""
     before = _shm_names()
-    pool = HostShardPool(2, engine_spec="cpu", max_inflight=2)
+    pool = HostShardPool(2, max_inflight=2)
     try:
         entries = _raw_entries(_chunks())
         pool.submit_group([], entries[:2])
@@ -88,7 +88,7 @@ def test_submit_group_guard_frees_segment_on_failure(monkeypatch):
     (before registration hands ownership to reap/close) must close +
     unlink it instead of leaking until process exit."""
     before = _shm_names()
-    pool = HostShardPool(1, engine_spec="cpu")
+    pool = HostShardPool(1)
     try:
         # entry shaped to blow up inside the population loop: a str has
         # a len() (so sizing + creation succeed) but is not a buffer, so

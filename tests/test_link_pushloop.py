@@ -210,7 +210,7 @@ def test_closed_app_does_not_keep_applying(tmp_path):
     asyncio.run(main())
 
 
-def test_sharded_snapshot_ingest_e2e(tmp_path, monkeypatch):
+def test_sharded_snapshot_ingest_e2e(tmp_path):
     """Full-sync catch-up through the process-parallel sharded ingest
     (ServerApp ingest_shards > 1): a joiner whose resume point is off the
     pusher's ring downloads a snapshot, fans it out to shard workers, and
@@ -219,8 +219,6 @@ def test_sharded_snapshot_ingest_e2e(tmp_path, monkeypatch):
     import sys
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from cluster_util import Client, close_cluster, converge, make_cluster
-
-    monkeypatch.setenv("CONSTDB_SHARD_ENGINE", "cpu")  # jax-free workers
 
     async def main():
         apps = await make_cluster(2, str(tmp_path), repl_log_cap=2_000,
@@ -245,3 +243,21 @@ def test_sharded_snapshot_ingest_e2e(tmp_path, monkeypatch):
         finally:
             await close_cluster(apps)
     asyncio.run(main())
+
+
+def test_device_engine_node_ingests_in_process(tmp_path):
+    """One process per chip: shard workers are CPU-engine processes, so
+    a node whose engine batches on a device never fans a snapshot out
+    to them, whatever ingest_shards asks for — the decision is taken
+    and lands on the in-process path."""
+    from constdb_tpu.engine.tpu import TpuMergeEngine
+    from constdb_tpu.server.io import ServerApp
+    from constdb_tpu.server.node import Node
+
+    dev = ServerApp(Node(node_id=1, engine=TpuMergeEngine(resident=True)),
+                    work_dir=str(tmp_path), ingest_shards=4,
+                    ingest_shard_min_bytes=0)
+    assert dev.snapshot_ingest_shards(1 << 30) == 1
+    cpu = ServerApp(Node(node_id=2), work_dir=str(tmp_path),
+                    ingest_shards=4, ingest_shard_min_bytes=0)
+    assert cpu.snapshot_ingest_shards(1 << 30) == 4

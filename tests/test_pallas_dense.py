@@ -2,19 +2,27 @@
 
 Runs through the Pallas interpreter on the CPU platform (same kernel code
 path as TPU, minus the Mosaic compile), over adversarial int64 data:
-NEUTRAL_T sentinels, negative values, 63-bit uuids, exact ties.
+NEUTRAL_T sentinels, negative values, 63-bit uuids, exact ties.  On the
+chip (CONSTDB_TEST_TPU=1) every kernel the engine runs compiled there
+(TpuMergeEngine.AUTO_TPU_KERNELS == "pallas") is compiled by Mosaic here
+too; the kernels whose XLA twin the engine selects stay interpreted.
 """
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from constdb_tpu.crdt.semantics import NEUTRAL_T
+from constdb_tpu.engine.tpu import TpuMergeEngine
 from constdb_tpu.ops import dense as D
 from constdb_tpu.ops import pallas_dense as PD
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret(kernel: str) -> bool:
+    return jax.default_backend() != "tpu" or \
+        TpuMergeEngine.AUTO_TPU_KERNELS[kernel] != "pallas"
 
 
 def _cols(rng, R, S, ties=True):
@@ -39,7 +47,8 @@ def test_merge_elems_matches_xla(seed, R, S):
 
     a1, n1, d1, w1 = (np.asarray(x) for x in D.dense_merge_elems(at, an, dt))
     a2, n2, d2, w2 = (np.asarray(x) for x in
-                      PD.merge_elems(at, an, dt, interpret=INTERPRET))
+                      PD.merge_elems(at, an, dt,
+                                     interpret=_interpret("merge_elems")))
     np.testing.assert_array_equal(a1, a2)
     np.testing.assert_array_equal(n1, n2)
     np.testing.assert_array_equal(d1, d2)
@@ -54,7 +63,8 @@ def test_merge_counters_matches_xla(seed, R, S):
     # exact-uuid ties must resolve by max value on both paths
     v1, t1 = (np.asarray(x) for x in D.dense_merge_counters(vals, ts))
     v2, t2 = (np.asarray(x) for x in
-              PD.merge_counters(vals, ts, interpret=INTERPRET))
+              PD.merge_counters(vals, ts,
+                                interpret=_interpret("merge_counters")))
     np.testing.assert_array_equal(t1, t2)
     np.testing.assert_array_equal(v1, v2)
 
@@ -68,11 +78,33 @@ def test_negative_and_extreme_values():
     dt = np.array([[0, 3, 0, 0], [5, 0, 0, 0]], dtype=np.int64)
     a1, n1, d1, w1 = (np.asarray(x) for x in D.dense_merge_elems(at, an, dt))
     a2, n2, d2, w2 = (np.asarray(x) for x in
-                      PD.merge_elems(at, an, dt, interpret=INTERPRET))
+                      PD.merge_elems(at, an, dt,
+                                     interpret=_interpret("merge_elems")))
     np.testing.assert_array_equal(a1, a2)
     np.testing.assert_array_equal(n1, n2)
     np.testing.assert_array_equal(d1, d2)
     np.testing.assert_array_equal(w1, w2)
+
+
+@pytest.mark.parametrize("n", [2, 3, 8])
+@pytest.mark.parametrize("strat", ["sum", "maxmag", "trimmed-mean"])
+def test_tensor_reduce_matches_xla(strat, n):
+    """The tensor strategy kernel vs its XLA twin on the SAME backend
+    (tests/test_tensor_family.py pins both to the host chain in
+    interpret mode; this is the leg Mosaic compiles on the chip)."""
+    from constdb_tpu.crdt import tensor as T
+    sid = T.STRATEGY_IDS[strat]
+    rng = np.random.default_rng(sid * 10 + n)
+    G, Kp = 5, 2 * PD.TENSOR_BLOCK
+    mat = jnp.asarray((rng.standard_normal((G, n, Kp)) * 9)
+                      .astype(np.float32))
+    cnts = jnp.ones((G, n), jnp.float32)
+    div = np.float32(n if n <= 2 else n - 2)
+    xla = np.asarray(D.tensor_reduce(mat, cnts, div, strat=sid, n=n))
+    pal = np.asarray(PD.tensor_reduce(
+        mat, cnts, div, strat=sid, n=n,
+        interpret=_interpret("tensor_reduce")))
+    assert np.array_equal(xla.view(np.uint32), pal.view(np.uint32))
 
 
 # -------------------------------------------------- resident scatter kernels
@@ -81,9 +113,6 @@ def test_negative_and_extreme_values():
 # (ops/bulk.py bulk_lww_src / ops/dense.py segment_sum) and the host
 # reference, over the engine's exact padding protocol.
 
-import jax.numpy as jnp
-
-from constdb_tpu.engine.tpu import TpuMergeEngine
 from constdb_tpu.ops import bulk as B
 
 
@@ -106,7 +135,7 @@ def _scatter_both(p, s, src, idx, bp, bs, base):
         jnp.array(_pad1(idx, np2, pad_row)),
         jnp.array(_pad1(bp, np2, NEUTRAL_T)),
         jnp.array(_pad1(bs, np2, NEUTRAL_T)),
-        np.int32(base), interpret=INTERPRET)
+        np.int32(base), interpret=_interpret("scatter_pair_src_split"))
     idx_x = np.concatenate([idx, (sp + np.arange(np2 - n)).astype(np.int32)])
     xla_out = B.bulk_lww_src(
         jnp.array(p), jnp.array(s), jnp.array(src), jnp.array(idx_x),
@@ -224,7 +253,8 @@ def test_segment_sum_matches_xla_and_host(seed, n, n_seg):
     # full-range magnitudes force the unsigned lo-word carry chains
     vals = rng.integers(-(1 << 61), 1 << 61, n).astype(np.int64)
     got = np.asarray(PD.segment_sum(jnp.array(ids), jnp.array(vals),
-                                    n_seg=n_seg, interpret=INTERPRET))
+                                    n_seg=n_seg,
+                                    interpret=_interpret("segment_sum")))
     xla = np.asarray(D.segment_sum(jnp.array(ids), jnp.array(vals),
                                    n_seg=n_seg))
     want = np.zeros(n_seg, dtype=np.int64)
@@ -238,20 +268,23 @@ def test_segment_sum_carry_boundary():
     ids = np.zeros(8, dtype=np.int32)
     vals = np.full(8, (1 << 32) - 1, dtype=np.int64)
     got = np.asarray(PD.segment_sum(jnp.array(ids), jnp.array(vals),
-                                    n_seg=3, interpret=INTERPRET))
+                                    n_seg=3,
+                                    interpret=_interpret("segment_sum")))
     assert got.tolist() == [8 * ((1 << 32) - 1), 0, 0]
     # negative totals round-trip the split sign correctly
     vals = np.array([-(1 << 40), 1, -(1 << 33), 5], dtype=np.int64)
     ids = np.array([0, 1, 0, 1], dtype=np.int32)
     got = np.asarray(PD.segment_sum(jnp.array(ids), jnp.array(vals),
-                                    n_seg=2, interpret=INTERPRET))
+                                    n_seg=2,
+                                    interpret=_interpret("segment_sum")))
     assert got.tolist() == [-(1 << 40) - (1 << 33), 6]
 
 
 def test_segment_sum_scratch_cap():
     with pytest.raises(ValueError):
         PD.segment_sum(jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int64),
-                       n_seg=PD.SEGMENT_SUM_MAX_SEG + 1, interpret=INTERPRET)
+                       n_seg=PD.SEGMENT_SUM_MAX_SEG + 1,
+                       interpret=_interpret("segment_sum"))
 
 # ---------------------------------------------------- pre-split planes
 # The retired PR 8 follow-up: LWW pair planes live PRE-SPLIT as hi/lo
@@ -388,10 +421,10 @@ def test_recompute_sums_joins_split_cache():
     # real TPU backend): host-combine staging stays on (env rides host
     # mode — no env mirror, so nothing flushes between the bulk round
     # and the micro rounds) while the scatter kernels run Pallas.  On
-    # this CPU box auto resolves to xla, so pin the resolution.
+    # the CPU backend auto resolves to xla, so pin the resolution.
     eng = TpuMergeEngine(resident=True, steady=True, warmup=0,
                          dense_fold="auto")
-    eng._fold_backend = lambda: "pallas-interpret"
+    eng._kernel_backend = lambda kernel: "pallas-interpret"
     # bulk catch-up: whole-plane cnt mirror (dirty=None)
     b1, b2 = (cnt_batch([100, 101, 102, 103], 10, True) for _ in range(2))
     cpu.merge_many(ref, [b1])

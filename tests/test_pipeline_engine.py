@@ -156,33 +156,22 @@ def test_clustered_rank_stays_dense():
     assert ks.cnt_rows_lookup(rank, kids).tolist() == rows.tolist()
 
 
-def test_failed_probe_expires_ok_probe_sticks(monkeypatch):
-    """probe_backend: failed probes get a TTL so a healed device is
-    re-probed; successful probes cache for the process lifetime."""
-    from constdb_tpu.utils import backend as bk
+def test_build_engine_starts_no_process(monkeypatch):
+    """JAX initializes in the server process, once: build_engine never
+    probes the backend from a second process (a child that touches the
+    chip before the server does would hold it — one process per
+    chip)."""
+    import subprocess
 
-    calls = []
+    import constdb_tpu.conf as conf
 
-    def fake_fail(timeout):
-        calls.append("fail")
-        return bk.BackendProbe(False, error="wedged")
+    def boom(*a, **kw):
+        raise AssertionError("build_engine spawned a process")
 
-    def fake_ok(timeout):
-        calls.append("ok")
-        return bk.BackendProbe(True, platform="tpu", n_devices=1)
-
-    monkeypatch.setattr(bk, "_PROBE_MEMO", [])
-    monkeypatch.setattr(bk, "_probe_backend_uncached", fake_fail)
-    assert not bk.probe_backend().ok
-    # within the TTL the failure is served from cache
-    assert not bk.probe_backend(fail_ttl=3600).ok
-    assert calls == ["fail"]
-    # past the TTL the device healed: the next call re-probes and the
-    # success then sticks forever
-    monkeypatch.setattr(bk, "_probe_backend_uncached", fake_ok)
-    assert bk.probe_backend(fail_ttl=0.0).ok
-    assert bk.probe_backend(fail_ttl=0.0).ok
-    assert calls == ["fail", "ok"]
+    monkeypatch.setattr(subprocess, "run", boom)
+    monkeypatch.setattr(subprocess, "Popen", boom)
+    for kind in ("auto", "cpu"):
+        assert conf.build_engine(kind).name == "cpu"
 
 
 def test_bench_smoke_pipelined_end_to_end():

@@ -221,44 +221,16 @@ def test_sharded_process_cpu_byte_identical():
     sks.close()
 
 
-@pytest.mark.slow
-def test_sharded_process_tpu_byte_identical():
-    """The acceptance differential at full fidelity: N=2 worker processes
-    each running the resident TPU-path engine — byte-identical to the
-    single-shard engine over the same split, union canonically equal to
-    the unsplit single path.  (slow: each worker initializes its own JAX
-    runtime.)"""
-    chunks = _workload()
-    n, group = 2, 4
-    sks = ShardedKeySpace(n_shards=n, mode="process", engine_spec="tpu",
-                          group=group, env={"XLA_FLAGS": ""})
-    for c in chunks:
-        sks.submit(c)
-    sks.flush()
-    got = sks.state_bytes_per_shard()
-
-    split = [[] for _ in range(n)]
-    for i in range(0, len(chunks), group):
-        for s, subs in enumerate(_split(chunks[i:i + group], n)):
-            split[s].append(subs)
-    for s in range(n):
-        ref = KeySpace()
-        eng = TpuMergeEngine(resident=True)
-        for subs in split[s]:
-            if subs:
-                eng.merge_many(ref, subs)
-        eng.flush(ref)
-        assert got[s] == keyspace_state_bytes(ref), f"shard {s} diverged"
-        eng.close()
-
-    single = KeySpace()
-    eng = TpuMergeEngine(resident=True)
-    for i in range(0, len(chunks), group):
-        eng.merge_many(single, chunks[i:i + group])
-    eng.flush(single)
-    assert sks.canonical() == single.canonical()
-    eng.close()
-    sks.close()
+def test_process_mode_refuses_device_engines():
+    """One process per chip: process-mode workers build the CPU engine
+    only, and asking for a device engine there is an error at
+    construction — never a child that hangs on a chip its parent
+    holds.  (A device engine shards in-process: mode="local", above.)"""
+    with pytest.raises(ValueError, match="CPU engine only"):
+        ShardedKeySpace(n_shards=2, mode="process", engine_spec="tpu")
+    with pytest.raises(ValueError, match="CPU engine only"):
+        ShardedKeySpace(n_shards=2, mode="process", engine_spec="cpu",
+                        engine_factory=lambda: TpuMergeEngine(resident=True))
 
 
 def test_consolidate_into_single_keyspace():
@@ -302,7 +274,7 @@ def test_pool_worker_error_propagates():
     worker traceback, not a hang."""
     from constdb_tpu.parallel.host_pool import HostShardPool
 
-    pool = HostShardPool(1, engine_spec="cpu")
+    pool = HostShardPool(1)
     try:
         with pytest.raises(RuntimeError, match="shard worker 0"):
             pool.submit_group([], [(b"garbage-not-a-batch",
