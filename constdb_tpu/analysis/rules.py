@@ -794,6 +794,68 @@ class LockDisciplineRule(Rule):
                     f"{lock} blocks the loop with the lock held")
 
 
+class StageAwaitRule(Rule):
+    """STAGE-AWAIT: a stage clock is never held across an `await`.
+
+    `with <...>stage("name"):` (utils/stagetime.py) bills the block's
+    wall time to one stage of ONE connection's work.  The event loop
+    runs other connections at every `await`, so a stage left open
+    across one would count their work — and their stages would nest
+    into it as children, taking the time back out again: the self
+    times stop adding up to wall time, which is the one property the
+    per-layer time metrics rest on."""
+
+    name = "STAGE-AWAIT"
+    hint = ("close the stage before the await: time the synchronous "
+            "calls only (server/io.py times `writer.write`, not the "
+            "`await writer.drain()` after it)")
+
+    def applies(self, ctx: FileContext) -> bool:
+        return _scoped(ctx, "server", "replica", "engine", "persist",
+                       "parallel")
+
+    @staticmethod
+    def _stages(node: ast.With) -> list[str]:
+        out = []
+        for item in node.items:
+            call = item.context_expr
+            if isinstance(call, ast.Call):
+                name = dotted(call.func) or ""
+                if name.rsplit(".", 1)[-1] in ("stage", "_stage"):
+                    out.append(name)
+        return out
+
+    @staticmethod
+    def _suspends(body: list):
+        """Every point in `body` where the coroutine can yield to the
+        loop.  A def nested in the block is its own scope: it runs (and
+        awaits) when called, not inside this stage."""
+        stack = list(body)
+        while stack:
+            n = stack.pop()
+            if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
+                              ast.Lambda, ast.ClassDef)):
+                continue
+            if isinstance(n, (ast.Await, ast.AsyncFor, ast.AsyncWith)):
+                yield n
+            stack.extend(ast.iter_child_nodes(n))
+
+    def check(self, ctx: FileContext):
+        for qual, fn, _is_async, _actx in ctx.functions:
+            for node in own_nodes(fn):
+                if not isinstance(node, ast.With):
+                    continue
+                for stage in self._stages(node):
+                    hits = sorted(self._suspends(node.body),
+                                  key=lambda n: (n.lineno, n.col_offset))
+                    if hits:
+                        yield self.finding(
+                            ctx, hits[0], qual, stage,
+                            f"await inside `with {stage}(...)`: the "
+                            "loop runs other connections there, and "
+                            "their time is billed to this stage")
+
+
 class CutOrderingRule(Rule):
     """CUT-ORDERING: watermark/record capture precedes any awaited state
     export in the same function — the INVARIANTS "consistency cuts" law
@@ -1088,4 +1150,5 @@ ALL_RULES: list[Rule] = [
     SlotEpochRule(),
     LockDisciplineRule(),
     CutOrderingRule(),
+    StageAwaitRule(),
 ]

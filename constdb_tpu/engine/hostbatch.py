@@ -339,12 +339,17 @@ def merge_host_batches(store: KeySpace, batches: list) -> MergeStats:
 
 
 def merge_host_batch(store: KeySpace, batch: ColumnarBatch,
-                     kid_of: np.ndarray, st: MergeStats) -> None:
+                     kid_of: np.ndarray, st: MergeStats,
+                     counts: dict = None) -> None:
     """Merge one columnar batch into the host store, fully vectorized.
     `kid_of` is the caller's key resolution (the engine's memoized
     `_resolve_keys`).  Duplicate rows per slot are folded by associative
     group reductions, so raw op-stream batches
-    (`rows_unique_per_slot=False`) are first-class here."""
+    (`rows_unique_per_slot=False`) are first-class here.  `counts`: the
+    device engine's rows merged on the host per family (INFO
+    merge_rows_host_<fam>), added to where given."""
+    if counts is None:
+        counts = dict.fromkeys(("env", "reg", "cnt", "el"), 0)
     valid = kid_of >= 0
     all_valid = bool(valid.all())
     if batch.n_keys:
@@ -353,6 +358,7 @@ def merge_host_batch(store: KeySpace, batch: ColumnarBatch,
             mat = np.stack([batch.key_ct, batch.key_mt, batch.key_dt,
                             batch.key_expire], axis=-1)
             _merge_env(store, kids, mat if all_valid else mat[valid])
+            counts["env"] += len(kids)
 
         from ..utils.native_tables import nonnull_mask
         em = (kid_of >= 0) & (batch.key_enc == S.ENC_BYTES) & \
@@ -362,12 +368,14 @@ def merge_host_batch(store: KeySpace, batch: ColumnarBatch,
             _merge_reg(store, kid_of[idx], batch.reg_t[idx],
                        batch.reg_node[idx],
                        list(map(batch.reg_val.__getitem__, idx.tolist())))
+            counts["reg"] += len(idx)
 
     if len(batch.cnt_ki):
         kid_arr = kid_of[batch.cnt_ki]
         keep = np.nonzero(kid_arr >= 0)[0]
         if len(keep):
             st.counter_rows += len(keep)
+            counts["cnt"] += len(keep)
             sel = slice(None) if len(keep) == len(kid_arr) else keep
             rows = _resolve_cnt_rows(store, kid_arr[sel], batch.cnt_node[sel])
             _apply_cnt_pair(store, rows, batch.cnt_val[sel],
@@ -382,6 +390,7 @@ def merge_host_batch(store: KeySpace, batch: ColumnarBatch,
         keep = np.nonzero(kid_arr >= 0)[0]
         if len(keep):
             st.elem_rows += len(keep)
+            counts["el"] += len(keep)
             if len(keep) == len(kid_arr):
                 sel = slice(None)
                 members = batch.el_member

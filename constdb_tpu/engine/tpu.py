@@ -56,7 +56,8 @@ import numpy as np
 from ..crdt import semantics as S
 from ..ops import bulk as B
 from ..ops import segment as K
-from ..store.keyspace import FAMILIES, KeySpace
+from ..store.keyspace import FAMILIES, TOUCH_CAUSES, KeySpace
+from ..utils.stagetime import StageClock, seconds_into
 from .base import ColumnarBatch, MergeStats, has_values
 from .hostbatch import HOST_MICRO_MAX
 
@@ -263,15 +264,26 @@ class TpuMergeEngine:
         # stale-mirror rebuilds per family (observability: mixed op/merge
         # traffic must keep these O(writes-to-that-plane), never O(ops))
         self.mirror_rebuilds = dict.fromkeys(FAMILIES, 0)
+        # ... and what invalidated the mirror each rebuild replaced (the
+        # family's last KeySpace.touch cause)
+        self.mirror_rebuild_causes = dict.fromkeys(TOUCH_CAUSES, 0)
+        # rows merged per family and per path (INFO merge_rows_dev_<fam> /
+        # merge_rows_host_<fam>): on the device path the rows handed to
+        # the scatter AFTER the host fold, on the host path the rows the
+        # twin merged.  env on the micro path is always host.
+        self.merge_rows_dev = dict.fromkeys(self.FAM_ORDER, 0)
+        self.merge_rows_host = dict.fromkeys(self.FAM_ORDER, 0)
+        # the served path's stage clock (utils/stagetime.py): every host
+        # clock below is taken through it, and its annotated stages land
+        # in the device trace's host plane.  The Node adopts it.
+        self.stages = StageClock(annotation=jax.profiler.TraceAnnotation)
         # cumulative host-side seconds per family on the CRITICAL PATH
-        # (stage-wait + dispatch; device work is async).  The flush entry
-        # includes the blocking downloads.  With the pipeline on,
-        # `stage_secs` separately records each family's background staging
-        # time — staging overlapped with device compute shows up there
-        # while family_secs shrinks to the un-overlapped remainder.
+        # (stage-wait + dispatch; device work is async; INFO
+        # merge_<fam>_seconds).  Inclusive totals that overlap the stage
+        # clock's self times by design: "micro" covers a whole resident
+        # micro round, "flush" includes the blocking downloads.
         self.family_secs = {"env": 0.0, "reg": 0.0, "cnt": 0.0, "el": 0.0,
                             "flush": 0.0, "host": 0.0, "micro": 0.0}
-        self.stage_secs = {"env": 0.0, "reg": 0.0, "cnt": 0.0, "el": 0.0}
         from ..conf import env_flag, env_int
         if pipeline is None:
             pipeline = env_flag("CONSTDB_PIPELINE", True)
@@ -454,12 +466,13 @@ class TpuMergeEngine:
         jnp = self._jax.numpy
         res = self._res.get(fam) or {}
         src = res.get("src")
-        if src is None:
-            return B.device_full(sp, -1, i32=True)
-        if src.shape[0] < sp:
-            src = jnp.concatenate(
+        if src is not None and src.shape[0] >= sp:
+            return src
+        with self.stages.stage("state_alloc", fam):
+            if src is None:
+                return B.device_full(sp, -1, i32=True)
+            return jnp.concatenate(
                 [src, B.device_full(sp - src.shape[0], -1, i32=True)])
-        return src
 
     # ----------------------------------------------------- device placement
 
@@ -479,9 +492,10 @@ class TpuMergeEngine:
 
     def _put_batch(self, arr: np.ndarray):
         self.bytes_h2d += arr.nbytes
-        if self._mesh is None:
-            return self._jax.device_put(arr)
-        return self._jax.device_put(arr, self._sh_rep)
+        with self.stages.stage("h2d"):
+            if self._mesh is None:
+                return self._jax.device_put(arr)
+            return self._jax.device_put(arr, self._sh_rep)
 
     def _device_get(self, x):
         out = self._jax.device_get(x)
@@ -492,21 +506,22 @@ class TpuMergeEngine:
     def _full(self, n: int, fill: int, cols: int = 0):
         """Neutral state materialized on device with the state sharding
         (cols=0 → [n]; cols=C → [n, C])."""
-        if self._mesh is None:
-            if cols:
-                return self._jax.numpy.zeros((n, cols),
-                                             dtype=self._jax.numpy.int64)
-            return B.device_full(n, fill)
-        key = ("full", n, fill, cols)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            jnp = self._jax.numpy
-            shape = (n, cols) if cols else (n,)
-            fn = self._jax.jit(
-                lambda: jnp.full(shape, fill, dtype=jnp.int64),
-                out_shardings=self._sh_state[2 if cols else 1])
-            self._jit_cache[key] = fn
-        return fn()
+        with self.stages.stage("state_alloc"):
+            if self._mesh is None:
+                if cols:
+                    return self._jax.numpy.zeros(
+                        (n, cols), dtype=self._jax.numpy.int64)
+                return B.device_full(n, fill)
+            key = ("full", n, fill, cols)
+            fn = self._jit_cache.get(key)
+            if fn is None:
+                jnp = self._jax.numpy
+                shape = (n, cols) if cols else (n,)
+                fn = self._jax.jit(
+                    lambda: jnp.full(shape, fill, dtype=jnp.int64),
+                    out_shardings=self._sh_state[2 if cols else 1])
+                self._jit_cache[key] = fn
+            return fn()
 
     def _grow(self, old, delta: int, fill: int, cols: int = 0):
         """Extend resident state by `delta` neutral rows, preserving the
@@ -578,14 +593,16 @@ class TpuMergeEngine:
         # and shape tokens pin their parents via shape_refs)
         memo: dict = {}
         resolved = []
-        for b in batches:
-            mk = b.key_shape if b.key_shape is not None \
-                else ("id", id(b.keys), id(b.key_enc))
-            kid_of = memo.get(mk)
-            if kid_of is None:
-                kid_of = self._resolve_keys(store, b, st)
-                memo[mk] = kid_of
-            resolved.append((b, kid_of))
+        stage = self.stages.stage
+        with stage("stage_rows", "keys"):
+            for b in batches:
+                mk = b.key_shape if b.key_shape is not None \
+                    else ("id", id(b.keys), id(b.key_enc))
+                kid_of = memo.get(mk)
+                if kid_of is None:
+                    kid_of = self._resolve_keys(store, b, st)
+                    memo[mk] = kid_of
+                resolved.append((b, kid_of))
         if not self._unique_ok and self._mesh is None and \
                 sum(b.n_rows for b in batches) <= self.HOST_SCATTER_MAX:
             # op-stream micro-batches (the steady-state coalescers'
@@ -598,20 +615,18 @@ class TpuMergeEngine:
             # per family (cold planes — see _micro_placement) or for the
             # whole round (non-resident engines, CONSTDB_RESIDENT=0,
             # mesh-partitioned state).
-            import time as _time
             placement = self._micro_placement(store, resolved)
             if placement is not None:
-                t0 = _time.perf_counter()
-                for b, kid_of in resolved:
-                    self._merge_micro_resident(store, b, kid_of, st,
-                                               placement)
+                with seconds_into(self.family_secs, "micro"):
+                    for b, kid_of in resolved:
+                        self._merge_micro_resident(store, b, kid_of, st,
+                                                   placement)
                 if any(placement.values()):
                     self.dev_rounds_resident += 1
                 elif placement:
                     self.host_micro_rounds += 1
                 # empty placement (env-only / delete-only round): neither
                 # gauge — no device family was touched at all
-                self.family_secs["micro"] += _time.perf_counter() - t0
                 if self.needs_flush and \
                         self._pool_bytes > self.pool_flush_bytes:
                     self.flush(store)
@@ -623,14 +638,13 @@ class TpuMergeEngine:
             for fam in list(self._res):
                 self._drop_family(store, fam)
             self.host_micro_rounds += 1
-            t0 = _time.perf_counter()
             rows0 = st.tensor_rows
-            for b, kid_of in resolved:
-                merge_host_batch(store, b, kid_of, st)
+            with stage("host_twin", total=(self.family_secs, "host")):
+                for b, kid_of in resolved:
+                    merge_host_batch(store, b, kid_of, st,
+                                     counts=self.merge_rows_host)
             self.tns_host_rows += st.tensor_rows - rows0
-            self.family_secs["host"] += _time.perf_counter() - t0
             return st
-        import time as _time
         # a src-tracked pool from resident MICRO rounds must resolve
         # before a bulk branch that does not track src (forced dense_fold
         # configs skip the src kernels) scatters into the same planes —
@@ -638,8 +652,10 @@ class TpuMergeEngine:
         # round's winners
         if self.resident and self._pool_size and not self._host_combine():
             self.flush(store)
-        stage = {"env": self._stage_envelopes, "reg": self._stage_registers,
-                 "cnt": self._stage_counter_rows, "el": self._stage_elem_rows}
+        stage_fn = {"env": self._stage_envelopes,
+                    "reg": self._stage_registers,
+                    "cnt": self._stage_counter_rows,
+                    "el": self._stage_elem_rows}
         dispatch = {"env": self._dispatch_envelopes,
                     "reg": self._dispatch_registers,
                     "cnt": self._dispatch_counter_rows,
@@ -651,16 +667,15 @@ class TpuMergeEngine:
             # order as it lands.  The only cross-plane seam is flush,
             # which joins the in-flight stages first.
             ex = self._staging_executor()
-            futs = {f: ex.submit(self._timed_stage, f, stage[f],
+            futs = {f: ex.submit(self._timed_stage, f, stage_fn[f],
                                  store, resolved, st)
                     for f in self.FAM_ORDER}
             self._stage_pending = futs
             try:
                 for fam in self.FAM_ORDER:
-                    t0 = _time.perf_counter()
-                    plan = futs[fam].result()
-                    dispatch[fam](store, plan, st)
-                    self.family_secs[fam] += _time.perf_counter() - t0
+                    with seconds_into(self.family_secs, fam):
+                        self._dispatch_plan(fam, dispatch[fam], store,
+                                            futs[fam].result(), st)
             finally:
                 # a dispatch error must not leave stages mutating the
                 # store behind the caller's back
@@ -669,10 +684,10 @@ class TpuMergeEngine:
                 self._stage_pending = None
         else:
             for fam in self.FAM_ORDER:
-                t0 = _time.perf_counter()
-                plan = self._timed_stage(fam, stage[fam], store, resolved, st)
-                dispatch[fam](store, plan, st)
-                self.family_secs[fam] += _time.perf_counter() - t0
+                with seconds_into(self.family_secs, fam):
+                    plan = self._timed_stage(fam, stage_fn[fam], store,
+                                             resolved, st)
+                    self._dispatch_plan(fam, dispatch[fam], store, plan, st)
         # tensor rows (few, payload-heavy) ride the resident payload
         # pools whenever the steady path is on — bulk catch-up seeds the
         # pools the micro rounds then merge into; the host twin covers
@@ -680,8 +695,9 @@ class TpuMergeEngine:
         tns_device = self.resident and self.steady and self._mesh is None
         for b, kid_of in resolved:
             if len(b.tns_ki):
-                self._merge_micro_tns(store, b, kid_of, st,
-                                      device=tns_device)
+                with stage("dispatch" if tns_device else "host_twin", "tns"):
+                    self._merge_micro_tns(store, b, kid_of, st,
+                                          device=tns_device)
         for b, _ in resolved:
             for i, key in enumerate(b.del_keys):
                 store.record_key_delete(key, int(b.del_t[i]))
@@ -738,12 +754,25 @@ class TpuMergeEngine:
             pass
 
     def _timed_stage(self, fam: str, fn, store, resolved, st):
-        import time as _time
-        t0 = _time.perf_counter()
-        try:
+        """One family's STAGE step under the `stage_rows` clock — on a
+        staging-pool thread when the pipeline is on (its own stage stack:
+        the time is counted but never nests into the dispatching
+        thread's stages)."""
+        with self.stages.stage("stage_rows", fam):
             return fn(store, resolved, st)
-        finally:
-            self.stage_secs[fam] += _time.perf_counter() - t0
+
+    def _dispatch_plan(self, fam: str, fn, store, plan, st) -> None:
+        """One family's DISPATCH step under the `dispatch` clock, and its
+        staged rows counted by the path they took: every bulk and scatter
+        mode launches device kernels; only the resident envelope fold
+        ("host" mode) merges on the host, under `host_twin`."""
+        if plan is None:
+            return
+        host = plan.get("mode") == "host"
+        with self.stages.stage("host_twin" if host else "dispatch", fam):
+            fn(store, plan, st)
+        by_path = self.merge_rows_host if host else self.merge_rows_dev
+        by_path[fam] += sum(len(s[0]) for s in plan["staged"])
 
     def _join_staging(self) -> None:
         """Wait for in-flight family stages before any cross-plane mutation
@@ -782,8 +811,12 @@ class TpuMergeEngine:
         if not self.needs_flush:
             return
         self._join_staging()
-        import time as _time
-        t0 = _time.perf_counter()
+        with self.stages.stage("d2h_flush",
+                               total=(self.family_secs, "flush")):
+            self._flush_resident(store)
+
+    def _flush_resident(self, store: KeySpace) -> None:
+        """flush()'s body, under its `d2h_flush` stage."""
         pending: dict[str, dict] = {}
         partial: dict[str, tuple] = {}  # fam -> (rows_d, {name: dev}, src)
         for fam, res in self._res.items():
@@ -944,7 +977,6 @@ class TpuMergeEngine:
             self._recompute_sums(store)
         self._flush_tns(store)
         self.needs_flush = False
-        self.family_secs["flush"] += _time.perf_counter() - t0
 
     def release_device_pools(self, store: KeySpace) -> None:
         """Hard-watermark memory reclaim (server/overload.py): flush
@@ -1104,26 +1136,32 @@ class TpuMergeEngine:
                     f"{fam} mirror invalidated with unflushed merge data "
                     "(flush-before-touch invariant broken upstream)")
             self.mirror_rebuilds[fam] += 1
+            self.mirror_rebuild_causes[store.fam_cause[fam]] += 1
             res = None
         cap = self._sp_size(n)
         spec = _FAMILIES[fam]
         if res is None:
-            table = _host_table(store, fam)
-            if fam == "env":
-                host = np.stack([table.col(c)[:n] for c, _ in spec], axis=-1)
-                cols = {"stack": self._put_state(_pad(host, cap, 0))}
-            else:
-                cols = {c: self._put_state(
-                    _pad(table.col(c)[:n], cap, fill)) for c, fill in spec}
+            # a whole-plane upload (first build or stale rebuild)
+            with self.stages.stage("mirror_rebuild", fam):
+                table = _host_table(store, fam)
+                if fam == "env":
+                    host = np.stack([table.col(c)[:n] for c, _ in spec],
+                                    axis=-1)
+                    cols = {"stack": self._put_state(_pad(host, cap, 0))}
+                else:
+                    cols = {c: self._put_state(
+                        _pad(table.col(c)[:n], cap, fill))
+                        for c, fill in spec}
         elif n > res["cap"]:
             old = res["cols"]
             delta = cap - res["cap"]
-            if fam == "env":
-                cols = {"stack": self._grow(old["stack"], delta, 0,
-                                            cols=len(spec))}
-            else:
-                cols = {c: self._grow(old[c], delta, fill)
-                        for c, fill in spec}
+            with self.stages.stage("mirror_rebuild", fam):
+                if fam == "env":
+                    cols = {"stack": self._grow(old["stack"], delta, 0,
+                                                cols=len(spec))}
+                else:
+                    cols = {c: self._grow(old[c], delta, fill)
+                            for c, fill in spec}
         else:
             cols = res["cols"]
             cap = res["cap"]
@@ -1280,31 +1318,39 @@ class TpuMergeEngine:
             # micro path keeps env host-authoritative, so sync it down
             # once and merge on host from here on
             self._drop_family(store, "env")
+        stage = self.stages.stage
+        rows_dev, rows_host = self.merge_rows_dev, self.merge_rows_host
         valid = kid_of >= 0
         all_valid = bool(valid.all())
         if b.n_keys:
             kids = kid_of if all_valid else kid_of[valid]
             if len(kids):
-                mat = np.stack([b.key_ct, b.key_mt, b.key_dt,
-                                b.key_expire], axis=-1)
-                _merge_env(store, kids, mat if all_valid else mat[valid])
+                with stage("host_twin", "env"):
+                    mat = np.stack([b.key_ct, b.key_mt, b.key_dt,
+                                    b.key_expire], axis=-1)
+                    _merge_env(store, kids, mat if all_valid else mat[valid])
+                rows_host["env"] += len(kids)
             em = valid & (b.key_enc == S.ENC_BYTES) & \
                 nonnull_mask(b.reg_val)
             idx = np.nonzero(em)[0]
             if len(idx):
                 if placement.get("reg"):
-                    wk, wt, wn, srci = fold_pair_rows(
-                        kid_of[idx], b.reg_t[idx], b.reg_node[idx])
-                    vals = list(map(b.reg_val.__getitem__,
-                                    idx[srci].tolist()))
+                    with stage("stage_rows", "reg"):
+                        wk, wt, wn, srci = fold_pair_rows(
+                            kid_of[idx], b.reg_t[idx], b.reg_node[idx])
+                        vals = list(map(b.reg_val.__getitem__,
+                                        idx[srci].tolist()))
                     self._micro_scatter_pair(store, "reg",
                                              ("rv_t", "rv_node"),
                                              wk, wt, wn, vals)
+                    rows_dev["reg"] += len(wk)
                 else:
-                    _merge_reg(store, kid_of[idx], b.reg_t[idx],
-                               b.reg_node[idx],
-                               list(map(b.reg_val.__getitem__,
-                                        idx.tolist())))
+                    with stage("host_twin", "reg"):
+                        _merge_reg(store, kid_of[idx], b.reg_t[idx],
+                                   b.reg_node[idx],
+                                   list(map(b.reg_val.__getitem__,
+                                            idx.tolist())))
+                    rows_host["reg"] += len(idx)
 
         if len(b.cnt_ki):
             kid_arr = kid_of[b.cnt_ki]
@@ -1312,33 +1358,40 @@ class TpuMergeEngine:
             if len(keep):
                 st.counter_rows += len(keep)
                 sel = slice(None) if len(keep) == len(kid_arr) else keep
-                rows = self._resolve_cnt_rows(store, kid_arr[sel],
-                                              b.cnt_node[sel])
-                bt = b.cnt_base_t[sel]
-                base_neutral = bool((bt == K.NEUTRAL_T).all())
-                if placement.get("cnt"):
-                    # (uuid, val) pair: LWW on uuid, max-value tie — the
-                    # winners reconstruct from the pool at flush, so the
-                    # two widest counter columns never download
-                    wr, wu, wv, _ = fold_pair_rows(rows, b.cnt_uuid[sel],
-                                                   b.cnt_val[sel])
+                on_dev = bool(placement.get("cnt"))
+                with stage("stage_rows", "cnt"):
+                    rows = self._resolve_cnt_rows(store, kid_arr[sel],
+                                                  b.cnt_node[sel])
+                    bt = b.cnt_base_t[sel]
+                    base_neutral = bool((bt == K.NEUTRAL_T).all())
+                    if on_dev:
+                        # (uuid, val) pair: LWW on uuid, max-value tie —
+                        # the winners reconstruct from the pool at flush,
+                        # so the two widest counter columns never download
+                        wr, wu, wv, _ = fold_pair_rows(
+                            rows, b.cnt_uuid[sel], b.cnt_val[sel])
+                        if not base_neutral:
+                            # base pair (counter deletes — rare): no src
+                            # tracking, its dirty rows download at flush
+                            wr2, wbt, wb, _ = fold_pair_rows(
+                                rows, bt, b.cnt_base[sel])
+                if on_dev:
                     self._micro_scatter_pair(store, "cnt", ("uuid", "val"),
                                              wr, wu, wv, None)
                     if not base_neutral:
-                        # base pair (counter deletes — rare): no src
-                        # tracking, its dirty rows download at flush
-                        wr2, wbt, wb, _ = fold_pair_rows(rows, bt,
-                                                         b.cnt_base[sel])
                         self._micro_scatter_pair(store, "cnt",
                                                  ("base_t", "base"),
                                                  wr2, wbt, wb, None,
                                                  src=False)
+                    rows_dev["cnt"] += len(wr)
                 else:
-                    _apply_cnt_pair(store, rows, b.cnt_val[sel],
-                                    b.cnt_uuid[sel], "val", "uuid", 1)
-                    if not base_neutral:
-                        _apply_cnt_pair(store, rows, b.cnt_base[sel], bt,
-                                        "base", "base_t", -1)
+                    with stage("host_twin", "cnt"):
+                        _apply_cnt_pair(store, rows, b.cnt_val[sel],
+                                        b.cnt_uuid[sel], "val", "uuid", 1)
+                        if not base_neutral:
+                            _apply_cnt_pair(store, rows, b.cnt_base[sel],
+                                            bt, "base", "base_t", -1)
+                    rows_host["cnt"] += len(rows)
 
         if len(b.el_ki):
             kid_arr = kid_of[b.el_ki]
@@ -1354,22 +1407,30 @@ class TpuMergeEngine:
                     members = list(map(b.el_member.__getitem__,
                                        keep.tolist()))
                     vals = list(map(b.el_val.__getitem__, keep.tolist()))
-                rows = _resolve_el_rows(store, kid_arr[sel], members)
-                if not placement.get("el"):
-                    _merge_el(store, rows, b.el_add_t[sel],
-                              b.el_add_node[sel], b.el_del_t[sel], vals)
+                on_dev = bool(placement.get("el"))
+                with stage("stage_rows", "el"):
+                    rows = _resolve_el_rows(store, kid_arr[sel], members)
+                    if on_dev:
+                        wr, wat, wan, d_red, srci = fold_el_rows(
+                            rows, b.el_add_t[sel], b.el_add_node[sel],
+                            b.el_del_t[sel])
+                        if b.el_has_vals is False or not has_values(vals):
+                            wvals = None  # winning valueless adds still
+                            # CLEAR the slot value at flush (pool
+                            # vals=None contract)
+                        else:
+                            wvals = list(map(vals.__getitem__,
+                                             srci.tolist()))
+                if not on_dev:
+                    with stage("host_twin", "el"):
+                        _merge_el(store, rows, b.el_add_t[sel],
+                                  b.el_add_node[sel], b.el_del_t[sel], vals)
+                    rows_host["el"] += len(rows)
                 else:
-                    wr, wat, wan, d_red, srci = fold_el_rows(
-                        rows, b.el_add_t[sel], b.el_add_node[sel],
-                        b.el_del_t[sel])
-                    if b.el_has_vals is False or not has_values(vals):
-                        wvals = None  # winning valueless adds still CLEAR
-                        # the slot value at flush (pool vals=None contract)
-                    else:
-                        wvals = list(map(vals.__getitem__, srci.tolist()))
                     self._micro_scatter_pair(store, "el",
                                              ("add_t", "add_node"),
                                              wr, wat, wan, wvals)
+                    rows_dev["el"] += len(wr)
                     # del side: plain max applied straight to the HOST
                     # column, with the DEVICE del_t plane advanced in
                     # lockstep (one max scatter, only when the batch
@@ -1395,14 +1456,16 @@ class TpuMergeEngine:
                             sp = res["cap"]
                             np2 = K.next_pow2(max(len(rows_adv),
                                                   self.MICRO_SCATTER_PAD))
-                            res["cols"]["del_t"] = B.bulk_max1(
-                                res["cols"]["del_t"],
-                                self._batch_idx(rows_adv, 0, sp, np2),
-                                self._put_batch(_pad(dv_adv, np2, 0)))
+                            with stage("dispatch", "el"):
+                                res["cols"]["del_t"] = B.bulk_max1(
+                                    res["cols"]["del_t"],
+                                    self._batch_idx(rows_adv, 0, sp, np2),
+                                    self._put_batch(_pad(dv_adv, np2, 0)))
 
         if len(b.tns_ki):
-            self._merge_micro_tns(store, b, kid_of, st,
-                                  device=bool(placement.get("tns")))
+            on_dev = bool(placement.get("tns"))
+            with stage("dispatch" if on_dev else "host_twin", "tns"):
+                self._merge_micro_tns(store, b, kid_of, st, device=on_dev)
 
         for i, key in enumerate(b.del_keys):
             store.record_key_delete(key, int(b.del_t[i]))
@@ -1418,9 +1481,16 @@ class TpuMergeEngine:
         both columns AND win values from the host pool.  src=False (the
         rare counter base pair) keeps its winner on device and downloads
         its dirty rows at flush."""
-        nw = len(wr)
-        if not nw:
+        if not len(wr):
             return
+        with self.stages.stage("dispatch", fam):
+            self._scatter_pair(store, fam, pair, wr, wp, ws, vals, src)
+
+    def _scatter_pair(self, store: KeySpace, fam: str, pair, wr, wp, ws,
+                      vals, src: bool) -> None:
+        """_micro_scatter_pair's body, under its `dispatch` stage (the
+        host-side launch: asynchronous, so launch time, not device time)."""
+        nw = len(wr)
         n = _fam_rows(store, fam)
         cols, sp = self._resident_state(store, fam, n, micro=True)
         res = self._res[fam]
@@ -1942,11 +2012,17 @@ class TpuMergeEngine:
         drop — the cache stamp covers both, so a steady read loop pays
         per round only the per-round truth (count columns, lww stamps,
         the reduce dispatches, the result download)."""
+        if not (self.resident and self.steady and self._mesh is None):
+            return {kid: store.tensor_read(kid) for kid in kids}
+        # launches AND the blocking download of the reduced rows
+        with self.stages.stage("dispatch", "tns_read"):
+            return self._tensor_read_resident(store, kids)
+
+    def _tensor_read_resident(self, store: KeySpace, kids) -> dict:
+        """tensor_read_many's device half, under its `dispatch` stage."""
         from ..crdt import tensor as T
         from ..ops import dense as D
         from ..ops import pallas_dense as PD
-        if not (self.resident and self.steady and self._mesh is None):
-            return {kid: store.tensor_read(kid) for kid in kids}
         self._tns_check(store)
         kids_t = tuple(kids)
         # one staleness stamp for ALL cached key sets, then one entry

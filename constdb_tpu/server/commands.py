@@ -298,7 +298,7 @@ def execute(node: "Node", req, client=None, uuid=None) -> Msg:
             _invalidate_read_cache(node, cmd, items[1:])
         return Err(e.resp_error())
     if cmd.is_write:
-        node.ks.touch(*cmd.families)
+        node.ks.touch(*cmd.families, cause="client_op")
         # invalidate-before-visible: the reply cache drops this key's
         # entries before any later read can observe the write
         # (server/read_cache.py; every data command is first-key-
@@ -398,7 +398,7 @@ def apply_replicated(node: "Node", name: bytes, args: list, origin_nodeid: int,
         _invalidate_read_cache(node, cmd, args, scoped=True)
     reply = cmd.handler(node, ctx, ArgIter(args, name))
     if cmd.is_write:
-        node.ks.touch(*cmd.families)
+        node.ks.touch(*cmd.families, cause="repl_op")
     return reply
 
 

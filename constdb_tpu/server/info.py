@@ -199,11 +199,18 @@ def _section_stats(node, out):
                     round(float(np.percentile(lat_ms, 99)), 3)))
     out.append(("merge_batches", st.merges))
     out.append(("merge_rows", st.merge_rows))
-    out.append(("merge_seconds_total", round(st.merge_secs, 6)))
-    if st.merges and st.merge_secs:
-        out.append(("merge_rows_per_sec",
-                    int(st.merge_rows / st.merge_secs)))
-    out.append(("flush_seconds_total", round(st.flush_secs, 6)))
+    merge_secs = st.secs["merge"]
+    out.append(("merge_seconds_total", round(merge_secs, 6)))
+    if st.merges and merge_secs:
+        out.append(("merge_rows_per_sec", int(st.merge_rows / merge_secs)))
+    out.append(("flush_seconds_total", round(st.secs["flush"], 6)))
+    # the served path's stage clock (utils/stagetime.py): SELF time per
+    # stage in whole microseconds and entries, every declared stage from
+    # boot — nested stages on one thread add up to wall time, so these
+    # can be summed where the inclusive merge_*_seconds totals cannot
+    for name, (us, n) in node.stages.snapshot().items():
+        out.append((f"span_{name}_us", us))
+        out.append((f"span_{name}_n", n))
     fam = getattr(node.engine, "family_secs", None)
     if fam:
         for name, secs in sorted(fam.items()):
@@ -215,6 +222,14 @@ def _section_stats(node, out):
     if rebuilds is not None:
         for name, cnt in sorted(rebuilds.items()):
             out.append((f"mirror_rebuilds_{name}", cnt))
+        # ... by what invalidated the mirror (KeySpace.touch cause), and
+        # the rows each family merged on the device / on its host twin
+        for cause, cnt in node.engine.mirror_rebuild_causes.items():
+            out.append((f"mirror_rebuilds_cause_{cause}", cnt))
+        for path in ("dev", "host"):
+            rows = getattr(node.engine, f"merge_rows_{path}")
+            for name, cnt in rows.items():
+                out.append((f"merge_rows_{path}_{name}", cnt))
     # device-transfer accounting (engine/tpu.py): cumulative host<->device
     # bytes, steady-state micro rounds merged in place against resident
     # planes vs routed to the host fallback, and the dirty-row flush

@@ -517,6 +517,10 @@ class ServerApp:
         parser = make_parser()
         out = bytearray()
         upgraded = False
+        # the node's stage clock (utils/stagetime.py).  `intake` is taken
+        # per socket read around the parser calls only — never across an
+        # await, or another connection's work would be billed to it
+        stage = self.node.stages.stage
         plane = self.serve_plane
         coal = None
         if plane is None and self.serve_batch > 1:
@@ -534,8 +538,12 @@ class ServerApp:
                 if not data:
                     break
                 self.node.stats.net_in_bytes += len(data)
-                parser.feed(data)
                 if coal is None and plane is None:
+                    # the exact per-command loop (CONSTDB_SERVE_BATCH=1):
+                    # its per-message parse is inside a per-operation
+                    # loop and stays untimed
+                    with stage("intake"):
+                        parser.feed(data)
                     while (msg := parser.next_msg()) is not None:
                         if self._is_sync(msg):
                             # replies for commands pipelined BEFORE the
@@ -551,20 +559,35 @@ class ServerApp:
                         if not isinstance(reply, NoReply):
                             encode_into(out, reply)
                 else:
-                    if coal is not None and self.native_intake:
-                        # native intake stage: the C scanner owns every
-                        # leading well-formed flat frame (split +
-                        # classify in one call); whatever it stops at —
-                        # partial frame, SYNC upgrade, malformed bytes,
-                        # nested array — stays buffered for the pure
-                        # drain() below, which keeps the reference
-                        # behavior for those frames byte for byte
-                        while (nat := parser.native_drain()) is not None:
-                            stats = self.node.stats
-                            stats.native_intake_chunks += 1
-                            stats.native_intake_msgs += len(nat[0])
-                            coal.run_native_chunk(nat[0], nat[1], out)
-                    msgs = parser.drain()
+                    # native intake stage: the C scanner owns every
+                    # leading well-formed flat frame (split + classify
+                    # in one call); whatever it stops at — partial
+                    # frame, SYNC upgrade, malformed bytes, nested array
+                    # — stays buffered for the pure drain(), which keeps
+                    # the reference behavior for those frames byte for
+                    # byte.  Each native chunk runs before the next
+                    # scan, and drain() comes last.  One `intake` entry
+                    # a read in the common case: once the scanner has
+                    # taken every buffered byte there is no parse left
+                    # to time, and drain() only hands over what is
+                    # queued.
+                    native = coal is not None and self.native_intake
+                    with stage("intake"):
+                        parser.feed(data)
+                        nat = parser.native_drain() if native else None
+                        msgs = parser.drain() if nat is None else None
+                    while nat is not None:
+                        stats = self.node.stats
+                        stats.native_intake_chunks += 1
+                        stats.native_intake_msgs += len(nat[0])
+                        coal.run_native_chunk(nat[0], nat[1], out)
+                        if not parser.buffered:
+                            msgs = parser.drain()
+                            break
+                        with stage("intake"):
+                            nat = parser.native_drain()
+                            if nat is None:
+                                msgs = parser.drain()
                     for i, msg in enumerate(msgs):
                         if self._is_sync(msg):
                             # messages after the SYNC belong to the
@@ -707,8 +730,11 @@ class ServerApp:
         a SYNC upgrade takes the stream over, so pipelined-before-SYNC
         replies are not dropped."""
         if out:
-            self.node.stats.net_out_bytes += len(out)
-            writer.write(out)
+            # the write only: the `await writer.drain()` that follows at
+            # the call sites is outside the stage
+            with self.node.stages.stage("reply_write"):
+                self.node.stats.net_out_bytes += len(out)
+                writer.write(out)
             out = bytearray()
         return out
 

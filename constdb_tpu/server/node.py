@@ -16,6 +16,7 @@ from typing import Optional
 from ..engine.cpu import CpuMergeEngine
 from ..store.keyspace import KeySpace
 from ..utils.hlc import HLC
+from ..utils.stagetime import StageClock, seconds_into
 from .events import EVENT_REPLICATED, EventBus
 from .repl_log import ReplLog
 
@@ -117,8 +118,11 @@ class NodeStats:
     tracking_demotions: int = 0
     merges: int = 0
     merge_rows: int = 0
-    merge_secs: float = 0.0
-    flush_secs: float = 0.0
+    # inclusive seconds inside engine.merge*/flush as this node called
+    # them (INFO merge_seconds_total / flush_seconds_total; fed by
+    # utils/stagetime.seconds_into — they overlap the stage clock's self
+    # times by design)
+    secs: dict = field(default_factory=lambda: {"merge": 0.0, "flush": 0.0})
     gc_freed: int = 0
     start_time: float = 0.0
     extra: dict = field(default_factory=dict)
@@ -213,6 +217,10 @@ class Node:
         self.repl_log = ReplLog(repl_log_cap)
         self.events = EventBus()
         self.engine = engine if engine is not None else CpuMergeEngine()
+        # the served path's stage clock (utils/stagetime.py; INFO
+        # span_<name>_us / span_<name>_n): a device engine brings one
+        # that also writes trace spans, any other engine gets counters
+        self.stages = getattr(self.engine, "stages", None) or StageClock()
         self.stats = NodeStats()
         # undoable local counter ops (CNTUNDO — server/commands.py)
         self.undo = CounterUndoLog()
@@ -351,11 +359,9 @@ class Node:
         With a device-resident engine, merged state stays on the device
         between calls; it flushes to the host lazily before the next read
         (`ensure_flushed`)."""
-        import time
         self._invalidate_reads((batch,))
-        t0 = time.perf_counter()
-        st = self.engine.merge(self.ks, batch)
-        self.stats.merge_secs += time.perf_counter() - t0
+        with seconds_into(self.stats.secs, "merge"):
+            st = self.engine.merge(self.ks, batch)
         self.stats.merges += 1
         self.stats.merge_rows += batch.n_rows
         self._dump_stale()
@@ -412,11 +418,9 @@ class Node:
             for b in batches:
                 self.merge_batch(b)
             return
-        import time
         self._invalidate_reads(batches)
-        t0 = time.perf_counter()
-        self.engine.merge_many(self.ks, batches)
-        self.stats.merge_secs += time.perf_counter() - t0
+        with seconds_into(self.stats.secs, "merge"):
+            self.engine.merge_many(self.ks, batches)
         self.stats.merges += 1
         self.stats.merge_rows += sum(b.n_rows for b in batches)
         if len(batches) > 1:
@@ -534,10 +538,8 @@ class Node:
         before any read/write of the numeric plane."""
         engine = self.engine
         if getattr(engine, "needs_flush", False):
-            import time
-            t0 = time.perf_counter()
-            engine.flush(self.ks)
-            self.stats.flush_secs += time.perf_counter() - t0
+            with seconds_into(self.stats.secs, "flush"):
+                engine.flush(self.ks)
 
     def ensure_flushed_for(self, families) -> None:
         """Flush only when unflushed device-resident state actually

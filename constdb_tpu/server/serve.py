@@ -204,7 +204,7 @@ class ServeCoalescer:
     __slots__ = ("node", "max_run", "nodeid", "ks", "regs", "cnts", "els",
                  "tns", "_keys", "_pending_keys", "_buf", "_log",
                  "_pending", "_planned", "_lat_pending", "_sample_every",
-                 "_now", "_cur_uuid", "client")
+                 "_now", "_cur_uuid", "client", "_stage")
 
     def __init__(self, node, max_run: int = 512,
                  sample_every: int | None = None,
@@ -218,6 +218,10 @@ class ServeCoalescer:
         self.max_run = max_run
         self.nodeid = node.node_id
         self.ks = node.ks
+        # the node's stage clock (utils/stagetime.py): plan / read_batch /
+        # read_miss / exec are per-chunk counters, serve_flush also a
+        # trace span; none is held across an await (this class has none)
+        self._stage = node.stages.stage
         # per-chunk overlay caches: landed-state probes (seeded in bulk
         # by _preprobe) overlaid with the pending run's own writes.
         # Reset at chunk entry; a mid-chunk barrier invalidates only the
@@ -259,6 +263,14 @@ class ServeCoalescer:
         uuid instead of ticking.  `spans`: when given, receives
         `len(out)` after each message — the parent slices per-command
         replies out for in-order reassembly across shards."""
+        with self._stage("plan"):
+            self._plan_chunk(msgs, out, uuids, spans)
+
+    def _plan_chunk(self, msgs: list, out: bytearray, uuids: list,
+                    spans: list) -> None:
+        """run_chunk's body, under its `plan` stage (whose self time
+        excludes the read batches, per-command executions and flushes
+        nested in it)."""
         self._reset_caches()
         if len(msgs) == 1:
             # lone command: the exact per-command path, zero overhead
@@ -396,6 +408,12 @@ class ServeCoalescer:
         the differential).  Never used on the sharded plane — io.py
         builds a coalescer only when no plane is active — so there are
         no pre-minted uuids or reply spans here."""
+        with self._stage("plan"):
+            self._plan_native_chunk(ops, payloads, out)
+
+    def _plan_native_chunk(self, ops: bytes, payloads: list,
+                           out: bytearray) -> None:
+        """run_native_chunk's body, under its `plan` stage."""
         self._reset_caches()
         n = len(ops)
         if n == 1:
@@ -880,6 +898,14 @@ class ServeCoalescer:
         stream position).  `extras`: reply bytes of commands executed
         while the run stayed open — `(msg_index, payload)`, spliced
         back at their exact positions."""
+        with self._stage("read_batch"):
+            self._read_batch(specs, out, spans, extras)
+
+    def _read_batch(self, specs: list, out: bytearray, spans,
+                    extras) -> None:
+        """_run_read_batch's body under its `read_batch` stage: the
+        reply-cache probe and the splice of hits; what the cache cannot
+        answer goes on to _read_misses."""
         node = self.node
         st = node.stats
         cl = self.client
@@ -932,6 +958,20 @@ class ServeCoalescer:
                 if spans is not None:
                     spans.append(len(out))
             return
+        with self._stage("read_miss"):
+            self._read_misses(specs, hits, miss, out, spans, extras)
+
+    def _read_misses(self, specs: list, hits: list, miss: list,
+                     out: bytearray, spans, extras) -> None:
+        """The miss branch of a planned read run, under its `read_miss`
+        stage: key resolution, family gathers, reply build, cache fill —
+        and the in-order emit of hits and misses alike."""
+        node = self.node
+        st = node.stats
+        ks = self.ks
+        rc = node.read_cache
+        use_cache = rc.enabled
+        n = len(specs)
         resolved: dict = {}
         env: dict = {}
         if miss:
@@ -1191,13 +1231,15 @@ class ServeCoalescer:
         write executed per-command by CHOICE is not a barrier, but its
         mutation still invalidates its key's cached probes."""
         node = self.node
-        reply = node.execute(msg, client=self.client, uuid=self._cur_uuid)
-        if not isinstance(reply, NoReply):
-            encode_into(out, reply)
-        if count_barrier:
-            node.stats.serve_barriers += 1
-        if invalidate:
-            self._invalidate_after(msg)
+        with self._stage("exec"):
+            reply = node.execute(msg, client=self.client,
+                                 uuid=self._cur_uuid)
+            if not isinstance(reply, NoReply):
+                encode_into(out, reply)
+            if count_barrier:
+                node.stats.serve_barriers += 1
+            if invalidate:
+                self._invalidate_after(msg)
 
     def _invalidate_after(self, msg) -> None:
         """Drop exactly the cached state a just-executed barrier could
@@ -1323,6 +1365,13 @@ class ServeCoalescer:
             return
         self._pending_keys.clear()
         log, self._log = self._log, []
+        with self._stage("serve_flush"):
+            self._land(buf, n, log)
+
+    def _land(self, buf: dict, n: int, log: list) -> None:
+        """flush()'s body under its `serve_flush` stage (self time: the
+        group encode, the repl_log append, the event trigger — the
+        engine's stages nest inside and are excluded)."""
         node = self.node
         bb = BatchBuilder(node.ks)
         nodeid = self.nodeid

@@ -50,6 +50,10 @@ _I64 = np.int64
 # the CRDT planes a resident merge engine mirrors — the ONE definition the
 # command table, the version setter, and the engine all derive from
 FAMILIES = ("env", "reg", "cnt", "el", "tns")
+# why a plane was marked host-modified (KeySpace.touch): a resident engine
+# counts its mirror rebuilds by the cause that invalidated the mirror
+# (INFO mirror_rebuilds_cause_<cause>)
+TOUCH_CAUSES = ("client_op", "repl_op", "reset", "expire", "gc", "compact")
 
 
 def _blen(x) -> int:
@@ -167,6 +171,8 @@ class KeySpace:
         # that actually changed (engine/tpu.py; a global version made
         # mixed traffic re-upload every table per frame)
         self.fam_ver: dict[str, int] = dict.fromkeys(FAMILIES, 0)
+        # the cause of each plane's LAST version bump (TOUCH_CAUSES)
+        self.fam_cause: dict[str, str] = dict.fromkeys(FAMILIES, "reset")
 
         self.cnt = _CntCols()
         # per-rank direct (kid -> cnt row) index windows: counter slot
@@ -255,11 +261,17 @@ class KeySpace:
 
     # ------------------------------------------------------------- versions
 
-    def touch(self, *families: str) -> None:
-        """Mark CRDT planes as host-modified (op path / GC)."""
+    def touch(self, *families: str, cause: str = "client_op") -> None:
+        """Mark CRDT planes as host-modified (op path / GC), and keep why
+        (`cause`, one of TOUCH_CAUSES) as each plane's last cause."""
+        if cause not in TOUCH_CAUSES:
+            raise ValueError(f"touch cause {cause!r} is not one of "
+                             f"{TOUCH_CAUSES}")
         fv = self.fam_ver
+        fc = self.fam_cause
         for f in families:
             fv[f] += 1
+            fc[f] = cause
 
     @property
     def version(self) -> int:
@@ -269,7 +281,7 @@ class KeySpace:
     @version.setter
     def version(self, _value) -> None:
         """`ks.version += 1` keeps meaning "everything may have changed"."""
-        self.touch(*FAMILIES)
+        self.touch(*FAMILIES, cause="reset")
 
     # ------------------------------------------------------------------ keys
 
@@ -318,7 +330,7 @@ class KeySpace:
             # this is a READ-path host write: without the bump a resident
             # env mirror would flush its older dt back and resurrect the
             # expired key
-            self.touch("env")
+            self.touch("env", cause="expire")
         return kid
 
     def alive(self, kid: int) -> bool:
@@ -1114,7 +1126,7 @@ class KeySpace:
             # which REORDERS rows) must invalidate them or later flushes
             # write stale columns over the collected table.  key_deletes-only
             # rounds touch no mirrored column and skip the bump.
-            self.touch("el")
+            self.touch("el", cause="gc")
         if self.el_dead > 10_000 and self.el_dead * 2 > self.el.n:
             self._compact_elements()
         return freed
@@ -1123,7 +1135,8 @@ class KeySpace:
         """Rebuild element storage without dead rows (replaces free-list
         reuse: row ids must stay stable BETWEEN compactions so the batched
         engine's staged row indices never alias)."""
-        self.touch("el")  # row ids change: resident device mirrors are stale
+        # row ids change: resident device mirrors are stale
+        self.touch("el", cause="compact")
         # row ids are about to change: the digest's member-crc cache is
         # row-aligned and must rebuild from the compacted columns.  The
         # lock orders this against an off-loop warm_digest_caches pass:

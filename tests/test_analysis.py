@@ -121,6 +121,13 @@ def test_corpus_expectations(corpus_findings):
         {"self._crc_lock", "self._stream_lock:open",
          "self._stream_lock:.result()"}
     assert not any("fixed" in f.qualname for f in ld)
+    # STAGE-AWAIT: an await and an `async for` inside an open stage; the
+    # write-only form and a nested def's own await stay silent
+    sa = by["STAGE-AWAIT"]
+    assert {f.token for f in sa} == \
+        {"self.node.stages.stage", "self._stage"}
+    assert {f.qualname.rsplit(".", 1)[-1] for f in sa} == \
+        {"reply_bad", "relay_bad"}
 
 
 def test_findings_have_location_and_hint(corpus_findings):
