@@ -99,6 +99,7 @@ def _worker_stats(node) -> dict:
         # per shard — server/serve_shards.py _fold_stats)
         "reads": st.serve_reads_coalesced,
         "read_flushes": st.serve_read_flushes,
+        "reads_direct": st.serve_read_replies_direct,
         "cache_hits": rc.hits,
         "cache_misses": rc.misses,
         "cache_inv": rc.invalidations,

@@ -23,6 +23,7 @@ _COMPACT_THRESHOLD = 1 << 16
 # interned small-int reply lines (parity: reference src/resp.rs:12-27
 # pre-encodes the common counter replies)
 _INT_REPLY = [b":%d\r\n" % i for i in range(1024)]
+_NIL_REPLY = b"$-1\r\n"
 
 _DEFAULT_MAX_BULK = 512 << 20  # Redis proto-max-bulk-len default
 _MAX_BULK_CACHE: list = []
@@ -70,8 +71,7 @@ def _py_encode_into(out: bytearray, m: Msg) -> None:
         out += m.val
         out += _CRLF
     elif isinstance(m, Int):
-        v = m.val
-        out += _INT_REPLY[v] if 0 <= v < 1024 else b":%d\r\n" % v
+        out += int_reply(m.val)
     elif isinstance(m, Bulk):
         out += b"$%d\r\n" % len(m.val)
         out += m.val
@@ -100,6 +100,79 @@ def encode_msg(m: Msg) -> bytes:
     out = bytearray()
     encode_into(out, m)
     return bytes(out)
+
+
+# row-reply kinds -> the codes both tiers take (native/resp.cpp
+# resp_encode_rows); an LRANGE reaches them as "values" over its sorted,
+# sliced rows
+_ROW_KINDS = {"members": 0, "pairs": 1, "values": 2}
+
+
+def encode_rows_into(out: bytearray, kind: str, rows: list, el_member: list,
+                     el_val: list, start: int = 0, stop: int = -1) -> bytes:
+    """Append the reply of one planned row-scan read straight from the
+    element blob planes — the bytes `encode_into` gives for the Msg tree
+    of the per-command handler (server/commands.py), with no tree built —
+    and return the appended payload, which the reply cache stores as is.
+    `rows`: the key's live element rows (KeySpace.elem_live_rows_batch).
+    `kind` is the read's SERVE_READS kind: "members" (SMEMBERS: one bulk
+    per member), "pairs" (HGETALL: `*2` of member and value per row) or
+    "lrange" (the handler's order and inclusive `start`..`stop` slice,
+    then one bulk per value); a None value is the empty bulk.  Native
+    pass when the extension has it, bit-identical pure twin otherwise
+    (and for any shape the C pass declines)."""
+    code = _ROW_KINDS["values" if kind == "lrange" else kind]
+    if kind == "lrange":
+        # the handler sorts (position, value) pairs; live positions of
+        # one list are distinct, so the position alone orders them
+        n = len(rows)
+        if start < 0:
+            start += n
+        if stop < 0:
+            stop += n
+        start = max(0, start)
+        rows = sorted(rows, key=el_member.__getitem__)[start:stop + 1] \
+            if stop >= start else []
+    enc = _enc_rows()
+    if enc is not None:
+        payload = enc(out, code, rows, el_member, el_val)
+        if payload is not None:
+            return payload
+    return _py_encode_rows_into(out, code, rows, el_member, el_val)
+
+
+def _py_encode_rows_into(out: bytearray, code: int, rows: list,
+                         el_member: list, el_val: list) -> bytes:
+    """encode_rows_into's pure tier; `code` as the native pass takes it."""
+    parts = [b"*%d\r\n" % len(rows)]
+    add = parts.append
+    if code == 0:
+        for r in rows:
+            m = el_member[r]
+            add(b"$%d\r\n%b\r\n" % (len(m), m))
+    elif code == 1:
+        for r in rows:
+            m = el_member[r]
+            v = el_val[r] or b""
+            add(b"*2\r\n$%d\r\n%b\r\n$%d\r\n%b\r\n"
+                % (len(m), m, len(v), v))
+    else:
+        for r in rows:
+            v = el_val[r] or b""
+            add(b"$%d\r\n%b\r\n" % (len(v), v))
+    payload = b"".join(parts)
+    out += payload
+    return payload
+
+
+def bulk_reply(v: Optional[bytes]) -> bytes:
+    """The wire bytes of `Bulk(v)`, or of NIL for None."""
+    return _NIL_REPLY if v is None else b"$%d\r\n%b\r\n" % (len(v), v)
+
+
+def int_reply(v: int) -> bytes:
+    """The wire bytes of `Int(v)` (small ones interned)."""
+    return _INT_REPLY[v] if 0 <= v < 1024 else b":%d\r\n" % v
 
 
 class _NeedMore(Exception):
@@ -445,6 +518,7 @@ class NativeRespParser(RespParser):
 
 _EXT_CACHE: list = []
 _ENC_CACHE: list = []
+_ENC_ROWS_CACHE: list = []
 _INTAKE_CACHE: list = []
 
 
@@ -465,6 +539,16 @@ def _enc():
         from ..utils.native_tables import load_ext
         _ENC_CACHE.append(getattr(load_ext(), "resp_encode", None))
     return _ENC_CACHE[0]
+
+
+def _enc_rows():
+    """The native row-reply encoder entry point, or None (gated like
+    _enc: a cst_ext.so from before it existed degrades to the pure
+    twin)."""
+    if not _ENC_ROWS_CACHE:
+        from ..utils.native_tables import load_ext
+        _ENC_ROWS_CACHE.append(getattr(load_ext(), "resp_encode_rows", None))
+    return _ENC_ROWS_CACHE[0]
 
 
 def _intake():

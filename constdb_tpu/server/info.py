@@ -174,6 +174,7 @@ def _section_stats(node, out):
         if k.startswith("serve_shard") and k.endswith("_cache_bytes"))
     out.append(("read_cache_hits", rc.hits))
     out.append(("read_cache_misses", rc.misses))
+    out.append(("serve_read_replies_direct", st.serve_read_replies_direct))
     out.append(("read_cache_bytes", rc_bytes))
     out.append(("read_cache_invalidations", rc.invalidations))
     # overload governance (server/overload.py): client writes shed at

@@ -94,6 +94,13 @@ class NodeStats:
     # deltas into the parent's cache counters (server/serve_shards.py).
     serve_reads_coalesced: int = 0
     serve_read_flushes: int = 0
+    # planned reads the reply cache could not answer whose reply the
+    # stitch wrote as wire bytes straight from the gathers (resp/codec.py
+    # encode_rows_into and its single-value twins; absent-key constants
+    # included) — every read-cache miss except a demotion, so
+    # serve_read_replies_direct / read_cache_misses is the share of
+    # misses that built no Msg tree
+    serve_read_replies_direct: int = 0
     # native intake stage (native/intake.cpp + server/io.py): pipelined
     # chunks split+classified by the C scanner in one call, and the
     # command frames it emitted as opcodes (CONSTDB_NATIVE_INTAKE=0 or a

@@ -90,7 +90,8 @@ import time
 import numpy as np
 
 from ..errors import CstError
-from ..resp.codec import encode_into
+from ..resp.codec import (bulk_reply, encode_into, encode_rows_into,
+                          int_reply)
 from ..resp.message import (Arr, Bulk, Int, NIL, NoReply, OK, as_bytes,
                             as_int)
 from ..replica.coalesce import BatchBuilder
@@ -1111,6 +1112,7 @@ class ServeCoalescer:
                 cacheable[j] = True
         st.cmds_processed += planned
         st.serve_reads_coalesced += planned
+        st.serve_read_replies_direct += planned - (n - len(miss))
         # ---- vectorized family gathers for the misses
         scan_rows: list = []
         if miss_scan:
@@ -1128,9 +1130,10 @@ class ServeCoalescer:
         reg_vals: list = []
         if miss_reg:
             reg_vals = ks.register_get_batch(miss_reg)
-        # ---- stitch: encode miss replies, emit everything in order
-        # (splicing deferred non-read replies back at their exact
-        # positions), fill the cache from the just-encoded bytes
+        # ---- stitch: write miss replies as wire bytes straight from the
+        # gathers (resp/codec.py's direct encoders: no Msg tree), emit
+        # everything in order (splicing deferred non-read replies back at
+        # their exact positions), fill the cache from the bytes written
         el_member, el_val = ks.el_member, ks.el_val
         ei, ne = 0, len(extras) if extras else 0
         for j, sp in enumerate(specs):
@@ -1142,57 +1145,35 @@ class ServeCoalescer:
             slot = slots[j]
             if type(slot) is tuple:
                 kind, ref = slot
-                spec = sp[2]
-                if kind == "cnt":
-                    reply = Int(cnt_vals[ref])
-                elif kind == "reg":
-                    v = reg_vals[ref]
-                    reply = Bulk(v if v is not None else b"")
-                elif kind == "probe":
-                    row = int(probe_rows[ref])
-                    ok = row >= 0 and bool(probe_alive[ref])
-                    if spec.kind == "ismember":
-                        reply = Int(1 if ok else 0)
-                    else:
-                        v = el_val[row] if ok else None
-                        reply = Bulk(v) if v is not None else NIL
-                else:  # scan
+                k2 = sp[2].kind
+                if kind == "scan":
                     rows = scan_rows[ref].tolist()
-                    k2 = spec.kind
-                    if k2 == "members":
-                        reply = Arr([Bulk(el_member[r]) for r in rows])
-                    elif k2 == "card":
-                        reply = Int(len(rows))
-                    elif k2 == "llen":
-                        reply = Int(len(rows))
-                    elif k2 == "pairs":
-                        reply = Arr([Arr([Bulk(el_member[r]),
-                                          Bulk(el_val[r]
-                                               if el_val[r] is not None
-                                               else b"")])
-                                     for r in rows])
-                    else:  # lrange — the handler's sort + slice, exactly
-                        live = sorted((el_member[r], el_val[r])
-                                      for r in rows)
-                        start, stop = sp[6]
-                        nv = len(live)
-                        if start < 0:
-                            start += nv
-                        if stop < 0:
-                            stop += nv
-                        start = max(0, start)
-                        if stop < start:
-                            reply = Arr([])
+                    if k2 == "card" or k2 == "llen":
+                        payload = int_reply(len(rows))
+                        out += payload
+                    elif k2 == "lrange":
+                        payload = encode_rows_into(out, k2, rows, el_member,
+                                                   el_val, *sp[6])
+                    else:  # members / pairs
+                        payload = encode_rows_into(out, k2, rows, el_member,
+                                                   el_val)
+                else:
+                    if kind == "cnt":
+                        payload = int_reply(cnt_vals[ref])
+                    elif kind == "reg":
+                        payload = bulk_reply(reg_vals[ref] or b"")
+                    else:  # probe
+                        row = int(probe_rows[ref])
+                        ok = row >= 0 and bool(probe_alive[ref])
+                        if k2 == "ismember":
+                            payload = int_reply(1 if ok else 0)
                         else:
-                            reply = Arr([Bulk(v if v is not None else b"")
-                                         for _m, v in
-                                         live[start:stop + 1]])
-                pos = len(out)
-                encode_into(out, reply)
+                            payload = bulk_reply(el_val[row] if ok else None)
+                    out += payload
                 if cacheable[j]:
                     e = env[j]
                     rc.put(sp[3], sp[4], sp[5], resolved[j][0], ks,
-                           bytes(out[pos:]), env=(e[0], e[1]))
+                           payload, env=(e[0], e[1]))
             else:
                 out += slot
             if spans is not None:

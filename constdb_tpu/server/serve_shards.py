@@ -360,6 +360,8 @@ class ServeShardPlane:
         st.serve_reads_coalesced += stats["reads"] - last.get("reads", 0)
         st.serve_read_flushes += \
             stats["read_flushes"] - last.get("read_flushes", 0)
+        st.serve_read_replies_direct += \
+            stats["reads_direct"] - last.get("reads_direct", 0)
         rc = node.read_cache
         rc.hits += stats["cache_hits"] - last.get("cache_hits", 0)
         rc.misses += stats["cache_misses"] - last.get("cache_misses", 0)

@@ -343,6 +343,9 @@ PyMethodDef methods[] = {
     {"resp_encode", py_resp_encode, METH_VARARGS,
      "resp_encode(out, msg, Arr, Bulk, Int, Simple, Err, NilT, NoReplyT) "
      "-> appended? (False = caller must use the pure-Python encoder)"},
+    {"resp_encode_rows", py_resp_encode_rows, METH_VARARGS,
+     "resp_encode_rows(out, kind, rows, members, vals) -> appended payload "
+     "bytes | None (None = caller must use the pure-Python twin)"},
     {"intake_scan", py_intake_scan, METH_VARARGS,
      "intake_scan(buf, pos, Arr, Bulk, Int, Simple, Err, nil[, max_bulk, "
      "max_msgs]) -> (ops, payloads, new_pos)"},

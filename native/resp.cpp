@@ -39,6 +39,7 @@
 
 #include <cstring>
 #include <string>
+#include <vector>
 
 namespace resp {
 
@@ -434,4 +435,133 @@ static PyObject* py_resp_encode(PyObject*, PyObject* args) {
         return nullptr;
     memcpy(PyByteArray_AS_STRING(out) + old, buf.data(), buf.size());
     Py_RETURN_TRUE;
+}
+
+// ----------------------------------------------------- row-reply encoder
+//
+// resp_encode_rows(out_bytearray, kind, rows, members, vals) -> bytes | None
+// appends the wire reply of one planned row-scan read (server/serve.py
+// _read_misses) straight from the element blob planes — no Arr/Bulk tree —
+// and returns the appended payload (the reply cache stores it as is):
+//   kind 0  members: *<n> then one bulk of members[r] per row
+//   kind 1  pairs:   *<n> then *2 + bulk members[r] + bulk vals[r] per row
+//   kind 2  values:  *<n> then one bulk of vals[r] per row
+// a None value is the empty bulk.  One size pre-pass, one memcpy per
+// piece.  Returns None, with nothing appended, for any shape it will not
+// encode (non-list, row out of range, non-bytes blob): the caller's pure
+// twin then encodes it or raises its own error, keeping behavior
+// identical.
+
+namespace resp {
+
+struct Blob {
+    const char* p;
+    Py_ssize_t n;
+};
+
+inline int dec_digits(Py_ssize_t v) {
+    int d = 1;
+    while (v >= 10) {
+        v /= 10;
+        d++;
+    }
+    return d;
+}
+
+inline char* put_head(char* w, char tag, Py_ssize_t v) {
+    *w++ = tag;
+    int d = dec_digits(v);
+    for (int i = d - 1; i >= 0; i--) {
+        w[i] = static_cast<char>('0' + v % 10);
+        v /= 10;
+    }
+    w += d;
+    *w++ = '\r';
+    *w++ = '\n';
+    return w;
+}
+
+inline char* put_bulk(char* w, const Blob& b) {
+    w = put_head(w, '$', b.n);
+    if (b.n) memcpy(w, b.p, static_cast<size_t>(b.n));
+    w += b.n;
+    *w++ = '\r';
+    *w++ = '\n';
+    return w;
+}
+
+// bytes -> its buffer, None -> empty (when allowed); false = decline
+inline bool blob_of(PyObject* list, Py_ssize_t r, bool none_ok, Blob* b) {
+    PyObject* o = PyList_GET_ITEM(list, r);
+    if (PyBytes_CheckExact(o)) {
+        b->p = PyBytes_AS_STRING(o);
+        b->n = PyBytes_GET_SIZE(o);
+        return true;
+    }
+    if (none_ok && o == Py_None) {
+        b->p = "";
+        b->n = 0;
+        return true;
+    }
+    return false;
+}
+
+}  // namespace resp
+
+static PyObject* py_resp_encode_rows(PyObject*, PyObject* args) {
+    PyObject *out, *rows, *members, *vals;
+    int kind;
+    if (!PyArg_ParseTuple(args, "OiOOO", &out, &kind, &rows, &members,
+                          &vals))
+        return nullptr;
+    if (!PyByteArray_CheckExact(out)) {
+        PyErr_SetString(PyExc_TypeError, "out must be a bytearray");
+        return nullptr;
+    }
+    if (kind < 0 || kind > 2 || !PyList_CheckExact(rows) ||
+        !PyList_Check(members) || !PyList_Check(vals))
+        Py_RETURN_NONE;
+    const Py_ssize_t n = PyList_GET_SIZE(rows);
+    const Py_ssize_t n_members = PyList_GET_SIZE(members);
+    const Py_ssize_t n_vals = PyList_GET_SIZE(vals);
+    const int per = kind == 1 ? 2 : 1;
+    std::vector<resp::Blob> blobs(static_cast<size_t>(n * per));
+    // "*<n>\r\n", then per blob "$<len>\r\n<bytes>\r\n", per pair "*2\r\n"
+    Py_ssize_t total = 3 + resp::dec_digits(n) + (kind == 1 ? 4 * n : 0);
+    resp::Blob* b = blobs.data();
+    for (Py_ssize_t j = 0; j < n; j++) {
+        PyObject* ro = PyList_GET_ITEM(rows, j);
+        if (!PyLong_CheckExact(ro)) Py_RETURN_NONE;
+        Py_ssize_t r = PyLong_AsSsize_t(ro);
+        if (r < 0) {  // negative index or overflow: the pure twin's case
+            PyErr_Clear();
+            Py_RETURN_NONE;
+        }
+        if (kind != 2) {
+            if (r >= n_members || !resp::blob_of(members, r, false, b))
+                Py_RETURN_NONE;
+            total += 5 + resp::dec_digits(b->n) + b->n;
+            b++;
+        }
+        if (kind != 0) {
+            if (r >= n_vals || !resp::blob_of(vals, r, true, b))
+                Py_RETURN_NONE;
+            total += 5 + resp::dec_digits(b->n) + b->n;
+            b++;
+        }
+    }
+    const Py_ssize_t old = PyByteArray_GET_SIZE(out);
+    if (PyByteArray_Resize(out, old + total)) return nullptr;
+    char* const base = PyByteArray_AS_STRING(out) + old;
+    char* w = resp::put_head(base, '*', n);
+    b = blobs.data();
+    for (Py_ssize_t j = 0; j < n; j++) {
+        if (kind == 1) {
+            memcpy(w, "*2\r\n", 4);
+            w += 4;
+            w = resp::put_bulk(w, *b++);
+        }
+        w = resp::put_bulk(w, *b++);
+    }
+    return PyBytes_FromStringAndSize(base, total);
 }
