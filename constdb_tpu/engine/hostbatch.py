@@ -124,6 +124,7 @@ def _merge_reg(store: KeySpace, kids: np.ndarray, t: np.ndarray,
     rows = wk[win]
     store.keys.rv_t[rows] = wt[win]
     store.keys.rv_node[rows] = wn[win]
+    store.journal["reg"].add_rows(rows)
     reg_val = store.reg_val
     for r, i in zip(rows.tolist(), src[win].tolist()):
         reg_val[r] = vals[i]
@@ -178,6 +179,7 @@ def _apply_cnt_pair(store: KeySpace, rows: np.ndarray, vals: np.ndarray,
     dv = wv[win] - cur_v[win]
     cv[rows_w] = wv[win]
     ct[rows_w] = wt[win]
+    store.journal["cnt"].add_rows(rows_w)
     changed = np.nonzero(dv)[0]
     if not len(changed):
         return
@@ -237,6 +239,7 @@ def _merge_el(store: KeySpace, rows: np.ndarray, at: np.ndarray,
     store.el.add_t[wr] = new_at
     store.el.add_node[wr] = np.where(win, wan, old_an)
     store.el.del_t[wr] = new_dt
+    store.journal["el"].add_rows(wr)
     # winner-carried values (None included — a winning valueless write
     # CLEARS the slot); set members are valueless on both sides, so only
     # value-carrying encodings pay the assignment loop.  Three equality

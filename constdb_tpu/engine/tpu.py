@@ -19,8 +19,11 @@ replica link applies a snapshot chunk-by-chunk, and each chunk is one
 Merged state flushes back to the host keyspace lazily (`flush()`), which
 the Node triggers before any command touches the numeric plane
 (`Node.ensure_flushed`); op-path writes bump the touched plane's
-`KeySpace.fam_ver` entry, so the engine rebuilds ONLY that plane's mirror
-(mixed op/merge traffic keeps the other mirrors resident).  Win VALUES
+`KeySpace.fam_ver` entry and journal the rows they wrote
+(`KeySpace.journal`), so the engine repairs ONLY that plane's mirror, by
+scattering those rows (`_patch_mirror`; a whole rebuild only after GC,
+compaction, a reset or a journal over its limit — mixed op/merge traffic
+keeps every mirror resident).  Win VALUES
 (dict fields / register bytes) resolve through a device src plane at
 flush — no per-call win-flag download; value bytes live only on the host.
 
@@ -56,7 +59,8 @@ import numpy as np
 from ..crdt import semantics as S
 from ..ops import bulk as B
 from ..ops import segment as K
-from ..store.keyspace import FAMILIES, TOUCH_CAUSES, KeySpace
+from ..store.keyspace import (FAMILIES, JOURNAL_FAMILIES, JOURNAL_MAX_ROWS,
+                              TOUCH_CAUSES, KeySpace)
 from ..utils.stagetime import StageClock, seconds_into
 from .base import ColumnarBatch, MergeStats, has_values
 from .hostbatch import HOST_MICRO_MAX
@@ -194,6 +198,12 @@ class TpuMergeEngine:
     # see _micro_scatter_pair.)
     MICRO_SCATTER_PAD = 256
     FLUSH_GATHER_PAD = 512
+    # a stale mirror's journaled rows (store/keyspace.py RowJournal) pad
+    # up to one of these before they scatter, so a family has at most
+    # three patch programs per plane cap, compiled at the flush before the
+    # mirror can go stale (_warm_patch); a journal over the largest falls
+    # back to the whole-plane rebuild
+    MIRROR_PATCH_BUCKETS = (1 << 10, 1 << 13, JOURNAL_MAX_ROWS)
     # staging order = dispatch order = the on-store plane contract
     FAM_ORDER = ("env", "reg", "cnt", "el")
 
@@ -267,6 +277,14 @@ class TpuMergeEngine:
         # ... and what invalidated the mirror each rebuild replaced (the
         # family's last KeySpace.touch cause)
         self.mirror_rebuild_causes = dict.fromkeys(TOUCH_CAUSES, 0)
+        # ... and the stale mirrors repaired in place instead: patches and
+        # the distinct rows they scattered, per journaled family, and the
+        # rebuilds taken because a journal outgrew the largest bucket
+        # (engagement = patches / (patches + rebuilds))
+        self.mirror_patches = dict.fromkeys(JOURNAL_FAMILIES, 0)
+        self.mirror_patch_rows = dict.fromkeys(JOURNAL_FAMILIES, 0)
+        self.mirror_patch_overflows = 0
+        self._patch_warm: dict[str, int] = {}   # fam -> cap warmed at
         # rows merged per family and per path (INFO merge_rows_dev_<fam> /
         # merge_rows_host_<fam>): on the device path the rows handed to
         # the scatter AFTER the host fold, on the host path the rows the
@@ -817,6 +835,7 @@ class TpuMergeEngine:
 
     def _flush_resident(self, store: KeySpace) -> None:
         """flush()'s body, under its `d2h_flush` stage."""
+        self._warm_patch()
         pending: dict[str, dict] = {}
         partial: dict[str, tuple] = {}  # fam -> (rows_d, {name: dev}, src)
         for fam, res in self._res.items():
@@ -1106,38 +1125,53 @@ class TpuMergeEngine:
 
         Staleness: the mirror records the host plane's write version at
         build time; an op-path write or GC to THIS plane (KeySpace.touch)
-        forces a rebuild from host — other planes' mirrors survive.
+        makes it stale — other planes' mirrors survive.  A stale mirror
+        of a journaled family is REPAIRED: the rows written since it last
+        equalled the host are in the plane's RowJournal, and one scatter
+        sets them (`_patch_mirror`).  Only a journal that is whole (GC,
+        compaction, a reset), over the largest bucket, or not this
+        engine's to trust forces the whole-plane rebuild from host.
 
         `micro`: the caller is the steady scatter path, which keeps LWW
         pair columns PRE-SPLIT as hi/lo 32-bit planes between rounds
         (`res["split"]` — ops/pallas_dense.py scatter_pair_src_split).
-        Bulk callers (micro=False) and the grow path speak int64, so
-        they JOIN any split cache back into `cols` first; the micro
-        reuse path leaves the split intact — that is the whole point of
-        the layout (zero O(plane) split/join passes in steady state)."""
+        Bulk callers (micro=False), the grow path and the patch speak
+        int64, so they JOIN any split cache back into `cols` first; the
+        micro reuse path leaves the split intact — that is the whole
+        point of the layout (zero O(plane) split/join passes in steady
+        state)."""
         res = self._res.get(fam)
         ver = store.fam_ver[fam]
+        stale = res is not None and res.get("ver") != ver
         if res is not None and res.get("split") and \
-                (not micro or n > res["cap"]):
+                (not micro or stale or n > res["cap"]):
             self._join_split(res)
-        if res is not None and res.get("ver") != ver:
-            # rebuild from host.  A stale mirror never holds unflushed
-            # device data: the Node flushes before every op-path write, so
-            # whatever bumped this plane's version found the mirror already
-            # synced.  (needs_flush may be True here from EARLIER families
-            # of this same merge round — their mirrors are not stale.)
-            # Dropping a stale mirror that still holds unflushed merged
-            # columns would silently lose merge results — that is a broken
-            # flush-before-touch invariant somewhere upstream; fail loud
-            # (a real raise, not an assert: `python -O` must not strip the
-            # only guard between a dispatch-table bug and silent data loss)
+        journal = store.journal.get(fam) if self._mesh is None else None
+        rows = None
+        if stale:
+            # A stale mirror never holds unflushed device data: the Node
+            # flushes before every op-path write, so whatever bumped this
+            # plane's version found the mirror already synced.
+            # (needs_flush may be True here from EARLIER families of this
+            # same merge round — their mirrors are not stale.)  Patching
+            # over or dropping a stale mirror that still holds unflushed
+            # merged columns would silently lose merge results — that is a
+            # broken flush-before-touch invariant somewhere upstream; fail
+            # loud (a real raise, not an assert: `python -O` must not
+            # strip the only guard between a dispatch-table bug and
+            # silent data loss)
             if res.get("written"):
                 raise RuntimeError(
                     f"{fam} mirror invalidated with unflushed merge data "
                     "(flush-before-touch invariant broken upstream)")
-            self.mirror_rebuilds[fam] += 1
-            self.mirror_rebuild_causes[store.fam_cause[fam]] += 1
-            res = None
+            if journal is not None and res.get("jepoch") == journal.epoch:
+                rows = journal.take()
+                if rows is None and journal.over:
+                    self.mirror_patch_overflows += 1
+            if rows is None:
+                self.mirror_rebuilds[fam] += 1
+                self.mirror_rebuild_causes[store.fam_cause[fam]] += 1
+                res = None
         cap = self._sp_size(n)
         spec = _FAMILIES[fam]
         if res is None:
@@ -1165,10 +1199,20 @@ class TpuMergeEngine:
         else:
             cols = res["cols"]
             cap = res["cap"]
+        if rows is not None:
+            # (rows past the old `n` ride the same scatter, after the grow)
+            self._patch_mirror(store, fam, cols, rows)
+        # mirror == host from here: the journal starts over, and the
+        # epoch says whose it is
+        jepoch = res.get("jepoch") if res else None
+        if journal is not None and (res is None or rows is not None):
+            jepoch = journal.reset()
         # `dirty`/`recon`/`split` survive a reuse/grow (the micro path
         # appends touched rows between flushes); a fresh build starts
-        # CLEAN (dirty=[] — host == device at build, nothing to download)
+        # CLEAN (dirty=[] — host == device at build, nothing to download).
+        # A patch writes nothing the host lacks: neither written nor dirty
         self._res[fam] = {"cols": cols, "n": n, "cap": cap, "ver": ver,
+                          "jepoch": jepoch,
                           "src": res.get("src") if res else None,
                           "written": res.get("written", set()) if res
                           else set(),
@@ -1176,6 +1220,58 @@ class TpuMergeEngine:
                           "split": res.get("split") if res else None,
                           "dirty": res.get("dirty") if res else []}
         return cols, cap
+
+    def _patch_mirror(self, store: KeySpace, fam: str, cols: dict,
+                      rows: np.ndarray) -> None:
+        """Repair a stale mirror in place: gather the host columns at the
+        journaled `rows` (sorted, distinct), upload them as one [Bp, C]
+        block behind its int32 idx, and SET them into the resident planes
+        (donated).  Bp is one of MIRROR_PATCH_BUCKETS, padded by repeating
+        the last row — a set is idempotent."""
+        k = len(rows)
+        self.mirror_patches[fam] += 1
+        self.mirror_patch_rows[fam] += k
+        if not k:
+            return   # the version moved and no row did (a lost LWW write)
+        with self.stages.stage("mirror_patch", fam):
+            bp = next(b for b in self.MIRROR_PATCH_BUCKETS if b >= k)
+            idx = _pad(rows, bp, rows[-1])
+            table = _host_table(store, fam)
+            vals = np.stack([table.col(c)[idx] for c, _ in _FAMILIES[fam]],
+                            axis=-1)
+            self._run_patch(fam, cols, idx.astype(_I32), vals)
+
+    def _run_patch(self, fam: str, cols: dict, idx: np.ndarray,
+                   vals: np.ndarray) -> None:
+        """Upload one patch block and launch `fam`'s program on `cols`
+        (donated; the dict takes the outputs)."""
+        names = [c for c, _ in _FAMILIES[fam]]
+        out = B.MIRROR_PATCH[fam](tuple(cols[c] for c in names),
+                                  self._put_batch(idx), self._put_batch(vals))
+        cols.update(zip(names, out))
+
+    def _warm_patch(self) -> None:
+        """Run the patch programs of every journaled mirror once at its
+        present cap, all rows out of range so nothing is written.  Called
+        by flush: a mirror goes stale only after one (flush-before-touch),
+        so the programs a later patch needs compile here — after a boot
+        restore that is inside set-up — and never inside a served window.
+        (Distinct rows never outnumber the plane, so no bucket past the
+        first that covers it is used.)"""
+        if self._mesh is not None:
+            return
+        for fam in JOURNAL_FAMILIES:
+            res = self._res.get(fam)
+            if res is None or self._patch_warm.get(fam) == res["cap"]:
+                continue
+            cap = self._patch_warm[fam] = res["cap"]
+            with self.stages.stage("state_alloc", fam):
+                for bp in self.MIRROR_PATCH_BUCKETS:
+                    self._run_patch(
+                        fam, res["cols"], np.full(bp, cap, dtype=_I32),
+                        np.zeros((bp, len(_FAMILIES[fam])), dtype=_I64))
+                    if bp >= cap:
+                        break
 
     @staticmethod
     def _join_split(res: dict) -> None:
@@ -1202,6 +1298,7 @@ class TpuMergeEngine:
         w |= set(cols) if written is None else written
         self._res[fam] = {"cols": cols, "n": n, "cap": cap, "written": w,
                           "ver": prev.get("ver"),
+                          "jepoch": prev.get("jepoch"),
                           "src": src if src is not None else prev.get("src"),
                           "recon": recon if recon is not None
                           else prev.get("recon")}
@@ -1503,9 +1600,12 @@ class TpuMergeEngine:
         if src:
             src_d = self._src_state(fam, sp)
             pb = self._pool_add(vals, **{pcol: wp, scol: ws})
-            from ..ops import pallas_dense as PD
 
             def _pallas(interp):
+                # imported HERE, not above: the XLA branch never needs it,
+                # and importing Pallas costs the process's first resident
+                # round over a second on the event loop (PERF.md §6, PR 31)
+                from ..ops import pallas_dense as PD
                 # the pair columns live PRE-SPLIT between rounds (the
                 # retired PR 8 follow-up): a warm plane pays no O(plane)
                 # int64<->hi/lo pass — only the first round after a bulk
@@ -3091,6 +3191,9 @@ class TpuMergeEngine:
                             if adv.any():
                                 host_dt[sel[adv]] = dv[adv]
                                 self._el_del_touched.append(sel[adv])
+                                # host-only: the device del_t lags on
+                                # these rows until a patch carries them
+                                store.journal["el"].add_rows(sel[adv])
                     self._family_done("el", {"add_t": at, "add_node": an,
                                              "del_t": dt}, n, sp, src=src,
                                       written={"add_t", "add_node"},

@@ -227,6 +227,16 @@ def _section_stats(node, out):
         # the rows each family merged on the device / on its host twin
         for cause, cnt in node.engine.mirror_rebuild_causes.items():
             out.append((f"mirror_rebuilds_cause_{cause}", cnt))
+        # ... and the stale mirrors repaired in place instead: patches,
+        # the distinct rows they scattered, and the rebuilds a journal
+        # over its limit forced (engagement = patches / (patches +
+        # rebuilds) over a window)
+        for name, cnt in node.engine.mirror_patches.items():
+            out.append((f"mirror_patches_{name}", cnt))
+            out.append((f"mirror_patch_rows_{name}",
+                        node.engine.mirror_patch_rows[name]))
+        out.append(("mirror_patch_overflows",
+                    node.engine.mirror_patch_overflows))
         for path in ("dev", "host"):
             rows = getattr(node.engine, f"merge_rows_{path}")
             for name, cnt in rows.items():

@@ -54,6 +54,82 @@ FAMILIES = ("env", "reg", "cnt", "el", "tns")
 # counts its mirror rebuilds by the cause that invalidated the mirror
 # (INFO mirror_rebuilds_cause_<cause>)
 TOUCH_CAUSES = ("client_op", "repl_op", "reset", "expire", "gc", "compact")
+# causes after which rows have moved or everything may have changed: a
+# resident engine rebuilds the whole mirror (the others are row-scoped:
+# the rows they wrote are in the plane's RowJournal, and it patches them)
+WHOLE_CAUSES = frozenset(("reset", "gc", "compact"))
+# the device families whose host writes are journaled by row (env is
+# host-authoritative on the micro path, tns has its own payload pools)
+JOURNAL_FAMILIES = ("reg", "cnt", "el")
+# a journal past this many distinct rows goes whole: the largest mirror
+# patch (engine/tpu.py MIRROR_PATCH_BUCKETS) moves 28 B a row, under half
+# a percent of a 16.7M-row plane's 402 MB
+JOURNAL_MAX_ROWS = 1 << 16
+
+
+class RowJournal:
+    """The host rows of one device family written since that family's
+    device mirror was last equal to the host (docs/INVARIANTS.md,
+    MIRROR-JOURNAL): for a family with a mirror, mirror == host on every
+    row that is not in here.  Appended to where host columns are written
+    while the device is not — the op-path mutators below and the micro
+    path's host twins (engine/hostbatch.py) — never by a flush (device ->
+    host: equal afterwards) or a bulk ingest (merged on the device).
+
+    `whole` = every row may differ (rows moved, a reset, or more distinct
+    rows than JOURNAL_MAX_ROWS — `over`): the engine rebuilds the mirror.
+    A journal is born whole — no mirror yet, nothing to keep rows for —
+    so a store no device engine ever mirrors pays one flag test a write.
+    `epoch` counts resets: an engine whose mirror was synced at another
+    epoch (a second engine on the same store) must not trust the rows."""
+
+    __slots__ = ("rows", "whole", "over", "epoch")
+
+    def __init__(self) -> None:
+        self.rows: list[int] = []
+        self.whole = True
+        self.over = False
+        self.epoch = 0
+
+    def add(self, row: int) -> None:
+        if not self.whole:
+            self.rows.append(row)
+            if len(self.rows) > 2 * JOURNAL_MAX_ROWS:
+                self._dedupe()
+
+    def add_rows(self, rows: np.ndarray) -> None:
+        if not self.whole and len(rows):
+            self.rows.extend(rows.tolist())
+            if len(self.rows) > 2 * JOURNAL_MAX_ROWS:
+                self._dedupe()
+
+    def _dedupe(self) -> np.ndarray:
+        uniq = np.unique(np.asarray(self.rows, dtype=_I64))
+        if len(uniq) > JOURNAL_MAX_ROWS:
+            self.mark_whole(over=True)
+        else:
+            self.rows = uniq.tolist()
+        return uniq
+
+    def mark_whole(self, over: bool = False) -> None:
+        self.whole = True
+        self.over = self.over or over
+        self.rows = []
+
+    def take(self) -> Optional[np.ndarray]:
+        """Sorted distinct rows, or None when the journal is whole."""
+        if self.whole:
+            return None
+        uniq = self._dedupe()
+        return None if self.whole else uniq
+
+    def reset(self) -> int:
+        """The mirror equals the host again (just built or patched):
+        start over, row-scoped.  -> the new epoch."""
+        self.rows = []
+        self.whole = self.over = False
+        self.epoch += 1
+        return self.epoch
 
 
 def _blen(x) -> int:
@@ -173,6 +249,11 @@ class KeySpace:
         self.fam_ver: dict[str, int] = dict.fromkeys(FAMILIES, 0)
         # the cause of each plane's LAST version bump (TOUCH_CAUSES)
         self.fam_cause: dict[str, str] = dict.fromkeys(FAMILIES, "reset")
+        # ... and WHICH rows the op path wrote since each device family's
+        # mirror last equalled the host (RowJournal): a stale mirror is
+        # repaired by scattering those rows, not by a whole-plane upload
+        self.journal: dict[str, RowJournal] = \
+            {f: RowJournal() for f in JOURNAL_FAMILIES}
 
         self.cnt = _CntCols()
         # per-rank direct (kid -> cnt row) index windows: counter slot
@@ -269,9 +350,12 @@ class KeySpace:
                              f"{TOUCH_CAUSES}")
         fv = self.fam_ver
         fc = self.fam_cause
+        whole = cause in WHOLE_CAUSES
         for f in families:
             fv[f] += 1
             fc[f] = cause
+            if whole and f in self.journal:
+                self.journal[f].mark_whole()
 
     @property
     def version(self) -> int:
@@ -639,6 +723,7 @@ class KeySpace:
             self.cnt.val[row] += delta
             self.cnt.uuid[row] = uuid
             self.keys.cnt_sum[kid] += delta
+            self.journal["cnt"].add(row)
         return int(self.keys.cnt_sum[kid]), int(self.cnt.val[row])
 
     def counter_set_total(self, kid: int, node: int, total: int, uuid: int) -> None:
@@ -648,6 +733,7 @@ class KeySpace:
             self.keys.cnt_sum[kid] += total - int(self.cnt.val[row])
             self.cnt.val[row] = total
             self.cnt.uuid[row] = uuid
+            self.journal["cnt"].add(row)
 
     def counter_set_base(self, kid: int, node: int, base: int, base_t: int) -> None:
         """Delete-observed base assignment (DELCNT): LWW on delete time,
@@ -659,6 +745,7 @@ class KeySpace:
             self.keys.cnt_sum[kid] -= base - b0
             self.cnt.base[row] = base
             self.cnt.base_t[row] = base_t
+            self.journal["cnt"].add(row)
 
     def counter_sum(self, kid: int) -> int:
         return int(self.keys.cnt_sum[kid])
@@ -712,6 +799,7 @@ class KeySpace:
         if (b1, bt1) != (b0, bt0):
             self.keys.cnt_sum[kid] -= b1 - b0
             self.cnt.base[row], self.cnt.base_t[row] = b1, bt1
+        self.journal["cnt"].add(row)
 
     # ------------------------------------------------------------- registers
 
@@ -721,6 +809,7 @@ class KeySpace:
             return False
         self.reg_val[kid] = val
         self.keys.rv_t[kid], self.keys.rv_node[kid] = uuid, node
+        self.journal["reg"].add(kid)
         self.updated_at(kid, uuid)
         return True
 
@@ -734,6 +823,7 @@ class KeySpace:
         if S.lww_wins(t, node, int(self.keys.rv_t[kid]), int(self.keys.rv_node[kid])):
             self.reg_val[kid] = val
             self.keys.rv_t[kid], self.keys.rv_node[kid] = t, node
+            self.journal["reg"].add(kid)
 
     # -------------------------------------------------------------- elements
 
@@ -767,6 +857,7 @@ class KeySpace:
         if not S.lww_wins(at, an, uuid, node):
             self.el.add_t[row], self.el.add_node[row] = uuid, node
             self.el_val[row] = val
+            self.journal["el"].add(row)
             at = uuid
         return S.elem_alive(at, dt) and not was_alive
 
@@ -786,6 +877,7 @@ class KeySpace:
         was_alive = S.elem_alive(at, dt)
         if uuid > dt:
             self.el.del_t[row] = dt = uuid
+            self.journal["el"].add(row)
             if at < dt:
                 self._enqueue_garbage(dt, self.key_bytes[kid], member)
         return was_alive and not S.elem_alive(at, dt)
@@ -933,6 +1025,7 @@ class KeySpace:
         a0, n0, d0 = int(self.el.add_t[row]), int(self.el.add_node[row]), int(self.el.del_t[row])
         at, an, dt, local_wins = S.merge_elem(a0, n0, d0, add_t, add_node, del_t)
         self.el.add_t[row], self.el.add_node[row], self.el.del_t[row] = at, an, dt
+        self.journal["el"].add(row)
         if not local_wins:
             self.el_val[row] = val
         # re-queue whenever the merged row is dead and its del_t advanced (a
@@ -946,6 +1039,8 @@ class KeySpace:
         self.el_member.append(member)
         self.el_val.append(val)
         self.el_index.put(combo, row)
+        # (a caller's del_t write to the fresh row rides this entry)
+        self.journal["el"].add(row)
         return row
 
     # -------------------------------------------------------------- tensors

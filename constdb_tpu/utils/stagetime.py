@@ -49,7 +49,8 @@ from time import perf_counter_ns
 # served path, in order: socket read -> ... -> reply write
 STAGES = ("intake", "plan", "read_batch", "read_miss", "exec",
           "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
-          "mirror_rebuild", "state_alloc", "d2h_flush", "reply_write")
+          "mirror_rebuild", "mirror_patch", "state_alloc", "d2h_flush",
+          "reply_write")
 # entered for every pipelined chunk: counters only.  The others come at
 # most once per coalescer flush, or rarer, and also open a trace span
 PER_CHUNK = frozenset(("intake", "plan", "read_batch", "read_miss", "exec",

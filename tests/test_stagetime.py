@@ -7,18 +7,19 @@ Pinned here:
     closes and counts the stage, threads never nest into each other;
   * the vocabulary is closed (an undeclared name raises) and only the
     per-flush stages open trace spans, named `cst.<name>[.<tag>]`;
-  * INFO lists every `span_*`, `merge_rows_*` and
-    `mirror_rebuilds_cause_*` field from boot, at 0 — `readers._delta`
+  * INFO lists every `span_*`, `merge_rows_*`, `mirror_rebuilds_cause_*`
+    and `mirror_patch*` field from boot, at 0 — `readers._delta`
     (benchmark/readers.py) reads a missing counter as "no metric", and a
     traced line on the chip is refused for a missing metric;
   * a pipelined chunk through a real ServerApp socket moves the loop's
     stages, and their sum stays under the wall time of the exchange;
-  * a device engine on JAX-CPU counts merged rows by path and mirror
-    rebuilds by cause, and the documented inclusive totals
+  * a device engine on JAX-CPU counts merged rows by path, mirror
+    rebuilds by cause and mirror patches with their rows (a row-scoped
+    write is patched, a GC rebuilds), and the documented inclusive totals
     (`merge_<fam>_seconds`, `merge_seconds_total`,
     `flush_seconds_total`) still read above 0;
   * every counter a per-layer metric names — the ten in BENCHMARK.json
-    and the thirteen specs of docs/stage_layers/ — is an INFO key of a
+    and the fourteen specs of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
 """
@@ -38,7 +39,7 @@ from constdb_tpu.server import info as info_mod
 from constdb_tpu.server.io import start_node
 from constdb_tpu.server.node import Node
 from constdb_tpu.server.serve import ServeCoalescer
-from constdb_tpu.store.keyspace import TOUCH_CAUSES
+from constdb_tpu.store.keyspace import JOURNAL_FAMILIES, TOUCH_CAUSES
 from constdb_tpu.utils import stagetime
 from constdb_tpu.utils.stagetime import ANNOTATED, STAGES, StageClock
 
@@ -198,8 +199,8 @@ def test_only_per_flush_stages_open_trace_spans():
         ["cst.mirror_rebuild.el"]
     assert seen.count(("exit", "cst.mirror_rebuild.el")) == 1
     assert ANNOTATED == {"serve_flush", "stage_rows", "h2d", "dispatch",
-                         "host_twin", "mirror_rebuild", "state_alloc",
-                         "d2h_flush"}
+                         "host_twin", "mirror_rebuild", "mirror_patch",
+                         "state_alloc", "d2h_flush"}
     # without an annotation the same stages are counters like the others
     plain = StageClock()
     with plain.stage("d2h_flush", "el"):
@@ -237,7 +238,9 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want = [f"span_{s}_{k}" for s in STAGES for k in ("us", "n")]
     want += [f"merge_rows_{p}_{f}" for p in ("dev", "host") for f in FAMS]
     want += [f"mirror_rebuilds_cause_{c}" for c in TOUCH_CAUSES]
-    assert len(want) == 2 * 14 + 8 + 6
+    want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
+             for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
+    assert len(want) == 2 * 15 + 8 + 6 + 7
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
     # a CPU-engine node has the clock, not the device engine's counters
     cpu = info_of(Node(node_id=2))
@@ -311,26 +314,37 @@ def test_device_engine_counts_rows_by_path_and_rebuilds_by_cause():
     ServeCoalescer(node).run_chunk([cmd(b"sadd", b"s", b"lone")], out)
     assert node.ks.fam_cause["el"] == "client_op"
     sadd_round(node, 12)         # version moved: host twin again
-    sadd_round(node, 18)         # stable again: device, after a rebuild
-    info = info_of(node)
+    sadd_round(node, 18)         # stable again: device, after a PATCH of
+    info = info_of(node)         # the lone row and the twin's six
     assert info["merge_rows_host_el"] == 12
     assert info["merge_rows_dev_el"] == 12
     assert info["merge_rows_host_env"] == 24     # one key row a command,
     assert info["merge_rows_dev_env"] == 0       # always on the host
-    assert info["mirror_rebuilds_el"] == 1
-    assert info["mirror_rebuilds_cause_client_op"] == 1
-    assert sum(info[f"mirror_rebuilds_cause_{c}"]
-               for c in TOUCH_CAUSES) == 1
+    assert info["mirror_rebuilds_el"] == 0       # (PR 31: was 1 rebuild)
+    assert info["mirror_patches_el"] == 1
+    assert info["mirror_patch_rows_el"] == 1 + 6
+    assert info["mirror_patch_overflows"] == 0
     for s in ("serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
-              "mirror_rebuild", "state_alloc"):
+              "mirror_rebuild", "mirror_patch", "state_alloc"):
         assert info[f"span_{s}_n"] > 0, s
-    assert info["span_mirror_rebuild_n"] == 2    # first build + rebuild
+    assert info["span_mirror_rebuild_n"] == 2    # first build + one grow
+    assert info["span_mirror_patch_n"] == 1
     assert info["span_host_twin_n"] == 4 + 2     # env every round, el twice
     # the lone command flushed before it touched the plane; the read
     # barrier below flushes the two rounds since
     assert info["span_d2h_flush_n"] == 1
     node.ensure_flushed()
     assert info_of(node)["span_d2h_flush_n"] == 2
+    # rows moved (a GC's cause): the journal is whole, the plane rebuilds
+    node.ks.touch("el", cause="gc")
+    sadd_round(node, 24)
+    sadd_round(node, 30)
+    info = info_of(node)
+    assert info["mirror_rebuilds_el"] == 1 and info["mirror_patches_el"] == 1
+    assert info["mirror_rebuilds_cause_gc"] == 1
+    assert sum(info[f"mirror_rebuilds_cause_{c}"]
+               for c in TOUCH_CAUSES) == 1
+    assert info["span_mirror_rebuild_n"] == 3
     assert node.canonical() is not None
 
 
@@ -377,8 +391,8 @@ def test_documented_totals_still_read_above_zero():
 
 
 def layer_specs() -> list:
-    """Every per-layer metric BENCHMARK.json names, and the thirteen this
-    PR's counters are for (docs/stage_layers/: a `benchmark` PR moves
+    """Every per-layer metric BENCHMARK.json names, and the fourteen the
+    stage counters are for (docs/stage_layers/: a `benchmark` PR moves
     them under benchmark/layers/ — see docs/stage_layers/README.md)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
@@ -413,7 +427,7 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    assert len(specs) == 10 + 13
+    assert len(specs) == 10 + 14
     missing = {s["name"]: [c for c in counters_of(s) if c not in info]
                for s in specs}
     assert not {k: v for k, v in missing.items() if v}
@@ -431,8 +445,8 @@ def benchmark_module(name: str):
 
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
-    """docs/stage_layers/overlay.py on a scratch copy: 13 files beside the
-    10, 13 entries at the END of per_layer, nothing else changed — and
+    """docs/stage_layers/overlay.py on a scratch copy: 14 files beside the
+    10, 14 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -446,7 +460,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     added = mod.overlay(str(tmp_path))
-    assert len(added) == 13 and mod.overlay(str(tmp_path)) == []
+    assert len(added) == 14 and mod.overlay(str(tmp_path)) == []
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -456,7 +470,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     assert [m["name"] for m in after["per_layer"][10:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 23
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 24
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
@@ -467,7 +481,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
             "compared": {"reads_wrong": {"value": 0, "limit": 0}}}
     assert validate.check_line(line, before, "ycsb-b", True) == []
     refused = validate.check_line(line, after, "ycsb-b", True)
-    assert len(refused) == 13 and all("is missing" in e for e in refused)
+    assert len(refused) == 14 and all("is missing" in e for e in refused)
 
 
 def test_stage_layer_specs_read_through_the_benchmarks_readers():
@@ -476,9 +490,13 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
     before = info_of(node)
     for i in range(3):
         sadd_round(node, 6 * i)
+    # a lone command makes the mirror stale; the next round patches it
+    ServeCoalescer(node).run_chunk([cmd(b"sadd", b"s", b"lone")],
+                                   bytearray())
+    sadd_round(node, 18, members=5)
     node.ensure_flushed()
     window = {"info_before": before, "info_after": info_of(node),
-              "ops": 18, "kops": 0.018, "seconds": 2.0}
+              "ops": 24, "kops": 0.024, "seconds": 2.0}
     got = {}
     for path in sorted(glob.glob(os.path.join(ROOT, "docs", "stage_layers",
                                               "*.json"))):
@@ -492,11 +510,13 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
     per_op = [v for k, v in got.items() if k.endswith("_us_per_op.serve")]
     assert len(per_op) == 10
     assert got["device_merged_row_share.serve"] == 100.0
-    # every stage is in exactly one of the ten per-op metrics or in the
-    # rebuild share, so the ten add up to the traced share less rebuilds
+    assert got["mirror_patch_share.serve"] == 100.0     # 1 patch, 0 rebuilds
+    # every stage is in exactly one of the ten per-op metrics (a patch in
+    # the engine's) or in the rebuild share, so the ten add up to the
+    # traced share less rebuilds
     traced_us = got["loop_traced_share.serve"] * 2.0 * 1e4
     rebuild_us = got["mirror_rebuild_stall_share.serve"] * 2.0 * 1e4
-    assert sum(per_op) * 18 == pytest.approx(traced_us - rebuild_us)
+    assert sum(per_op) * 24 == pytest.approx(traced_us - rebuild_us)
     # a node without the counters (the parent commit) reads nothing
     bare = dict(window, info_after={}, info_before={})
     with open(os.path.join(ROOT, "docs", "stage_layers",
