@@ -32,10 +32,10 @@ serve/replication coalescers' flushes, previously always routed to the
 host micro strategy — merge IN PLACE against the resident planes too
 (`_merge_micro_resident`): duplicate slots fold on host with the shared
 hostbatch reductions, unique winners scatter once per family
-(ops/pallas_dense.py `scatter_pair_src` or its XLA twin), the env plane
-stays host-authoritative, and `flush()` downloads only the rows touched
-since the last flush (dirty-row accounting; counter sums update
-incrementally or re-derive via the device `segment_sum`).  This inverts
+(ops/bulk.py `bulk_lww_src`), the env plane stays host-authoritative,
+and `flush()` downloads only the rows touched since the last flush
+(dirty-row accounting; counter sums update incrementally or re-derive
+via ops/dense.py `segment_sum` on the device).  This inverts
 HOST_SCATTER_MAX into a FALLBACK threshold — per family for cold planes
 (`_micro_placement`), whole-round only when the steady path is off
 (CONSTDB_RESIDENT=0, non-resident engines, mesh-partitioned state).
@@ -194,8 +194,7 @@ class TpuMergeEngine:
     # kernels re-trace per PLANE CAP only, not per batch-size bucket —
     # per-shape tracing dominated small-stream walls, while scattering/
     # gathering a few hundred padded rows costs microseconds on any
-    # backend.  (Scatter pads engage only while a free pad row exists —
-    # see _micro_scatter_pair.)
+    # backend.
     MICRO_SCATTER_PAD = 256
     FLUSH_GATHER_PAD = 512
     # a stale mirror's journaled rows (store/keyspace.py RowJournal) pad
@@ -224,13 +223,13 @@ class TpuMergeEngine:
         batches staging the exact same slot rows — R replica snapshots of
         one keyspace, the bulk catch-up shape).  Aligned batches reduce
         on-device in one fused [R, N] pass, then scatter ONCE instead of
-        R times.  "auto" = on a TPU backend, each kernel's entry in
-        AUTO_TPU_KERNELS (Mosaic-compiled Pallas, or its XLA twin by
-        name); XLA kernels (ops/dense.py, ops/bulk.py) elsewhere.
-        "pallas" / "pallas-interpret" / "xla" force one backend for
-        every kernel (the interpreter is for CPU tests; no server
-        selects it); "off" disables folding.  The backends are
-        differential-tested bit-identical.
+        R times.  "auto" = the Mosaic-compiled Pallas kernels
+        (ops/pallas_dense.py) on a TPU backend without a mesh; their XLA
+        twins (ops/dense.py) elsewhere.  "pallas" / "pallas-interpret" /
+        "xla" force one backend for the fold and tensor-reduce kernels
+        (the interpreter is for CPU tests; no server selects it); "off"
+        disables folding.  The backends are differential-tested
+        bit-identical.
 
         `steady`: device-resident STEADY-STATE path — op-stream
         micro-batches (the serve/replication coalescers' flushes) merge
@@ -1118,8 +1117,7 @@ class TpuMergeEngine:
 
     # ------------------------------------------------------ resident state
 
-    def _resident_state(self, store: KeySpace, fam: str, n: int,
-                        micro: bool = False):
+    def _resident_state(self, store: KeySpace, fam: str, n: int):
         """Device state dict for family `fam` covering rows [0, n); grows
         (neutral-filled) as the host table grows.  Returns (cols, cap).
 
@@ -1132,20 +1130,12 @@ class TpuMergeEngine:
         compaction, a reset), over the largest bucket, or not this
         engine's to trust forces the whole-plane rebuild from host.
 
-        `micro`: the caller is the steady scatter path, which keeps LWW
-        pair columns PRE-SPLIT as hi/lo 32-bit planes between rounds
-        (`res["split"]` — ops/pallas_dense.py scatter_pair_src_split).
-        Bulk callers (micro=False), the grow path and the patch speak
-        int64, so they JOIN any split cache back into `cols` first; the
-        micro reuse path leaves the split intact — that is the whole
-        point of the layout (zero O(plane) split/join passes in steady
-        state)."""
+        `res["cols"]` is the only copy of a family's planes, always
+        int64: bulk rounds, micro rounds, the grow path and the patch all
+        read and replace the same arrays."""
         res = self._res.get(fam)
         ver = store.fam_ver[fam]
         stale = res is not None and res.get("ver") != ver
-        if res is not None and res.get("split") and \
-                (not micro or stale or n > res["cap"]):
-            self._join_split(res)
         journal = store.journal.get(fam) if self._mesh is None else None
         rows = None
         if stale:
@@ -1207,7 +1197,7 @@ class TpuMergeEngine:
         jepoch = res.get("jepoch") if res else None
         if journal is not None and (res is None or rows is not None):
             jepoch = journal.reset()
-        # `dirty`/`recon`/`split` survive a reuse/grow (the micro path
+        # `dirty`/`recon` survive a reuse/grow (the micro path
         # appends touched rows between flushes); a fresh build starts
         # CLEAN (dirty=[] — host == device at build, nothing to download).
         # A patch writes nothing the host lacks: neither written nor dirty
@@ -1217,7 +1207,6 @@ class TpuMergeEngine:
                           "written": res.get("written", set()) if res
                           else set(),
                           "recon": res.get("recon") if res else None,
-                          "split": res.get("split") if res else None,
                           "dirty": res.get("dirty") if res else []}
         return cols, cap
 
@@ -1273,17 +1262,6 @@ class TpuMergeEngine:
                     if bp >= cap:
                         break
 
-    @staticmethod
-    def _join_split(res: dict) -> None:
-        """Fold a family's pre-split hi/lo pair cache back into its int64
-        `cols` (bulk kernels, state growth, and mirror rebuilds speak
-        int64).  One O(plane) pass per steady→bulk transition — the
-        per-round pass the split layout exists to remove."""
-        from ..ops import pallas_dense as PD
-        for name, (hi, lo) in res["split"].items():
-            res["cols"][name] = PD.join_plane(hi, lo)
-        res["split"] = None
-
     def _family_done(self, fam: str, cols: dict, n: int, cap: int,
                      src=None, written=None, recon=None) -> None:
         """Record post-merge device state.  `written` marks which columns
@@ -1317,8 +1295,8 @@ class TpuMergeEngine:
     # PLACE against the resident device planes instead of falling back to
     # the host micro strategy.  Duplicate slots fold on host with the exact
     # shared reductions from engine/hostbatch.py, the unique winners
-    # scatter once per family (Pallas gather-compare-scatter or its XLA
-    # twin, per _pallas_or_xla), the env plane stays HOST-AUTHORITATIVE
+    # scatter once per family (ops/bulk.py bulk_lww_src), the env plane
+    # stays HOST-AUTHORITATIVE
     # (its merge is a collision-free max into host columns — zero device
     # bytes, and key-dt reads never need a flush), and every scatter's
     # rows land in the family's dirty set so flush() downloads only them.
@@ -1589,89 +1567,27 @@ class TpuMergeEngine:
         host-side launch: asynchronous, so launch time, not device time)."""
         nw = len(wr)
         n = _fam_rows(store, fam)
-        cols, sp = self._resident_state(store, fam, n, micro=True)
-        res = self._res[fam]
+        cols, sp = self._resident_state(store, fam, n)
         pcol, scol = pair
-        # pad-floor the batch length (see MICRO_SCATTER_PAD) — but only
-        # while a free pad-target row exists (nw < sp); a batch covering
-        # every plane row pads to itself (nw == sp == pow2, no pads)
+        # pad-floor the batch length (see MICRO_SCATTER_PAD); a batch
+        # covering every plane row pads to itself (nw == sp == pow2)
         np2 = K.next_pow2(nw if nw >= sp
                           else max(nw, self.MICRO_SCATTER_PAD))
+        idx = self._batch_idx(wr, 0, sp, np2)
+        bp = self._put_batch(_pad(wp, np2, K.NEUTRAL_T))
+        bs = self._put_batch(_pad(ws, np2, K.NEUTRAL_T))
         if src:
-            src_d = self._src_state(fam, sp)
             pb = self._pool_add(vals, **{pcol: wp, scol: ws})
-
-            def _pallas(interp):
-                # imported HERE, not above: the XLA branch never needs it,
-                # and importing Pallas costs the process's first resident
-                # round over a second on the event loop (PERF.md §6, PR 31)
-                from ..ops import pallas_dense as PD
-                # the pair columns live PRE-SPLIT between rounds (the
-                # retired PR 8 follow-up): a warm plane pays no O(plane)
-                # int64<->hi/lo pass — only the first round after a bulk
-                # merge / rebuild splits, and only a bulk round joins
-                split = res.get("split") or {}
-                p_sp = split.get(pcol) or PD.split_plane(cols[pcol])
-                s_sp = split.get(scol) or PD.split_plane(cols[scol])
-                pad = self._scatter_pad_row(wr, nw, sp) if np2 > nw else 0
-                o = PD.scatter_pair_src_split(
-                    p_sp[0], p_sp[1], s_sp[0], s_sp[1], src_d,
-                    self._put_batch(_pad(wr.astype(_I32), np2, pad)),
-                    self._put_batch(_pad(wp, np2, K.NEUTRAL_T)),
-                    self._put_batch(_pad(ws, np2, K.NEUTRAL_T)),
-                    np.int32(pb), interpret=interp)
-                return ("split", o)
-
-            def _xla():
-                return B.bulk_lww_src(
-                    cols[pcol], cols[scol], src_d,
-                    self._batch_idx(wr, 0, sp, np2),
-                    self._put_batch(_pad(wp, np2, K.NEUTRAL_T)),
-                    self._put_batch(_pad(ws, np2, K.NEUTRAL_T)), pb)
-
-            out = self._pallas_or_xla("scatter_pair_src_split", _pallas,
-                                      _xla)
-            if isinstance(out, tuple) and len(out) == 2 and \
-                    out[0] == "split":
-                o_p_hi, o_p_lo, o_s_hi, o_s_lo, src2 = out[1]
-                split = res.get("split") or {}
-                split[pcol] = (o_p_hi, o_p_lo)
-                split[scol] = (o_s_hi, o_s_lo)
-                res["split"] = split
-                self._micro_done(fam, {}, src=src2,
-                                 recon={pcol: pcol, scol: scol},
-                                 written={pcol, scol}, rows=wr)
-                return
-            p2, s2, src2 = out
-            self._micro_done(fam, {pcol: p2, scol: s2}, src=src2,
-                             recon={pcol: pcol, scol: scol},
-                             written={pcol, scol}, rows=wr)
+            p2, s2, src2 = B.bulk_lww_src(
+                cols[pcol], cols[scol], self._src_state(fam, sp),
+                idx, bp, bs, pb)
+            recon = {pcol: pcol, scol: scol}
         else:
-            # (the rare counter base pair: XLA int64 kernels; these
-            # columns are never split-cached — only src-tracked pairs)
-            p2, s2, _win = B.bulk_lww(
-                cols[pcol], cols[scol], self._batch_idx(wr, 0, sp, np2),
-                self._put_batch(_pad(wp, np2, K.NEUTRAL_T)),
-                self._put_batch(_pad(ws, np2, K.NEUTRAL_T)))
-            self._micro_done(fam, {pcol: p2, scol: s2}, src=None,
-                             recon=None, written={pcol, scol}, rows=wr)
-
-    @staticmethod
-    def _scatter_pad_row(rows: np.ndarray, n: int, sp: int) -> int:
-        """An in-range state row NO real batch row targets (`rows` is
-        sorted unique over [0, sp)): a Pallas pad step re-writes its
-        target from a read that may predate a real step's merge, so a pad
-        aliased onto a real target would silently revert the merge
-        (ops/pallas_dense.py contract; pinned in test_pallas_dense.py).
-        Unique rows over a pow2 plane always leave a free row whenever
-        padding is needed (n < pow2(n) <= sp)."""
-        last = int(rows[n - 1])
-        if last + 1 < sp:
-            return last + 1
-        # rows - iota is non-decreasing; its first step to >= 1 marks the
-        # first absent row
-        d = rows - np.arange(n, dtype=np.int64)
-        return int(np.searchsorted(d, 1))
+            # (the rare counter base pair keeps its winner on device)
+            p2, s2, _win = B.bulk_lww(cols[pcol], cols[scol], idx, bp, bs)
+            src2 = recon = None
+        self._micro_done(fam, {pcol: p2, scol: s2}, src=src2, recon=recon,
+                         written={pcol, scol}, rows=wr)
 
     def _micro_done(self, fam: str, cols: dict, src, recon,
                     written: set, rows: np.ndarray) -> None:
@@ -1697,41 +1613,25 @@ class TpuMergeEngine:
         slot contributions (slot kids upload as int32, only the [n_keys]
         sums download — val/base never cross the link); on the CPU
         backend the host bincount pass does it (uploading to sum would
-        cost more than it saves) unless a Pallas mode is forced.  All
-        paths are exact int64 — bit-identical to
-        KeySpace.recompute_counter_sums."""
-        from ..ops import pallas_dense as PD
+        cost more than it saves).  Both are exact int64 — bit-identical
+        to KeySpace.recompute_counter_sums."""
         res = self._res.get("cnt")
         n = store.cnt.n
         nk = store.keys.n
-        if self._kernel_backend("segment_sum") == "xla":
-            on_device = self._jax.default_backend() != "cpu"
-        else:   # the Pallas kernel's scratch cap
-            on_device = K.next_pow2(nk) <= PD.SEGMENT_SUM_MAX_SEG
-        if not (on_device and self._mesh is None and res is not None
+        if not (self._jax.default_backend() != "cpu"
+                and self._mesh is None and res is not None
                 and res["n"] == n and n and nk):
             store.recompute_counter_sums()
             return
         from ..ops import dense as D
-        if res.get("split"):
-            # steady micro rounds left the val/uuid truth in the
-            # pre-split pair cache — the int64 cols are stale-by-design
-            # (the split-plane law); join before the device sum reads
-            # them, or cnt_sum would re-derive from pre-merge values
-            self._join_split(res)
         cols = res["cols"]
         # whole padded planes, pow2 segment count: pad rows contribute
         # 0 (val/base fill) to segment 0, and the jit re-traces per
         # plane cap, not per row count
         ids = self._put_batch(_pad(store.cnt.kid[:n].astype(_I32),
                                    res["cap"], 0))
-        contrib = cols["val"] - cols["base"]
-        sg = K.next_pow2(nk)
-        sums = self._pallas_or_xla(
-            "segment_sum",
-            lambda interp: PD.segment_sum(ids, contrib, n_seg=sg,
-                                          interpret=interp),
-            lambda: D.segment_sum(ids, contrib, n_seg=sg))
+        sums = D.segment_sum(ids, cols["val"] - cols["base"],
+                             n_seg=K.next_pow2(nk))
         store.keys.cnt_sum[:nk] = np.asarray(self._device_get(sums))[:nk]
 
     # ------------------------------------------------------ tensor registers
@@ -2174,7 +2074,6 @@ class TpuMergeEngine:
                 # correctness-pinned two-step (gather + block kernel)
                 if f32:
                     return self._pallas_or_xla(
-                        "tensor_reduce",
                         lambda interp: PD.tensor_reduce(
                             B.gather_rows(buf, idx_dev).reshape(
                                 g, n, pool["Kp"]),
@@ -2204,7 +2103,6 @@ class TpuMergeEngine:
                                            n=n, g=g)
                 if f32:
                     red = self._pallas_or_xla(
-                        "tensor_reduce",
                         lambda interp: D.tensor_div(
                             PD.tensor_reduce(wmat, cnts_dev, div,
                                              strat=T.STRAT_SUM, n=n,
@@ -2412,45 +2310,27 @@ class TpuMergeEngine:
     def _stacked(staged, i: int, fill, np_: int) -> np.ndarray:
         return np.stack([_pad(s[i], np_, fill) for s in staged])
 
-    # What dense_fold="auto" resolves to on a TPU backend, per Pallas
-    # kernel (ops/pallas_dense.py).  "pallas": Mosaic compiles the kernel
-    # for the v5e and the on-chip run of tests/test_pallas_dense.py
-    # (CONSTDB_TEST_TPU=1) holds it bit-identical to its XLA twin.
-    # "xla": Mosaic refuses the kernel and the repair is a redesign, so
-    # its XLA twin (ops/bulk.py bulk_lww_src, ops/dense.py segment_sum)
-    # is selected HERE, by name — both kernels walk (1, 1) blocks over
-    # (N, 1) column planes, one grid step per row: "the last two
-    # dimensions of your block shape [must be] divisible by 8 and 128
-    # respectively, or be equal to the respective dimensions of the
-    # overall array" (ROADMAP S6).  Nothing at run time flips an entry:
-    # a lowering failure of a "pallas" kernel raises.
-    AUTO_TPU_KERNELS = {
-        "merge_elems": "pallas",
-        "merge_counters": "pallas",
-        "tensor_reduce": "pallas",
-        "scatter_pair_src_split": "xla",
-        "segment_sum": "xla",
-    }
-
-    def _kernel_backend(self, kernel: str) -> str:
-        """"pallas" | "pallas-interpret" | "xla" for one named kernel.
-        A forced dense_fold applies to every kernel; "auto" reads
-        AUTO_TPU_KERNELS on a TPU backend and is XLA everywhere else
-        (the interpreter is for CPU tests and is only ever FORCED; a
-        mesh keeps XLA — pallas_call inside GSPMD needs per-shard
-        shapes)."""
+    def _kernel_backend(self) -> str:
+        """"pallas" | "pallas-interpret" | "xla" for the fold and
+        tensor-reduce kernels (ops/pallas_dense.py; each has an XLA twin
+        in ops/dense.py).  A forced dense_fold is taken as given; "auto"
+        is the Mosaic-compiled kernels on a TPU backend and XLA
+        everywhere else (the interpreter is for CPU tests and is only
+        ever FORCED; a mesh keeps XLA — pallas_call inside GSPMD needs
+        per-shard shapes).  A lowering failure raises: nothing at run
+        time falls back."""
         mode = self.dense_fold
         if mode in ("pallas", "pallas-interpret", "xla"):
             return mode
         if mode == "off" or self._mesh is not None or \
                 self._jax.default_backend() != "tpu":
             return "xla"
-        return self.AUTO_TPU_KERNELS[kernel]
+        return "pallas"
 
-    def _pallas_or_xla(self, kernel: str, pallas_fn, xla_fn):
+    def _pallas_or_xla(self, pallas_fn, xla_fn):
         """ONE home for kernel-backend resolution: every Pallas call site
-        names its kernel and passes both twins."""
-        be = self._kernel_backend(kernel)
+        passes both twins."""
+        be = self._kernel_backend()
         if be == "xla":
             return xla_fn()
         return pallas_fn(be == "pallas-interpret")
@@ -2461,7 +2341,6 @@ class TpuMergeEngine:
         from ..ops import dense as D
         from ..ops import pallas_dense as PD
         return self._pallas_or_xla(
-            "merge_elems",
             lambda interp: PD.merge_elems(
                 self._put_batch(t_s), self._put_batch(n_s),
                 self._put_batch(d_s), interpret=interp),
@@ -2484,9 +2363,8 @@ class TpuMergeEngine:
             return at, an, win
 
         return self._pallas_or_xla(
-            "merge_elems", _pallas,
-            lambda: D.dense_merge_lww(self._put_batch(t_s),
-                                      self._put_batch(n_s)))
+            _pallas, lambda: D.dense_merge_lww(self._put_batch(t_s),
+                                               self._put_batch(n_s)))
 
     def _fold_pair(self, v_s, t_s):
         """[R, N] stacks -> per-slot (value @ time) LWW with max-value tie:
@@ -2494,7 +2372,6 @@ class TpuMergeEngine:
         from ..ops import dense as D
         from ..ops import pallas_dense as PD
         return self._pallas_or_xla(
-            "merge_counters",
             lambda interp: PD.merge_counters(
                 self._put_batch(v_s), self._put_batch(t_s),
                 interpret=interp),

@@ -67,9 +67,8 @@ def dense_max(cols):
 
 @partial(jax.jit, static_argnames=("n_seg",))
 def segment_sum(ids, vals, n_seg: int):
-    """Per-segment int64 sums over unsorted segment ids — the XLA twin of
-    ops/pallas_dense.py segment_sum (counter-sum re-derivation from
-    resident slot contributions)."""
+    """Per-segment int64 sums over unsorted segment ids (counter-sum
+    re-derivation from resident slot contributions)."""
     return jnp.zeros(n_seg, dtype=jnp.int64).at[ids].add(vals)
 
 

@@ -349,9 +349,10 @@ echo "== resident smoke (pallas-interpret snapshot + stream) =="
 # Pallas kernels forced through the interpreter: a kernel that drifts
 # from the host semantics fails HERE on CPU-only builders, not on the
 # first real-TPU round.  Snapshot leg = bulk catch-up through the fold
-# kernels; stream leg = in-place micro merges through the resident
-# scatter kernels (the differential suite proper runs inside tier-1 —
-# tests/test_resident_steady.py / tests/test_pallas_dense.py).
+# kernels; stream leg = in-place micro merges through the resident XLA
+# scatter, which a forced fold leaves as it is (the differential suite
+# proper runs inside tier-1 — tests/test_resident_steady.py /
+# tests/test_pallas_dense.py).
 JAX_PLATFORMS=cpu CONSTDB_BENCH_KEYS=20000 CONSTDB_BENCH_REPLICAS=2 \
 CONSTDB_BENCH_CPU_KEYS=5000 CONSTDB_BENCH_FOLD=pallas-interpret \
     timeout -k 10 300 python bench.py --mode snapshot --resident 1 \

@@ -21,11 +21,31 @@ from constdb_tpu.store.sharded_keyspace import ShardedKeySpace
 _I64 = np.int64
 
 
-def _shm_names() -> set:
-    try:
-        return {n for n in os.listdir("/dev/shm") if n.startswith("psm_")}
-    except FileNotFoundError:  # pragma: no cover - non-Linux
+@pytest.fixture
+def segments(monkeypatch):
+    """Names of the segments the pool under test makes: every one this
+    process creates or attaches by name while the test runs (a worker's
+    export segment reaches the parent by name).  Scoped to them because
+    /dev/shm is the machine's: another test process's live `psm_*`
+    segment is not this pool's leak."""
+    from multiprocessing import shared_memory
+    if not os.path.isdir("/dev/shm"):  # pragma: no cover - non-Linux
         pytest.skip("/dev/shm not available on this platform")
+    names = set()
+
+    class Recorded(shared_memory.SharedMemory):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            names.add(self.name)
+
+    monkeypatch.setattr(shared_memory, "SharedMemory", Recorded)
+    return names
+
+
+def _assert_none_left(segments: set) -> None:
+    assert segments, "the pool under test made no segment"
+    left = {n for n in segments if os.path.exists("/dev/shm/" + n)}
+    assert left == set(), "leaked /dev/shm segments"
 
 
 def _chunks(n_keys=240, n_rep=2, chunk=80):
@@ -40,8 +60,7 @@ def _raw_entries(chunks):
             for c in chunks]
 
 
-def test_no_leak_after_normal_completion():
-    before = _shm_names()
+def test_no_leak_after_normal_completion(segments):
     sks = ShardedKeySpace(n_shards=2, mode="process", engine_spec="cpu",
                           group=3)
     for c in _chunks():
@@ -49,14 +68,13 @@ def test_no_leak_after_normal_completion():
     sks.flush()
     assert sks.n_keys() > 0  # the merge actually happened
     sks.close()
-    assert _shm_names() - before == set(), "leaked /dev/shm segments"
+    _assert_none_left(segments)
 
 
-def test_no_leak_after_worker_crash_mid_job():
+def test_no_leak_after_worker_crash_mid_job(segments):
     """SIGKILL a worker while groups are in flight: the parent's reap
     surfaces the dead pipe as an error and close() still unlinks every
     job segment."""
-    before = _shm_names()
     pool = HostShardPool(2, max_inflight=2)
     try:
         entries = _raw_entries(_chunks())
@@ -70,24 +88,22 @@ def test_no_leak_after_worker_crash_mid_job():
                 pool.barrier()
     finally:
         pool.close()
-    assert _shm_names() - before == set(), "leaked /dev/shm segments"
+    _assert_none_left(segments)
 
 
-def test_no_leak_on_shutdown_with_jobs_in_flight():
-    before = _shm_names()
+def test_no_leak_on_shutdown_with_jobs_in_flight(segments):
     sks = ShardedKeySpace(n_shards=2, mode="process", engine_spec="cpu",
                           group=1)  # group=1: every submit ships a segment
     for c in _chunks():
         sks.submit(c)
     sks.close()  # no barrier, no flush: jobs still in flight
-    assert _shm_names() - before == set(), "leaked /dev/shm segments"
+    _assert_none_left(segments)
 
 
-def test_submit_group_guard_frees_segment_on_failure(monkeypatch):
+def test_submit_group_guard_frees_segment_on_failure(segments):
     """The new creation guard: a failure while POPULATING the segment
     (before registration hands ownership to reap/close) must close +
     unlink it instead of leaking until process exit."""
-    before = _shm_names()
     pool = HostShardPool(1)
     try:
         # entry shaped to blow up inside the population loop: a str has
@@ -97,4 +113,4 @@ def test_submit_group_guard_frees_segment_on_failure(monkeypatch):
             pool.submit_group([], [("x" * 64, None, None, None, -1, -1)])
     finally:
         pool.close()
-    assert _shm_names() - before == set(), "leaked /dev/shm segments"
+    _assert_none_left(segments)

@@ -11,7 +11,7 @@ mirror, mirror == host on every row that is not in the family's
     columns, downloaded, equal the host columns (padding neutral), the
     end state equals a CPU-engine node fed the same operations, and a
     mirror rebuilt from scratch by a second engine is the same arrays —
-    for el, reg and cnt, with the XLA twins and a pre-split plane;
+    for el, reg and cnt, under every forced kernel choice;
   * rows appended past the mirror's `n` and past its `cap`, duplicate
     rows in one journal, a version that moved with no row written;
   * overflow falls back to ONE rebuild and counts
@@ -58,15 +58,8 @@ def req(*parts) -> list:
 
 
 def mirror_cols(eng, fam: str) -> dict:
-    """The family's resident planes, downloaded whole — a pre-split pair
-    joined on a copy (the engine's own record is left as it is)."""
-    res = eng._res[fam]
-    out = {c: np.asarray(a) for c, a in res["cols"].items()}
-    if res.get("split"):
-        from constdb_tpu.ops import pallas_dense as PD
-        for c, (hi, lo) in res["split"].items():
-            out[c] = np.asarray(PD.join_plane(hi, lo))
-    return out
+    """The family's resident planes, downloaded whole."""
+    return {c: np.asarray(a) for c, a in eng._res[fam]["cols"].items()}
 
 
 def assert_mirror_is_host(eng, ks, fam: str) -> None:
@@ -92,7 +85,7 @@ def repair_and_check(node) -> None:
     eng, ks = node.engine, node.ks
     for fam in JOURNAL_FAMILIES:
         if fam in eng._res:
-            eng._resident_state(ks, fam, _fam_rows(ks, fam), micro=True)
+            eng._resident_state(ks, fam, _fam_rows(ks, fam))
             assert_mirror_is_host(eng, ks, fam)
 
 
@@ -247,7 +240,7 @@ def rebuilt_from_scratch_is_the_same(node) -> None:
         if fam not in eng._res:
             continue
         n = _fam_rows(ks, fam)
-        eng._resident_state(ks, fam, n, micro=True)
+        eng._resident_state(ks, fam, n)
         patched = mirror_cols(eng, fam)
         cols, cap = fresh._resident_state(ks, fam, n)
         assert cap == eng._res[fam]["cap"]
@@ -278,8 +271,6 @@ def test_interleavings_patched_mirror_is_host_is_rebuilt(seed, warmup, fold):
     assert sum(eng.mirror_rebuilds.values()) == 0
     assert {f for f, c in eng.mirror_patches.items() if c} == \
         set(JOURNAL_FAMILIES)
-    if fold == "pallas-interpret":
-        assert any(r.get("split") for r in eng._res.values())
     rebuilt_from_scratch_is_the_same(node)
     horizon = steps[-2][1] if steps[-2][0] == "local" else u(10 ** 6)
     assert node.ks.gc(horizon) == ref.ks.gc(horizon)
@@ -342,24 +333,6 @@ def test_a_version_that_moved_with_no_row_written_patches_nothing():
     assert eng.mirror_patches["el"] == 2 and eng.mirror_patch_rows["el"] == 1
     assert eng.bytes_h2d == h2d                  # nothing went up
     assert eng.stages.snapshot()["mirror_patch"][1] == n_before
-
-
-@pytest.mark.parametrize("fold", ("pallas-interpret",))
-def test_a_pre_split_plane_is_joined_patched_and_split_again(fold):
-    node, eng = device_node(warmup=0, fold=fold)
-    warm_el(node)
-    assert eng._res["el"].get("split")
-    node.ensure_flushed()
-    node.execute(req(b"sadd", b"warm", b"m1000", b"fresh"))
-    node.execute(req(b"srem", b"warm", b"m1001"))
-    repair_and_check(node)
-    assert not eng._res["el"].get("split")       # joined for the patch
-    assert eng.mirror_patches["el"] == 1 and eng.mirror_rebuilds["el"] == 0
-    sadd_round(node, 2000, key=b"warm")
-    assert eng._res["el"].get("split")           # the next round re-splits
-    repair_and_check(node)
-    got = {m.val for m in node.execute(req(b"smembers", b"warm")).items}
-    assert b"fresh" in got and b"m1001" not in got and b"m2003" in got
 
 
 # --------------------------------------------------------------- fallbacks
@@ -428,8 +401,7 @@ def test_unflushed_merge_data_under_a_stale_mirror_still_raises():
                      u(9), 1)
     node.ks.touch("el")                          # no flush before the touch
     with pytest.raises(RuntimeError, match="flush-before-touch"):
-        eng._resident_state(node.ks, "el", _fam_rows(node.ks, "el"),
-                            micro=True)
+        eng._resident_state(node.ks, "el", _fam_rows(node.ks, "el"))
     assert eng.mirror_patches["el"] == 0
 
 
@@ -583,10 +555,12 @@ def test_patch_programs_compile_at_the_flush_before_a_mirror_can_go_stale():
         B.MIRROR_PATCH.update(real)
 
 
-def test_an_xla_resident_round_imports_no_pallas():
+@pytest.mark.parametrize("fold", ("auto", "pallas-interpret"))
+def test_an_xla_resident_round_imports_no_pallas(fold):
     """Importing Pallas costs over a second; done lazily inside the first
     resident round it stalled the event loop inside a served window
-    (PERF.md §6, PR 31).  The XLA branch must never import it."""
+    (PERF.md §6, PR 31).  A resident round is XLA whatever `dense_fold`
+    says, and must never import it."""
     import os
     import subprocess
     import sys
@@ -596,7 +570,8 @@ def test_an_xla_resident_round_imports_no_pallas():
         "from constdb_tpu.server.node import Node\n"
         "from constdb_tpu.server.serve import ServeCoalescer\n"
         "from constdb_tpu.resp.message import Arr, Bulk\n"
-        "eng = TpuMergeEngine(resident=True, steady=True, warmup=0)\n"
+        "eng = TpuMergeEngine(resident=True, steady=True, warmup=0,\n"
+        f"                     dense_fold={fold!r})\n"
         "node = Node(node_id=1, engine=eng)\n"
         "for r in range(3):\n"
         "    ServeCoalescer(node).run_chunk(\n"
