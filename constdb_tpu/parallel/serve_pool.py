@@ -100,6 +100,7 @@ def _worker_stats(node) -> dict:
         "reads": st.serve_reads_coalesced,
         "read_flushes": st.serve_read_flushes,
         "reads_direct": st.serve_read_replies_direct,
+        "scans_native": st.serve_read_scans_native,
         "cache_hits": rc.hits,
         "cache_misses": rc.misses,
         "cache_inv": rc.invalidations,

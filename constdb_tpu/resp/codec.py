@@ -8,6 +8,14 @@ The parser consumes from an internal bytearray; `feed()` appends raw socket
 bytes, `next_msg()` returns one complete message or None.  Partial input never
 raises — the cursor only advances past fully parsed messages.  Consumed bytes
 are compacted away lazily once they exceed a threshold.
+
+Replies leave through `encode_into` (a Msg tree: the per-command path) or,
+for the read planner's misses (server/serve.py _read_misses), through the
+direct encoders that build no tree: `scan_replier` for SMEMBERS / HGETALL
+(one native pass from the key's row list to the reply bytes),
+`encode_rows_into` for LRANGE (rows already gathered and sorted),
+`bulk_reply` / `int_reply` for the single-value kinds.  Each has a native
+pass in native/resp.cpp and a bit-identical pure twin here.
 """
 
 from __future__ import annotations
@@ -103,8 +111,8 @@ def encode_msg(m: Msg) -> bytes:
 
 
 # row-reply kinds -> the codes both tiers take (native/resp.cpp
-# resp_encode_rows); an LRANGE reaches them as "values" over its sorted,
-# sliced rows
+# resp_encode_rows, resp_scan_reply); an LRANGE reaches them as "values"
+# over its sorted, sliced rows
 _ROW_KINDS = {"members": 0, "pairs": 1, "values": 2}
 
 
@@ -163,6 +171,49 @@ def _py_encode_rows_into(out: bytearray, code: int, rows: list,
     payload = b"".join(parts)
     out += payload
     return payload
+
+
+_NO_ROWS: list = []
+
+
+def scan_replier(ks):
+    """-> `reply(out, kind, kid) -> (payload, native)` over keyspace `ks`,
+    good until `ks` is next written (the caller has just run
+    `ks._sync_el_lists()`; a read run's stitch loop makes one per run).
+    `reply` appends the reply of one planned SMEMBERS ("members") /
+    HGETALL ("pairs") miss of key `kid`, from the key's row list to its
+    reply bytes in ONE native pass (native/resp.cpp resp_scan_reply): per
+    row of `ks.el_rows_by_kid[kid]`, in list order, keep it iff `el.kid[r]
+    == kid and add_t[r] >= del_t[r]`, then write it as `encode_rows_into`
+    does — no gather, no row list, no second call.  It returns the
+    appended bytes and whether the native pass wrote them: False when
+    the extension lacks the entry point, or the C pass declined the
+    shape, and the pure twin answered (or raised its own error)."""
+    scan = _scan_reply()
+    if scan is None:
+        return lambda out, kind, kid: (
+            _py_scan_reply_into(out, kind, kid, ks), False)
+    el = ks.el
+    rows_of = ks.el_rows_by_kid.get
+    el_kid, add_t, del_t = el.kid, el.add_t, el.del_t
+    el_member, el_val = ks.el_member, ks.el_val
+
+    def reply(out: bytearray, kind: str, kid: int) -> tuple:
+        payload = scan(out, _ROW_KINDS[kind], kid, rows_of(kid, _NO_ROWS),
+                       el_kid, add_t, del_t, el_member, el_val)
+        if payload is not None:
+            return payload, True
+        return _py_scan_reply_into(out, kind, kid, ks), False
+    return reply
+
+
+def _py_scan_reply_into(out: bytearray, kind: str, kid: int, ks) -> bytes:
+    """The fused scan reply's pure tier, and the reference its tests hold
+    the native pass to: the batch gather's rows through the row encoder's
+    pure twin."""
+    return _py_encode_rows_into(
+        out, _ROW_KINDS[kind], ks.elem_live_rows_batch([kid])[0].tolist(),
+        ks.el_member, ks.el_val)
 
 
 def bulk_reply(v: Optional[bytes]) -> bytes:
@@ -519,6 +570,7 @@ class NativeRespParser(RespParser):
 _EXT_CACHE: list = []
 _ENC_CACHE: list = []
 _ENC_ROWS_CACHE: list = []
+_SCAN_REPLY_CACHE: list = []
 _INTAKE_CACHE: list = []
 
 
@@ -549,6 +601,15 @@ def _enc_rows():
         from ..utils.native_tables import load_ext
         _ENC_ROWS_CACHE.append(getattr(load_ext(), "resp_encode_rows", None))
     return _ENC_ROWS_CACHE[0]
+
+
+def _scan_reply():
+    """The native fused scan-reply entry point, or None (gated like
+    _enc_rows)."""
+    if not _SCAN_REPLY_CACHE:
+        from ..utils.native_tables import load_ext
+        _SCAN_REPLY_CACHE.append(getattr(load_ext(), "resp_scan_reply", None))
+    return _SCAN_REPLY_CACHE[0]
 
 
 def _intake():

@@ -175,6 +175,7 @@ def _section_stats(node, out):
     out.append(("read_cache_hits", rc.hits))
     out.append(("read_cache_misses", rc.misses))
     out.append(("serve_read_replies_direct", st.serve_read_replies_direct))
+    out.append(("serve_read_scans_native", st.serve_read_scans_native))
     out.append(("read_cache_bytes", rc_bytes))
     out.append(("read_cache_invalidations", rc.invalidations))
     # overload governance (server/overload.py): client writes shed at

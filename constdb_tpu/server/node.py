@@ -101,6 +101,12 @@ class NodeStats:
     # serve_read_replies_direct / read_cache_misses is the share of
     # misses that built no Msg tree
     serve_read_replies_direct: int = 0
+    # planned SMEMBERS / HGETALL misses answered by the ONE native pass
+    # from the key's row list to its reply bytes (resp/codec.py
+    # scan_replier -> native/resp.cpp resp_scan_reply); the rest of
+    # them were answered by the pure twin (no entry point in the
+    # extension, or a shape the C pass declined)
+    serve_read_scans_native: int = 0
     # native intake stage (native/intake.cpp + server/io.py): pipelined
     # chunks split+classified by the C scanner in one call, and the
     # command frames it emitted as opcodes (CONSTDB_NATIVE_INTAKE=0 or a

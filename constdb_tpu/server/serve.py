@@ -27,11 +27,16 @@ Ordering discipline (docs/INVARIANTS.md "Client-serving coalescing"):
     families the run observes (READ_FLUSH_FAMILIES → ensure_flushed_for
     — a clean resident plane serves the batch with zero downloads),
     values gather vectorized per family (store/keyspace.py
-    register_get_batch / counter_sum_batch / elem_live_rows_batch /
-    elem_probe_batch), and finished reply bytes are served from —
-    and fill — the versioned hot-key reply cache (server/read_cache.py,
-    CONSTDB_READ_CACHE_MB).  A run stays open across interleaved
-    commands that provably commute with it — KEY-CONFINED data commands
+    register_get_batch / counter_sum_batch / elem_probe_batch, and
+    elem_live_rows_batch for the scans that need a count or a sort:
+    scnt/hlen/llen/lrange) — except a missed smembers/hgetall, which
+    gathers nothing: ONE native pass goes from the key's row list to its
+    reply bytes (resp/codec.py scan_replier; INFO
+    serve_read_scans_native counts them) — and finished reply bytes
+    are served from — and fill — the versioned hot-key reply cache
+    (server/read_cache.py, CONSTDB_READ_CACHE_MB).  A run stays open
+    across interleaved commands that provably commute with it —
+    KEY-CONFINED data commands
     whose first-arg key the run does not read (their replies buffer and
     splice back in exact request order; their HLC ticks and state
     effects happen at their exact positions, as do the reads' own
@@ -91,7 +96,7 @@ import numpy as np
 
 from ..errors import CstError
 from ..resp.codec import (bulk_reply, encode_into, encode_rows_into,
-                          int_reply)
+                          int_reply, scan_replier)
 from ..resp.message import (Arr, Bulk, Int, NIL, NoReply, OK, as_bytes,
                             as_int)
 from ..replica.coalesce import BatchBuilder
@@ -1098,6 +1103,10 @@ class ServeCoalescer:
                 elif kind in ("lrange", "llen") and not alive:
                     const = _EMPTY_ARR_BYTES if kind == "lrange" \
                         else _INT0_BYTES
+                elif kind == "members" or kind == "pairs":
+                    # no gather: the stitch loop's fused pass goes from
+                    # the key's row list to the reply bytes
+                    slots[j] = ("fused", kid)
                 else:
                     slots[j] = ("scan", len(miss_scan))
                     miss_scan.append((j, kid))
@@ -1113,7 +1122,9 @@ class ServeCoalescer:
         st.cmds_processed += planned
         st.serve_reads_coalesced += planned
         st.serve_read_replies_direct += planned - (n - len(miss))
-        # ---- vectorized family gathers for the misses
+        # ---- vectorized family gathers for the misses (card / llen /
+        # lrange scans need a count or a sort; members / pairs take the
+        # fused pass below)
         scan_rows: list = []
         if miss_scan:
             scan_rows = ks.elem_live_rows_batch([m[1] for m in miss_scan])
@@ -1135,6 +1146,8 @@ class ServeCoalescer:
         # everything in order (splicing deferred non-read replies back at
         # their exact positions), fill the cache from the bytes written
         el_member, el_val = ks.el_member, ks.el_val
+        scan_reply = None  # made at the run's first members/pairs miss
+        native_scans = 0
         ei, ne = 0, len(extras) if extras else 0
         for j, sp in enumerate(specs):
             while ei < ne and extras[ei][0] < sp[0]:
@@ -1146,17 +1159,20 @@ class ServeCoalescer:
             if type(slot) is tuple:
                 kind, ref = slot
                 k2 = sp[2].kind
-                if kind == "scan":
-                    rows = scan_rows[ref].tolist()
-                    if k2 == "card" or k2 == "llen":
+                if kind == "fused":  # members / pairs
+                    if scan_reply is None:
+                        ks._sync_el_lists()  # once: it reads the row lists
+                        scan_reply = scan_replier(ks)
+                    payload, native = scan_reply(out, k2, ref)
+                    native_scans += native
+                elif kind == "scan":
+                    rows = scan_rows[ref]
+                    if k2 == "lrange":
+                        payload = encode_rows_into(out, k2, rows.tolist(),
+                                                   el_member, el_val, *sp[6])
+                    else:  # card / llen
                         payload = int_reply(len(rows))
                         out += payload
-                    elif k2 == "lrange":
-                        payload = encode_rows_into(out, k2, rows, el_member,
-                                                   el_val, *sp[6])
-                    else:  # members / pairs
-                        payload = encode_rows_into(out, k2, rows, el_member,
-                                                   el_val)
                 else:
                     if kind == "cnt":
                         payload = int_reply(cnt_vals[ref])
@@ -1183,6 +1199,7 @@ class ServeCoalescer:
             if spans is not None:
                 spans.append(len(out))
             ei += 1
+        st.serve_read_scans_native += native_scans
 
     def _emit_merged(self, specs: list, hits: list, extras: list,
                      out: bytearray, spans) -> None:

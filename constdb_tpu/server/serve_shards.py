@@ -362,6 +362,8 @@ class ServeShardPlane:
             stats["read_flushes"] - last.get("read_flushes", 0)
         st.serve_read_replies_direct += \
             stats["reads_direct"] - last.get("reads_direct", 0)
+        st.serve_read_scans_native += \
+            stats["scans_native"] - last.get("scans_native", 0)
         rc = node.read_cache
         rc.hits += stats["cache_hits"] - last.get("cache_hits", 0)
         rc.misses += stats["cache_misses"] - last.get("cache_misses", 0)

@@ -346,6 +346,9 @@ PyMethodDef methods[] = {
     {"resp_encode_rows", py_resp_encode_rows, METH_VARARGS,
      "resp_encode_rows(out, kind, rows, members, vals) -> appended payload "
      "bytes | None (None = caller must use the pure-Python twin)"},
+    {"resp_scan_reply", py_resp_scan_reply, METH_VARARGS,
+     "resp_scan_reply(out, kind, kid, rows, el_kid, add_t, del_t, members, "
+     "vals) -> appended payload bytes | None (None = pure-Python twin)"},
     {"intake_scan", py_intake_scan, METH_VARARGS,
      "intake_scan(buf, pos, Arr, Bulk, Int, Simple, Err, nil[, max_bulk, "
      "max_msgs]) -> (ops, payloads, new_pos)"},

@@ -37,6 +37,8 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <algorithm>
+#include <cstdint>
 #include <cstring>
 #include <string>
 #include <vector>
@@ -506,7 +508,117 @@ inline bool blob_of(PyObject* list, Py_ssize_t r, bool none_ok, Blob* b) {
     return false;
 }
 
+// The one writer behind resp_encode_rows and resp_scan_reply: add() the
+// reply's rows in order (a size pre-pass over their blobs), then finish()
+// resizes `out` once and writes.
+struct RowReply {
+    const int kind;
+    PyObject* const members;
+    PyObject* const vals;
+    const Py_ssize_t n_members, n_vals;
+    std::vector<Blob> blobs;
+    Py_ssize_t n = 0, body = 0;
+
+    // nullptr planes (not lists) leave ok() false: the caller declines
+    RowReply(int k, PyObject* m, PyObject* v, Py_ssize_t reserve)
+        : kind(k),
+          members(PyList_Check(m) ? m : nullptr),
+          vals(PyList_Check(v) ? v : nullptr),
+          n_members(members ? PyList_GET_SIZE(members) : 0),
+          n_vals(vals ? PyList_GET_SIZE(vals) : 0) {
+        blobs.reserve(static_cast<size_t>(reserve * (k == 1 ? 2 : 1)));
+    }
+
+    bool ok() const { return kind >= 0 && kind <= 2 && members && vals; }
+
+    // row r's blobs; false = decline (row past a plane, non-bytes blob)
+    bool add(Py_ssize_t r) {
+        Blob b;
+        if (kind != 2) {
+            if (r >= n_members || !blob_of(members, r, false, &b))
+                return false;
+            body += 5 + dec_digits(b.n) + b.n;
+            blobs.push_back(b);
+        }
+        if (kind != 0) {
+            if (r >= n_vals || !blob_of(vals, r, true, &b)) return false;
+            body += 5 + dec_digits(b.n) + b.n;
+            blobs.push_back(b);
+        }
+        n++;
+        return true;
+    }
+
+    // "*<n>\r\n", then per blob "$<len>\r\n<bytes>\r\n", per pair "*2\r\n";
+    // returns the appended payload (new reference) or nullptr on error
+    PyObject* finish(PyObject* out) const {
+        const Py_ssize_t total =
+            3 + dec_digits(n) + (kind == 1 ? 4 * n : 0) + body;
+        const Py_ssize_t old = PyByteArray_GET_SIZE(out);
+        if (PyByteArray_Resize(out, old + total)) return nullptr;
+        char* const base = PyByteArray_AS_STRING(out) + old;
+        char* w = put_head(base, '*', n);
+        const Blob* b = blobs.data();
+        for (Py_ssize_t j = 0; j < n; j++) {
+            if (kind == 1) {
+                memcpy(w, "*2\r\n", 4);
+                w += 4;
+                w = put_bulk(w, *b++);
+            }
+            w = put_bulk(w, *b++);
+        }
+        return PyBytes_FromStringAndSize(base, total);
+    }
+};
+
+// a list item as a row index; -1 (error cleared) = decline: not an int,
+// negative, or past Py_ssize_t — the pure twin's cases
+inline Py_ssize_t row_of(PyObject* ro) {
+    if (!PyLong_CheckExact(ro)) return -1;
+    Py_ssize_t r = PyLong_AsSsize_t(ro);
+    if (r < 0) {
+        PyErr_Clear();
+        return -1;
+    }
+    return r;
+}
+
+// One C-contiguous int64 column through the buffer protocol (no copy).
+struct I64Col {
+    Py_buffer view;
+    bool held = false;
+    const int64_t* p = nullptr;
+    Py_ssize_t n = 0;
+
+    // false (error cleared) = decline: no buffer, strided, not int64
+    bool take(PyObject* o) {
+        if (PyObject_GetBuffer(o, &view,
+                               PyBUF_C_CONTIGUOUS | PyBUF_FORMAT) != 0) {
+            PyErr_Clear();
+            return false;
+        }
+        held = true;
+        const char* f = view.format ? view.format : "";
+        if (*f == '@' || *f == '=' || *f == '<') f++;
+        if (view.itemsize != 8 || (*f != 'l' && *f != 'q') || f[1])
+            return false;
+        p = static_cast<const int64_t*>(view.buf);
+        n = view.len / 8;
+        return true;
+    }
+
+    ~I64Col() {
+        if (held) PyBuffer_Release(&view);
+    }
+};
+
 }  // namespace resp
+
+static bool out_is_bytearray(PyObject* out) {
+    if (PyByteArray_CheckExact(out)) return true;
+    PyErr_SetString(PyExc_TypeError, "out must be a bytearray");
+    return false;
+}
 
 static PyObject* py_resp_encode_rows(PyObject*, PyObject* args) {
     PyObject *out, *rows, *members, *vals;
@@ -514,54 +626,55 @@ static PyObject* py_resp_encode_rows(PyObject*, PyObject* args) {
     if (!PyArg_ParseTuple(args, "OiOOO", &out, &kind, &rows, &members,
                           &vals))
         return nullptr;
-    if (!PyByteArray_CheckExact(out)) {
-        PyErr_SetString(PyExc_TypeError, "out must be a bytearray");
-        return nullptr;
-    }
-    if (kind < 0 || kind > 2 || !PyList_CheckExact(rows) ||
-        !PyList_Check(members) || !PyList_Check(vals))
-        Py_RETURN_NONE;
+    if (!out_is_bytearray(out)) return nullptr;
+    if (!PyList_CheckExact(rows)) Py_RETURN_NONE;
     const Py_ssize_t n = PyList_GET_SIZE(rows);
-    const Py_ssize_t n_members = PyList_GET_SIZE(members);
-    const Py_ssize_t n_vals = PyList_GET_SIZE(vals);
-    const int per = kind == 1 ? 2 : 1;
-    std::vector<resp::Blob> blobs(static_cast<size_t>(n * per));
-    // "*<n>\r\n", then per blob "$<len>\r\n<bytes>\r\n", per pair "*2\r\n"
-    Py_ssize_t total = 3 + resp::dec_digits(n) + (kind == 1 ? 4 * n : 0);
-    resp::Blob* b = blobs.data();
+    resp::RowReply reply(kind, members, vals, n);
+    if (!reply.ok()) Py_RETURN_NONE;
     for (Py_ssize_t j = 0; j < n; j++) {
-        PyObject* ro = PyList_GET_ITEM(rows, j);
-        if (!PyLong_CheckExact(ro)) Py_RETURN_NONE;
-        Py_ssize_t r = PyLong_AsSsize_t(ro);
-        if (r < 0) {  // negative index or overflow: the pure twin's case
-            PyErr_Clear();
+        Py_ssize_t r = resp::row_of(PyList_GET_ITEM(rows, j));
+        if (r < 0 || !reply.add(r)) Py_RETURN_NONE;
+    }
+    return reply.finish(out);
+}
+
+// ------------------------------------------------------- fused scan reply
+//
+// resp_scan_reply(out_bytearray, kind, kid, rows, el_kid, add_t, del_t,
+//                 members, vals) -> bytes | None
+// answers one planned SMEMBERS (kind 0) / HGETALL (kind 1) miss in ONE
+// pass from the key's row list (KeySpace.el_rows_by_kid[kid]) to its
+// reply bytes: per row, in list order, keep it iff
+//   el_kid[r] == kid and add_t[r] >= del_t[r]
+// (the compaction-staleness check and the liveness rule of
+// KeySpace.elem_live_rows_batch, letter for letter), then write the kept
+// rows exactly as resp_encode_rows would.  The three columns arrive
+// through the buffer protocol: no gather, no row array, no second call.
+// Returns None, with nothing appended, for any shape it will not take
+// (what resp_encode_rows declines; a row past a column; a column that is
+// not C-contiguous int64): the caller's pure twin answers or raises.
+
+static PyObject* py_resp_scan_reply(PyObject*, PyObject* args) {
+    PyObject *out, *rows, *o_kid, *o_add, *o_del, *members, *vals;
+    int kind;
+    long long kid;
+    if (!PyArg_ParseTuple(args, "OiLOOOOOO", &out, &kind, &kid, &rows,
+                          &o_kid, &o_add, &o_del, &members, &vals))
+        return nullptr;
+    if (!out_is_bytearray(out)) return nullptr;
+    if (kind == 2 || !PyList_CheckExact(rows)) Py_RETURN_NONE;
+    const Py_ssize_t n = PyList_GET_SIZE(rows);
+    resp::RowReply reply(kind, members, vals, n);
+    if (!reply.ok()) Py_RETURN_NONE;
+    resp::I64Col el_kid, add_t, del_t;
+    if (!el_kid.take(o_kid) || !add_t.take(o_add) || !del_t.take(o_del))
+        Py_RETURN_NONE;
+    const Py_ssize_t n_col = std::min(el_kid.n, std::min(add_t.n, del_t.n));
+    for (Py_ssize_t j = 0; j < n; j++) {
+        Py_ssize_t r = resp::row_of(PyList_GET_ITEM(rows, j));
+        if (r < 0 || r >= n_col) Py_RETURN_NONE;
+        if (el_kid.p[r] == kid && add_t.p[r] >= del_t.p[r] && !reply.add(r))
             Py_RETURN_NONE;
-        }
-        if (kind != 2) {
-            if (r >= n_members || !resp::blob_of(members, r, false, b))
-                Py_RETURN_NONE;
-            total += 5 + resp::dec_digits(b->n) + b->n;
-            b++;
-        }
-        if (kind != 0) {
-            if (r >= n_vals || !resp::blob_of(vals, r, true, b))
-                Py_RETURN_NONE;
-            total += 5 + resp::dec_digits(b->n) + b->n;
-            b++;
-        }
     }
-    const Py_ssize_t old = PyByteArray_GET_SIZE(out);
-    if (PyByteArray_Resize(out, old + total)) return nullptr;
-    char* const base = PyByteArray_AS_STRING(out) + old;
-    char* w = resp::put_head(base, '*', n);
-    b = blobs.data();
-    for (Py_ssize_t j = 0; j < n; j++) {
-        if (kind == 1) {
-            memcpy(w, "*2\r\n", 4);
-            w += 4;
-            w = resp::put_bulk(w, *b++);
-        }
-        w = resp::put_bulk(w, *b++);
-    }
-    return PyBytes_FromStringAndSize(base, total);
+    return reply.finish(out);
 }

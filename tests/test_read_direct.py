@@ -1,6 +1,9 @@
 """Planned read misses write their reply bytes straight from the gathers
 (resp/codec.py encode_rows_into / bulk_reply / int_reply; the native pass
-is native/resp.cpp resp_encode_rows) — no Arr/Bulk/Int tree.
+is native/resp.cpp resp_encode_rows) — no Arr/Bulk/Int tree — and a
+missed SMEMBERS / HGETALL gathers nothing: one native pass goes from the
+key's row list to the reply bytes (codec.scan_replier; native/resp.cpp
+resp_scan_reply).
 
 Pinned here (docs/INVARIANTS.md "Read coalescing laws"):
   * byte identity: for every planned read kind and every edge of the
@@ -9,13 +12,19 @@ Pinned here (docs/INVARIANTS.md "Read coalescing laws"):
     handler returns, once through the extension's pass and once through
     the pure twin;
   * the two tiers decline and fail alike on shapes neither encodes;
+  * the fused scan pass is the byte twin of `elem_live_rows_batch` + the
+    row encoder over every shape of a row list, declines what it will not
+    take with nothing appended, and `serve_read_scans_native` counts
+    exactly the members / pairs misses it answered;
   * `serve_read_replies_direct` counts exactly the planned misses (never
     a cache hit, never a demotion), is an INFO field, and rides shard
     worker acks.
 """
 
 import asyncio
+import types
 
+import numpy as np
 import pytest
 
 from constdb_tpu.resp import codec
@@ -183,10 +192,11 @@ def _case_id(case) -> str:
 
 @pytest.fixture(params=["native", "pure"])
 def tier(request, monkeypatch):
-    """Which of encode_rows_into's two tiers a test runs through."""
+    """Which of the direct encoders' two tiers a test runs through."""
     if request.param == "pure":
         monkeypatch.setattr(codec, "_enc_rows", lambda: None)
-    elif codec._enc_rows() is None:
+        monkeypatch.setattr(codec, "_scan_reply", lambda: None)
+    elif codec._enc_rows() is None or codec._scan_reply() is None:
         pytest.skip("native extension not built")
     return request.param
 
@@ -268,6 +278,205 @@ def test_tiers_fail_alike_and_append_nothing(kind, rows, error, tier):
     assert out == b"head"
 
 
+# ------------------------------------------------------ the fused scan pass
+
+SCAN_KINDS = {"members": b"sadd", "pairs": b"hset"}
+
+
+def scan_state(kind: str, shape: str):
+    """-> (ks, kid) of key `k` in the shape's state; `other` is a second
+    key whose rows a compaction-stale list still names."""
+    node = Node(node_id=1, clock=stepping_clock())
+    add = SCAN_KINDS[kind]
+
+    def elems(key: bytes, n: int) -> list:
+        return [cmd(add, key, b"m%04d" % i, *([b"v%d" % i] * (kind == "pairs")))
+                for i in range(n)]
+
+    n = {"many-rows": 100, "many-rows-tombstoned": 100}.get(shape, 10)
+    run(node, *elems(b"k", n), *elems(b"other", 4))
+    ks = node.ks
+    kid = ks.key_index.lookup(b"k")
+    ks._sync_el_lists()
+    rows = ks.el_rows_by_kid[kid]
+    if shape in ("tombstoned", "many-rows-tombstoned"):
+        rem = b"srem" if kind == "members" else b"hdel"
+        run(node, *[cmd(rem, b"k", b"m%04d" % i) for i in range(1, n, 3)])
+    elif shape == "stale-rows":
+        # what _compact_elements leaves until the lists rebuild: rows that
+        # now belong to another key, between the key's own
+        theirs = ks.el_rows_by_kid[ks.key_index.lookup(b"other")]
+        rows[3:3] = theirs[:2]
+        rows.append(theirs[3])
+    elif shape == "none-value":
+        ks.el_val[rows[4]] = None
+    elif shape == "empty-list":
+        del rows[:]
+    elif shape == "no-list":
+        kid = 1 << 40
+    elif shape == "row-past-columns":
+        rows.insert(2, ks.el.n + 5)
+    elif shape == "non-bytes-blob":
+        ks.el_member[rows[2]] = "str"
+    elif shape == "non-int-row":
+        rows[1] = float(rows[1])
+    else:
+        assert shape in ("all-live", "many-rows"), shape
+    return ks, kid
+
+
+SCAN_SHAPES = ["all-live", "tombstoned", "stale-rows", "none-value",
+               "empty-list", "no-list", "many-rows", "many-rows-tombstoned"]
+# shape -> the error the pure twin raises once the C pass has declined
+SCAN_DECLINES = {"row-past-columns": IndexError,
+                 "non-bytes-blob": TypeError,
+                 "non-int-row": IndexError}
+
+
+@pytest.mark.parametrize("shape", SCAN_SHAPES)
+@pytest.mark.parametrize("kind", list(SCAN_KINDS))
+def test_fused_scan_is_the_byte_twin_of_gather_plus_encode(kind, shape):
+    scan = codec._scan_reply()
+    if scan is None:
+        pytest.skip("native extension not built")
+    ks, kid = scan_state(kind, shape)
+    want = bytearray(b"head")
+    twin = codec._py_scan_reply_into(want, kind, kid, ks)
+    # the twin is the documented composition, through the row encoder
+    rows = ks.elem_live_rows_batch([kid])[0].tolist()
+    assert twin == codec.encode_rows_into(bytearray(), kind, rows,
+                                          ks.el_member, ks.el_val)
+    if shape.startswith("many-rows"):
+        assert len(ks.el_rows_by_kid[kid]) >= 64   # the vectorized leg
+        assert len(rows) == (100 if shape == "many-rows" else 67)
+    elif shape in ("tombstoned", "stale-rows"):
+        assert len(rows) == (7 if shape == "tombstoned" else 10)
+        assert len(ks.el_rows_by_kid[kid]) > len(rows)
+    out = bytearray(b"head")
+    payload, native = codec.scan_replier(ks)(out, kind, kid)
+    assert native is True
+    assert type(payload) is bytes and payload == twin
+    assert out == want and bytes(out) == b"head" + twin
+
+
+@pytest.mark.parametrize("shape", list(SCAN_DECLINES))
+@pytest.mark.parametrize("kind", list(SCAN_KINDS))
+def test_fused_scan_declines_with_nothing_appended(kind, shape):
+    scan = codec._scan_reply()
+    if scan is None:
+        pytest.skip("native extension not built")
+    ks, kid = scan_state(kind, shape)
+    el = ks.el
+    out = bytearray(b"head")
+    assert scan(out, codec._ROW_KINDS[kind], kid, ks.el_rows_by_kid[kid],
+                el.kid, el.add_t, el.del_t, ks.el_member,
+                ks.el_val) is None
+    assert out == b"head"
+    # through the replier the pure twin then raises its own error
+    with pytest.raises(SCAN_DECLINES[shape]):
+        codec.scan_replier(ks)(out, kind, kid)
+    assert out == b"head"
+
+
+@pytest.mark.parametrize("column", ["int32", "strided", "not-a-buffer",
+                                    "rows-not-a-list", "values-kind"])
+def test_fused_scan_declines_columns_it_cannot_read(column):
+    scan = codec._scan_reply()
+    if scan is None:
+        pytest.skip("native extension not built")
+    ks, kid = scan_state("pairs", "all-live")
+    el = ks.el
+    rows = ks.el_rows_by_kid[kid]
+    cols = [el.kid, el.add_t, el.del_t]
+    code = 1
+    if column == "int32":
+        cols[1] = el.add_t.astype(np.int32)
+    elif column == "strided":
+        cols[2] = np.repeat(el.del_t, 2)[::2]
+    elif column == "not-a-buffer":
+        cols[0] = el.kid.tolist()
+    elif column == "rows-not-a-list":
+        rows = tuple(rows)
+    else:
+        code = codec._ROW_KINDS["values"]
+    out = bytearray(b"head")
+    assert scan(out, code, kid, rows, *cols, ks.el_member,
+                ks.el_val) is None
+    assert out == b"head"
+
+
+@pytest.mark.parametrize("kind", list(SCAN_KINDS))
+def test_extension_without_the_entry_point_degrades_to_the_twin(
+        kind, monkeypatch):
+    """A cst_ext.so from before resp_scan_reply existed: `_scan_reply`
+    resolves to None once and the pure twin answers, flagged not native."""
+    from constdb_tpu.utils import native_tables
+    ext = native_tables.load_ext()
+    if ext is None:
+        pytest.skip("native extension not built")
+    older = types.SimpleNamespace(**{
+        name: getattr(ext, name) for name in dir(ext)
+        if name != "resp_scan_reply" and not name.startswith("__")})
+    monkeypatch.setattr(codec, "_SCAN_REPLY_CACHE", [])
+    monkeypatch.setattr(native_tables, "load_ext", lambda: older)
+    ks, kid = scan_state(kind, "tombstoned")
+    want = bytearray()
+    twin = codec._py_scan_reply_into(want, kind, kid, ks)
+    out = bytearray()
+    payload, native = codec.scan_replier(ks)(out, kind, kid)
+    assert native is False and payload == twin and out == want
+    assert codec._SCAN_REPLY_CACHE == [None]
+
+
+def scan_pipeline() -> list:
+    """HGETALL / SMEMBERS hits and misses with an HSET / HDEL between
+    them; every chunk long enough to take the planner."""
+    reads = [cmd(b"hgetall", b"h%d" % i) for i in range(4)] + \
+        [cmd(b"smembers", b"s%d" % i) for i in range(3)]
+    return [
+        [cmd(b"hset", b"h%d" % (i % 4), b"f%d" % i, b"v%d" % i)
+         for i in range(24)] +
+        [cmd(b"sadd", b"s%d" % (i % 3), b"m%d" % i) for i in range(12)],
+        reads,                                    # 7 misses
+        reads + [cmd(b"hgetall", b"nokey")],      # 7 hits, 1 absent key
+        [cmd(b"hgetall", b"h0"), cmd(b"hset", b"h1", b"f1", b"new"),
+         cmd(b"hgetall", b"h1"), cmd(b"hdel", b"h2", b"f2"),
+         cmd(b"smembers", b"s0"), cmd(b"hgetall", b"h2"),
+         cmd(b"hlen", b"h1"), cmd(b"hgetall", b"h1"),
+         cmd(b"srem", b"s1", b"m1"), cmd(b"smembers", b"s1"),
+         cmd(b"scnt", b"s1"), cmd(b"hgetall", b"h3")],
+    ]
+
+
+def test_pipelined_scans_reply_as_the_per_command_path_and_are_counted(
+        tmp_path):
+    """Through ServeCoalescer: replies byte-identical to the per-command
+    path, in order, and `serve_read_scans_native` = the members / pairs
+    misses of existing keys (never a hit, an absent key's constant, a
+    count or a write).  The shard workers' fold of the counter is held
+    by test_counter_rides_shard_worker_acks."""
+    if codec._scan_reply() is None:
+        pytest.skip("native extension not built")
+    work = [scan_pipeline()]
+
+    async def main():
+        ref = await drive_node(tmp_path / "ref", 1, work)
+        one = await drive_node(tmp_path / "one", 64, work)
+        return ref, one
+
+    (ref_raw, _c, _r, ref_node), (one_raw, _c1, _r1, one_node) = \
+        asyncio.run(main())
+    assert one_raw == ref_raw
+    assert ref_node.stats.serve_read_scans_native == 0   # never planned
+    # chunk 2: 7 misses; chunk 3: hits + an absent key; chunk 4: h1, h2
+    # and s1 were written since their fill, h1's second read sits in the
+    # same run as its first (the fill comes at the run's end), and HLEN /
+    # SCNT count but do not scan
+    assert one_node.stats.serve_read_scans_native == 7 + 4
+    assert info_of(one_node)["serve_read_scans_native"] == 11
+    assert one_node.stats.serve_read_replies_direct == 7 + 1 + 4 + 2
+
+
 def test_single_value_replies():
     assert codec.bulk_reply(None) == b"$-1\r\n"
     assert codec.bulk_reply(b"") == b"$0\r\n\r\n"
@@ -340,7 +549,8 @@ def test_counter_is_the_planned_misses_and_in_info(tmp_path, monkeypatch,
 
 def test_counter_rides_shard_worker_acks(tmp_path):
     """serve_shards=2: the workers' planners write the replies, and the
-    parent's INFO total is the fold of their acks — not silently zero."""
+    parent's INFO totals (direct replies, native scans) are the fold of
+    their acks — not silently zero."""
     work = [[[cmd(b"hset", b"h%d" % i, b"f", b"v%d" % i) for i in range(8)],
              [cmd(b"hgetall", b"h%d" % i) for i in range(8)],
              [cmd(b"hgetall", b"h%d" % i) for i in range(8)]]]
@@ -354,4 +564,7 @@ def test_counter_rides_shard_worker_acks(tmp_path):
     assert g_raw == w_raw
     assert w_node.stats.serve_read_replies_direct == 8
     assert g_node.stats.serve_read_replies_direct == 8
+    if codec._scan_reply() is not None:   # all eight are HGETALL misses
+        assert w_node.stats.serve_read_scans_native == 8
+        assert g_node.stats.serve_read_scans_native == 8
     assert g_node.read_cache.misses == 8 and g_node.read_cache.hits == 8

@@ -19,7 +19,7 @@ Pinned here:
     (`merge_<fam>_seconds`, `merge_seconds_total`,
     `flush_seconds_total`) still read above 0;
   * every counter a per-layer metric names — the ten in BENCHMARK.json
-    and the fourteen specs of docs/stage_layers/ — is an INFO key of a
+    and the fifteen specs of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
 """
@@ -391,7 +391,7 @@ def test_documented_totals_still_read_above_zero():
 
 
 def layer_specs() -> list:
-    """Every per-layer metric BENCHMARK.json names, and the fourteen the
+    """Every per-layer metric BENCHMARK.json names, and the fifteen the
     stage counters are for (docs/stage_layers/: a `benchmark` PR moves
     them under benchmark/layers/ — see docs/stage_layers/README.md)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -427,7 +427,7 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    assert len(specs) == 10 + 14
+    assert len(specs) == 10 + 15
     missing = {s["name"]: [c for c in counters_of(s) if c not in info]
                for s in specs}
     assert not {k: v for k, v in missing.items() if v}
@@ -445,8 +445,8 @@ def benchmark_module(name: str):
 
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
-    """docs/stage_layers/overlay.py on a scratch copy: 14 files beside the
-    10, 14 entries at the END of per_layer, nothing else changed — and
+    """docs/stage_layers/overlay.py on a scratch copy: 15 files beside the
+    10, 15 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -460,7 +460,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     added = mod.overlay(str(tmp_path))
-    assert len(added) == 14 and mod.overlay(str(tmp_path)) == []
+    assert len(added) == 15 and mod.overlay(str(tmp_path)) == []
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -470,7 +470,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     assert [m["name"] for m in after["per_layer"][10:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 24
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 25
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
@@ -481,7 +481,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
             "compared": {"reads_wrong": {"value": 0, "limit": 0}}}
     assert validate.check_line(line, before, "ycsb-b", True) == []
     refused = validate.check_line(line, after, "ycsb-b", True)
-    assert len(refused) == 14 and all("is missing" in e for e in refused)
+    assert len(refused) == 15 and all("is missing" in e for e in refused)
 
 
 def test_stage_layer_specs_read_through_the_benchmarks_readers():
@@ -494,6 +494,9 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
     ServeCoalescer(node).run_chunk([cmd(b"sadd", b"s", b"lone")],
                                    bytearray())
     sadd_round(node, 18, members=5)
+    # two planned SMEMBERS misses: the fused scan pass answers both
+    ServeCoalescer(node).run_chunk([cmd(b"smembers", b"s")] * 2,
+                                   bytearray())
     node.ensure_flushed()
     window = {"info_before": before, "info_after": info_of(node),
               "ops": 24, "kops": 0.024, "seconds": 2.0}
@@ -511,6 +514,7 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
     assert len(per_op) == 10
     assert got["device_merged_row_share.serve"] == 100.0
     assert got["mirror_patch_share.serve"] == 100.0     # 1 patch, 0 rebuilds
+    assert got["read_scan_native_share.serve"] == 100.0  # 2 of 2 misses
     # every stage is in exactly one of the ten per-op metrics (a patch in
     # the engine's) or in the rebuild share, so the ten add up to the
     # traced share less rebuilds
