@@ -474,14 +474,17 @@ class CoalescingApplier:
         st.repl_wire_batches_in += 1
         st.repl_wire_batch_frames_in += n
         node.hlc.observe(last)
-        node.merge_stream_batch(wb, n)
-        if node.oplog is not None:
-            # the (decompressed) payload IS the columnar wire encoding
-            # and was just crc-validated whole: splice it into the
-            # durable op log verbatim — zero re-encode (persist/oplog.py)
-            node.oplog.append_batch(origin, first_prev, last, n, payload)
-        self.cursor = last
-        self._advance(last, wake=True)
+        with node.stages.stage("repl_flush"):
+            node.merge_stream_batch(wb, n)
+            if node.oplog is not None:
+                # the (decompressed) payload IS the columnar wire
+                # encoding and was just crc-validated whole: splice it
+                # into the durable op log verbatim — zero re-encode
+                # (persist/oplog.py)
+                node.oplog.append_batch(origin, first_prev, last, n,
+                                        payload)
+            self.cursor = last
+            self._advance(last, wake=True)
 
     def observe_beacon(self, beacon: int) -> None:
         """REPLACK drained-stream beacon: may only advance the pull
@@ -523,6 +526,13 @@ class CoalescingApplier:
         if not frames:
             return
         self._pending_keys.clear()
+        with self.node.stages.stage("repl_flush"):
+            self._land(buf, frames)
+
+    def _land(self, buf: dict, frames: int) -> None:
+        """`flush`'s body, under the `repl_flush` stage: group encode,
+        merge, watermark (the engine's stages nested in the merge keep
+        their own time)."""
         node = self.node
         if node.reset_epoch != self._epoch:
             # a state wipe landed between intake and flush (another

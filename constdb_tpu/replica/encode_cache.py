@@ -46,11 +46,11 @@ class CachedRun:
     """One published wire encoding of a drained run."""
 
     __slots__ = ("end", "payload", "batches", "batch_frames",
-                 "comp_raw", "comp_wire", "refs")
+                 "comp_raw", "comp_wire", "refs", "frames")
 
     def __init__(self, end: int, payload: bytes, batches: int,
                  batch_frames: int, comp_raw: int,
-                 comp_wire: int, refs: int):
+                 comp_wire: int, refs: int, frames: int = 0):
         self.end = end                  # cursor after the run
         self.payload = payload          # finished wire bytes
         self.batches = batches          # REPLBATCH frames inside
@@ -58,6 +58,7 @@ class CachedRun:
         self.comp_raw = comp_raw        # compression accounting
         self.comp_wire = comp_wire
         self.refs = refs                # expected remaining readers
+        self.frames = frames            # log entries the run holds
 
 
 class RunEncodeCache:
@@ -116,7 +117,7 @@ class RunEncodeCache:
     def put(self, caps_class: str, cursor: int, end: int, payload: bytes,
             batches: int = 0, batch_frames: int = 0,
             comp_raw: int = 0, comp_wire: int = 0,
-            readers: int = 0) -> None:
+            readers: int = 0, frames: int = 0) -> None:
         """Publish a finished encoding.  `readers`: how many OTHER links
         are expected to drain this range — <= 0 skips caching entirely
         (nobody to share with)."""
@@ -126,7 +127,7 @@ class RunEncodeCache:
         if key in self._map:
             self._drop(key)
         self._map[key] = CachedRun(end, payload, batches, batch_frames,
-                                   comp_raw, comp_wire, readers)
+                                   comp_raw, comp_wire, readers, frames)
         self.bytes += len(payload)
         self._shrink()
 

@@ -659,6 +659,7 @@ class ReplicaLink:
             from ..conf import env_int
             window = env_int("CONSTDB_REPL_WINDOW", 16 << 20)
         from collections import deque
+        stage = node.stages.stage
         inflight: deque = deque()
         inflight_bytes = 0
         paused = False
@@ -801,87 +802,99 @@ class ReplicaLink:
                     nonlocal inflight_bytes
                     inflight.append((cursor, len(buf)))
                     inflight_bytes += len(buf)
-                    return self._flush_wire(writer, buf)
+                    with stage("repl_push"):
+                        return self._flush_wire(writer, buf)
 
                 while not paused:
-                    hit = None
-                    if cache.enabled:
-                        # the splice honors the same emission floor
-                        # run_after applies (encode_cache.get docstring:
-                        # a published-but-not-yet-durable run must not
-                        # be emitted through the cache side door)
-                        fl = getattr(node.repl_log, "floor", None)
-                        hit = cache.get(
-                            caps_class, cursor,
-                            below=fl() if callable(fl) else None)
-                    if hit is not None:
-                        # published by another peer's loop at this exact
-                        # cursor: splice the finished bytes and republish
-                        # the per-send wire counters from the entry
-                        out += hit.payload
-                        cursor = hit.end
-                        self.cache_hits += 1
-                        st = node.stats
-                        st.repl_encode_cache_hits += 1
-                        st.repl_wire_batches_out += hit.batches
-                        st.repl_wire_batch_frames_out += hit.batch_frames
-                        st.repl_comp_raw_bytes += hit.comp_raw
-                        st.repl_comp_wire_bytes += hit.comp_wire
-                        self.comp_raw_bytes += hit.comp_raw
-                        self.comp_wire_bytes += hit.comp_wire
-                    else:
-                        # byte-capped runs: the flush bound below must
-                        # get a chance to engage BEFORE a backlog of
-                        # huge values is encoded into one frame/buffer
-                        # (a lone oversized entry still ships whole, as
-                        # per-frame always did)
-                        run = node.repl_log.run_after(
-                            cursor,
-                            wire_batch if batching else _RUN_FRAMES,
-                            _WIRE_FLUSH_BYTES)
-                        if not run:
-                            break
-                        if run[0].prev_uuid > cursor:
-                            # the ring evicted past our cursor while this
-                            # loop yielded (the drain below): streaming
-                            # the run would hand the peer a gap, blow up
-                            # its pull loop (ReplicateCommandsLost) and
-                            # force a teardown + redial + snapshot over a
-                            # FRESH connection.  Recover IN PLACE
-                            # instead: stop here and let the round
-                            # decision re-send a full snapshot on this
-                            # same stream (eviction past the cursor
-                            # implies can_resume_from(cursor) is False).
-                            # This is the fallback the module header
-                            # documents — the reference leaves the case
-                            # unhandled (pull.rs:167-172).
-                            log.warning(
-                                "push %s: repl_log evicted past send "
-                                "cursor mid-stream; resyncing in place",
-                                meta.addr)
-                            break
-                        seg = bytearray()
-                        start = cursor
-                        if batching:
-                            (cursor, nb, nbf, craw,
-                             cwire) = self._encode_wire_run(
-                                seg, run, cursor, compress=compressing,
-                                comp_min=wire_comp_min)
-                        else:
-                            self._encode_frames(seg, run)
-                            cursor = run[-1].uuid
-                            nb = nbf = craw = cwire = 0
-                        self.comp_raw_bytes += craw
-                        self.comp_wire_bytes += cwire
+                    # one drained run a step, under the stage: log
+                    # read, encode cache, wire batch (the socket
+                    # write is `flush_out`'s, staged there)
+                    with stage("repl_push"):
+                        hit = None
                         if cache.enabled:
-                            self.cache_misses += 1
-                            node.stats.repl_encode_cache_misses += 1
-                            cache.put(caps_class, start, cursor,
-                                      bytes(seg), batches=nb,
-                                      batch_frames=nbf, comp_raw=craw,
-                                      comp_wire=cwire,
-                                      readers=self._expected_readers())
-                        out += seg
+                            # the splice honors the same emission
+                            # floor run_after applies (encode_cache.get
+                            # docstring: a published-but-not-yet-durable
+                            # run must not be emitted through the cache
+                            # side door)
+                            fl = getattr(node.repl_log, "floor", None)
+                            hit = cache.get(
+                                caps_class, cursor,
+                                below=fl() if callable(fl) else None)
+                        if hit is not None:
+                            # published by another peer's loop at this
+                            # exact cursor: splice the finished bytes and
+                            # republish the per-send wire counters from
+                            # the entry
+                            out += hit.payload
+                            cursor = hit.end
+                            self.cache_hits += 1
+                            st = node.stats
+                            st.repl_ops_out += hit.frames
+                            st.repl_encode_cache_hits += 1
+                            st.repl_wire_batches_out += hit.batches
+                            st.repl_wire_batch_frames_out += \
+                                hit.batch_frames
+                            st.repl_comp_raw_bytes += hit.comp_raw
+                            st.repl_comp_wire_bytes += hit.comp_wire
+                            self.comp_raw_bytes += hit.comp_raw
+                            self.comp_wire_bytes += hit.comp_wire
+                        else:
+                            # byte-capped runs: the flush bound below
+                            # must get a chance to engage BEFORE a backlog
+                            # of huge values is encoded into one frame/
+                            # buffer (a lone oversized entry still ships
+                            # whole, as per-frame always did)
+                            run = node.repl_log.run_after(
+                                cursor,
+                                wire_batch if batching else _RUN_FRAMES,
+                                _WIRE_FLUSH_BYTES)
+                            if not run:
+                                break
+                            if run[0].prev_uuid > cursor:
+                                # the ring evicted past our cursor while
+                                # this loop yielded (the drain below):
+                                # streaming the run would hand the peer a
+                                # gap, blow up its pull loop
+                                # (ReplicateCommandsLost) and force a
+                                # teardown + redial + snapshot over a FRESH
+                                # connection.  Recover IN PLACE instead:
+                                # stop here and let the round decision
+                                # re-send a full snapshot on this same
+                                # stream (eviction past the cursor implies
+                                # can_resume_from(cursor) is False).  This
+                                # is the fallback the module header
+                                # documents — the reference leaves the
+                                # case unhandled (pull.rs:167-172).
+                                log.warning(
+                                    "push %s: repl_log evicted past send "
+                                    "cursor mid-stream; resyncing in place",
+                                    meta.addr)
+                                break
+                            seg = bytearray()
+                            start = cursor
+                            if batching:
+                                (cursor, nb, nbf, craw,
+                                 cwire) = self._encode_wire_run(
+                                    seg, run, cursor, compress=compressing,
+                                    comp_min=wire_comp_min)
+                            else:
+                                self._encode_frames(seg, run)
+                                cursor = run[-1].uuid
+                                nb = nbf = craw = cwire = 0
+                            self.comp_raw_bytes += craw
+                            self.comp_wire_bytes += cwire
+                            node.stats.repl_ops_out += len(run)
+                            if cache.enabled:
+                                self.cache_misses += 1
+                                node.stats.repl_encode_cache_misses += 1
+                                cache.put(caps_class, start, cursor,
+                                          bytes(seg), batches=nb,
+                                          batch_frames=nbf, comp_raw=craw,
+                                          comp_wire=cwire,
+                                          readers=self._expected_readers(),
+                                          frames=len(run))
+                            out += seg
                     if len(out) >= _WIRE_FLUSH_BYTES or \
                             loop.time() - t_flush >= wire_latency:
                         out = flush_out(out)
@@ -1343,9 +1356,36 @@ class ReplicaLink:
         finally:
             gov.unregister_source(src)
 
+    def _ingest(self, parser, applier):
+        """The steady stream's share of one socket read, synchronously:
+        every leading REPLICATE / REPLBATCH frame parsed and handed to the
+        applier (dup-skip, gap check, buffering, or a wire batch's land).
+        -> the first frame of another kind — the awaiting path's — or
+        None when the parser holds no complete frame.  One `repl_ingest`
+        stage entry per call, not per frame (utils/stagetime.py)."""
+        with self.node.stages.stage("repl_ingest"):
+            while True:
+                msg = parser.next_msg()
+                items = msg.items if isinstance(msg, Arr) else None
+                if not items:
+                    return msg
+                kind = as_bytes(items[0]).lower()
+                if kind == REPLICATE:
+                    applier.apply(items)
+                elif kind == REPLBATCH:
+                    applier.apply_wire_batch(items)
+                else:
+                    return msg
+                self.meta.last_seen_ms = now_ms()
+
     async def _pull_frames(self, reader, writer, parser, applier) -> None:
+        from .coalesce import CoalescingApplier
+        # a shard-routing applier genuinely awaits its workers: its
+        # stream frames take the awaiting branches below
+        sync = isinstance(applier, CoalescingApplier)
         while True:
-            msg = parser.next_msg()
+            msg = self._ingest(parser, applier) if sync \
+                else parser.next_msg()
             if msg is None:
                 if applier.pending:
                     await applier.aflush()  # stream idle: land now

@@ -114,6 +114,15 @@ def _section_stats(node, out):
     out.append(("repl_frames_coalesced", st.repl_frames_coalesced))
     out.append(("repl_coalesce_flushes", st.repl_coalesce_flushes))
     out.append(("repl_apply_barriers", st.repl_apply_barriers))
+    # the link's staleness where batches land (mean lag in ms = sum / n),
+    # frames pushed to peers, and which side feeds the merge path: rows
+    # from peers' streams against rows of this node's own coalesced
+    # writes (frames applied from peers = coalesced + barriers, above)
+    out.append(("repl_apply_lag_ms_sum", st.repl_apply_lag_ms_sum))
+    out.append(("repl_apply_lag_n", st.repl_apply_lag_n))
+    out.append(("repl_ops_out", st.repl_ops_out))
+    out.append(("merge_rows_repl", st.merge_rows_repl))
+    out.append(("merge_rows_serve", st.merge_rows_serve))
     # batch wire protocol (replica/wire.py REPLBATCH): aggregated
     # steady-state stream bytes out, group-encoded runs sent/received
     # (with the op frames they covered), and receiver-side payload

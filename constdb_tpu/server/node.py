@@ -15,7 +15,7 @@ from typing import Optional
 
 from ..engine.cpu import CpuMergeEngine
 from ..store.keyspace import KeySpace
-from ..utils.hlc import HLC
+from ..utils.hlc import HLC, SEQ_BITS, now_ms
 from ..utils.stagetime import StageClock, seconds_into
 from .events import EVENT_REPLICATED, EventBus
 from .repl_log import ReplLog
@@ -43,6 +43,20 @@ class NodeStats:
     repl_frames_coalesced: int = 0
     repl_coalesce_flushes: int = 0
     repl_apply_barriers: int = 0
+    # ... and how stale those frames were when their batch landed: the
+    # sum over landed frames of (this node's clock - the frame's stamp),
+    # in ms, and the count of frames it sums over (a mean lag = sum / n;
+    # clocks of different hosts make it an offset plus the lag)
+    repl_apply_lag_ms_sum: int = 0
+    repl_apply_lag_n: int = 0
+    # frames the push loops wrote to peers, once per peer (encoded or
+    # spliced from the encode-once cache alike)
+    repl_ops_out: int = 0
+    # rows the merge engine took from peers' streams and from this
+    # node's own coalesced client writes (merge_stream_batch /
+    # merge_serve_batch): which side feeds the merge path
+    merge_rows_repl: int = 0
+    merge_rows_serve: int = 0
     # columnar wire protocol (replica/wire.py REPLBATCH): steady-state
     # stream bytes written by the push loop's aggregated flushes (frames
     # only — snapshots/acks ride repl_out_bytes), batch frames
@@ -461,9 +475,19 @@ class Node:
         so consecutive stream batches merge in place on device with no
         flush round-trip between them."""
         self.ensure_flushed_for(("env",))
-        self.merge_batches([builder.finalize()])
-        self.stats.repl_frames_coalesced += frames
-        self.stats.repl_coalesce_flushes += 1
+        b = builder.finalize()
+        self.merge_batches([b])
+        st = self.stats
+        st.repl_frames_coalesced += frames
+        st.repl_coalesce_flushes += 1
+        st.merge_rows_repl += b.n_rows
+        # staleness, read where the batch LANDS: a frame's key row
+        # carries its uuid as mt (BatchBuilder.add_keys/add_del_keys, the
+        # wire decoder), whose upper bits are the origin's clock in ms
+        n = len(b.key_mt)
+        st.repl_apply_lag_n += n
+        st.repl_apply_lag_ms_sum += n * now_ms() - \
+            int((b.key_mt >> SEQ_BITS).sum())
 
     def merge_serve_batch(self, builder, msgs: int) -> None:
         """Land one coalesced client-serving micro-batch (the pipelined
@@ -475,9 +499,11 @@ class Node:
         repl-logged by the caller, so logged=True keeps the shared
         full-sync dump reusable."""
         self.ensure_flushed_for(("env",))
-        self.merge_batches([builder.finalize()], logged=True)
+        b = builder.finalize()
+        self.merge_batches([b], logged=True)
         self.stats.serve_msgs_coalesced += msgs
         self.stats.serve_flushes += 1
+        self.stats.merge_rows_serve += b.n_rows
 
     def reset_for_full_resync(self, keep_link=None) -> None:
         """Wipe local CRDT state and rejoin as a fresh node (the receive

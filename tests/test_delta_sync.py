@@ -273,6 +273,13 @@ async def _sever(apps) -> None:
     await asyncio.sleep(0.1)
 
 
+async def _until(cond, timeout: float) -> None:
+    """Poll until `cond()` holds (an assertion after it says what did not)."""
+    deadline = asyncio.get_running_loop().time() + timeout
+    while not cond() and asyncio.get_running_loop().time() < deadline:
+        await asyncio.sleep(0.02)
+
+
 def _rejoin(apps) -> None:
     for app in apps:
         for m in app.node.replicas.peers.values():
@@ -324,6 +331,11 @@ def test_delta_resync_e2e(tmp_path):
             await converge(apps, timeout=30)
 
             st = a.node.stats
+            # b may land the delta (and `converge` see it) before a's push
+            # loop runs on from its stream's last drain to count the
+            # resync: wait for the count, not for the scheduler
+            await _until(lambda: st.repl_delta_syncs
+                         + st.repl_full_syncs - full0 >= 1, timeout=15)
             assert st.repl_delta_syncs >= 1, "resync did not go delta"
             assert st.repl_full_syncs == full0, \
                 "delta resync fell back to a snapshot"
@@ -579,6 +591,7 @@ def test_delta_resync_from_sharded_pusher(tmp_path):
             _rejoin(apps)
             await converge_plane(apps)
             st = a.node.stats
+            await _until(lambda: st.repl_delta_syncs >= 1, timeout=15)
             assert st.repl_delta_syncs >= 1, "plane pusher never went delta"
             await c.close()
         finally:

@@ -48,6 +48,9 @@ from test_serve_coalesce import cmd, read_replies
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMS = ("env", "reg", "cnt", "el")
+# the replication link's counters beside its three stages (server/info.py)
+LINK_COUNTERS = ["repl_apply_lag_ms_sum", "repl_apply_lag_n", "repl_ops_out",
+                 "merge_rows_repl", "merge_rows_serve"]
 
 
 @pytest.fixture
@@ -200,7 +203,7 @@ def test_only_per_flush_stages_open_trace_spans():
     assert seen.count(("exit", "cst.mirror_rebuild.el")) == 1
     assert ANNOTATED == {"serve_flush", "stage_rows", "h2d", "dispatch",
                          "host_twin", "mirror_rebuild", "mirror_patch",
-                         "state_alloc", "d2h_flush"}
+                         "state_alloc", "d2h_flush", "repl_flush"}
     # without an annotation the same stages are counters like the others
     plain = StageClock()
     with plain.stage("d2h_flush", "el"):
@@ -240,11 +243,13 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want += [f"mirror_rebuilds_cause_{c}" for c in TOUCH_CAUSES]
     want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
-    assert len(want) == 2 * 15 + 8 + 6 + 7
+    want += LINK_COUNTERS
+    assert len(want) == 2 * 18 + 8 + 6 + 7 + 5
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
     # a CPU-engine node has the clock, not the device engine's counters
     cpu = info_of(Node(node_id=2))
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
+    assert all(cpu[k] == 0 for k in LINK_COUNTERS)
     assert "merge_rows_dev_el" not in cpu
 
 
@@ -286,6 +291,48 @@ def test_pipelined_chunk_through_a_socket_moves_the_loop_stages(tmp_path):
     assert total < wall_us
     # fewer than one stage entry per operation
     assert sum(info[f"span_{s}_n"] for s in STAGES) < 3 * 28
+
+
+def test_a_peers_stream_moves_the_links_stages_and_counters(tmp_path):
+    """Two nodes, one MEET, pipelined writes at each: the pusher's
+    `repl_push` and `repl_ops_out`, the puller's `repl_ingest`,
+    `repl_flush`, lag and row counters all move; a node with no peer
+    never enters them."""
+    from cluster_util import close_cluster, converge, make_cluster
+
+    async def main():
+        apps = await make_cluster(2, str(tmp_path), serve_batch=512)
+        a, b = apps
+        try:
+            ca = await Client().connect(a.advertised_addr)
+            cb = await Client().connect(b.advertised_addr)
+            await ca.cmd("meet", b.advertised_addr)
+            for c, tag in ((ca, b"a"), (cb, b"b")):
+                chunk = [cmd(b"hset", b"h%d" % (i % 4), tag + b"%d" % i,
+                             b"v") for i in range(24)]
+                for _ in range(3):
+                    c.writer.write(b"".join(encode_msg(m) for m in chunk))
+                    await c.writer.drain()
+                    await read_replies(c, bytearray(), len(chunk))
+            await converge(apps, timeout=20)
+            await ca.close()
+            await cb.close()
+            return info_of(a.node), info_of(b.node)
+        finally:
+            await close_cluster(apps)
+
+    for info in asyncio.run(main()):
+        for s in ("repl_ingest", "repl_flush", "repl_push"):
+            assert info[f"span_{s}_n"] > 0, s
+        assert info["span_repl_ingest_us"] + info["span_repl_flush_us"] > 0
+        assert info["repl_ops_out"] >= 72          # its own 72 writes, out
+        assert info["repl_apply_lag_n"] >= 72      # the peer's 72, landed
+        assert 0 <= info["repl_apply_lag_ms_sum"] < 72 * 20_000
+        assert info["merge_rows_repl"] >= 2 * 72   # a key row + an el row
+        assert info["merge_rows_serve"] > 0
+        assert info["repl_apply_lag_n"] == info["repl_frames_coalesced"]
+    lone = info_of(Node(node_id=9))
+    assert lone["span_repl_push_n"] == lone["span_repl_ingest_n"] == 0
 
 
 # ------------------------------------------------------- the device engine
@@ -427,7 +474,10 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    assert len(specs) == 10 + 15
+    assert len(specs) == 10 + 5 + 15     # the five of the replication link
+    link = [s for s in specs if s["layer"] == "replication link"]
+    assert len(link) == 5 and all(
+        s["workloads"] == ["aa-3node-ycsb-a"] for s in link)
     missing = {s["name"]: [c for c in counters_of(s) if c not in info]
                for s in specs}
     assert not {k: v for k, v in missing.items() if v}
@@ -446,7 +496,7 @@ def benchmark_module(name: str):
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     """docs/stage_layers/overlay.py on a scratch copy: 15 files beside the
-    10, 15 entries at the END of per_layer, nothing else changed — and
+    15, 15 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -466,15 +516,16 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     with open(tmp_path / "BENCHMARK.json") as f:
         after = json.load(f)
     assert validate.check_manifest(after) == []
-    assert after["per_layer"][:10] == before["per_layer"]
-    assert [m["name"] for m in after["per_layer"][10:]] == added
+    assert after["per_layer"][:15] == before["per_layer"]
+    assert [m["name"] for m in after["per_layer"][15:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 25
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 30
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
-                        for m in before["per_layer"]},
+                        for m in before["per_layer"]
+                        if "ycsb-b" in m["workloads"]},
             "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
                        "memory_peak_bytes": 1, "window_s": 2.0,
                        "busy_s": 1.0},
