@@ -96,7 +96,8 @@ class StagePureRule(Rule):
 
     DEVICE_MARKERS = {
         "_jax", "_put_state", "_put_batch", "_device_get", "_full",
-        "_grow", "_src_state", "_resident_state", "_family_done",
+        "_grow", "_plane_get", "_src_state", "_resident_state",
+        "_family_done",
         "_pool_add", "flush", "device_put", "device_get",
     }
     HEAVY_STAGE_CALLS = {"self._stacked", "self._combine_groups",

@@ -12,6 +12,10 @@ Device strategies, picked per CRDT family:
   * scatter (ops/segment.py): touched-slot gather + scatter-max kernels.
     Chosen for sparse merges when state is host-resident.
 
+Per-slot device state is held as ops/bulk.py `Plane`s — (hi int32, lo
+uint32) pairs, never int64 arrays (docs/INVARIANTS.md, PLANE-PAIR); the
+batch columns this module stages, uploads and downloads stay int64.
+
 **Resident mode** (`TpuMergeEngine(resident=True)`): the per-family device
 state persists ACROSS merge calls, so streaming replica catch-up — the
 replica link applies a snapshot chunk-by-chunk, and each chunk is one
@@ -81,6 +85,16 @@ def _pad(arr: np.ndarray, size: int, fill) -> np.ndarray:
     out[: len(arr)] = arr
     out[len(arr):] = fill
     return out
+
+
+def _pad_idx(rows: np.ndarray, sp: int, size: int) -> np.ndarray:
+    """int32 scatter idx of `size` rows: `rows`, then distinct slots past
+    the plane (`sp`), which every scatter drops."""
+    n = len(rows)
+    idx = np.empty(size, dtype=_I32)
+    idx[:n] = rows
+    idx[n:] = sp + np.arange(size - n, dtype=_I32)
+    return idx
 
 
 # family -> [(column name in the family's host table, neutral fill)]
@@ -502,10 +516,12 @@ class TpuMergeEngine:
         return sp
 
     def _put_state(self, host: np.ndarray):
+        """A host int64 column (or [n, C] stack) as a device Plane:
+        uploaded under the state sharding, split once on the device (the
+        int64 upload is dropped as the split returns)."""
         self.bytes_h2d += host.nbytes
-        if self._mesh is None:
-            return self._jax.device_put(host)
-        return self._jax.device_put(host, self._sh_state[host.ndim])
+        sh = None if self._mesh is None else self._sh_state[host.ndim]
+        return B.plane_split(self._jax.device_put(host, sh))
 
     def _put_batch(self, arr: np.ndarray):
         self.bytes_h2d += arr.nbytes
@@ -521,44 +537,39 @@ class TpuMergeEngine:
         return out
 
     def _full(self, n: int, fill: int, cols: int = 0):
-        """Neutral state materialized on device with the state sharding
+        """Neutral Plane materialized on device with the state sharding
         (cols=0 → [n]; cols=C → [n, C])."""
         with self.stages.stage("state_alloc"):
             if self._mesh is None:
-                if cols:
-                    return self._jax.numpy.zeros(
-                        (n, cols), dtype=self._jax.numpy.int64)
-                return B.device_full(n, fill)
+                return B.device_full(n, fill, cols=cols)
             key = ("full", n, fill, cols)
             fn = self._jit_cache.get(key)
             if fn is None:
-                jnp = self._jax.numpy
                 shape = (n, cols) if cols else (n,)
                 fn = self._jax.jit(
-                    lambda: jnp.full(shape, fill, dtype=jnp.int64),
+                    lambda: B.neutral_plane(shape, fill),
                     out_shardings=self._sh_state[2 if cols else 1])
                 self._jit_cache[key] = fn
             return fn()
 
-    def _grow(self, old, delta: int, fill: int, cols: int = 0):
-        """Extend resident state by `delta` neutral rows, preserving the
+    def _grow(self, old, delta: int, fill: int):
+        """Extend a resident Plane by `delta` neutral rows, preserving the
         state sharding."""
-        jnp = self._jax.numpy
         if self._mesh is None:
-            if cols:
-                return jnp.concatenate(
-                    [old, jnp.zeros((delta, cols), dtype=jnp.int64)])
-            return jnp.concatenate([old, B.device_full(delta, fill)])
-        key = ("grow", delta, fill, cols)
+            return B.grown_plane(old, delta, fill)
+        key = ("grow", delta, fill, old.hi.ndim)
         fn = self._jit_cache.get(key)
         if fn is None:
-            shape = (delta, cols) if cols else (delta,)
             fn = self._jax.jit(
-                lambda o: jnp.concatenate(
-                    [o, jnp.full(shape, fill, dtype=jnp.int64)]),
-                out_shardings=self._sh_state[2 if cols else 1])
+                lambda o: B.grown_plane(o, delta, fill),
+                out_shardings=self._sh_state[old.hi.ndim])
             self._jit_cache[key] = fn
         return fn(old)
+
+    def _plane_get(self, plane, n: int) -> np.ndarray:
+        """Rows [0, n) of a device Plane as a host int64 array: one join
+        program over n rows, one download."""
+        return np.asarray(self._device_get(B.plane_rows(plane, n=n)))
 
     # ------------------------------------------------------------------ API
 
@@ -859,7 +870,7 @@ class TpuMergeEngine:
                     and not (recon and name in recon)]
             self.flush_rows_full_equiv += n
             if dirty is None:
-                fp = {name: cols[name][:n] for name in want}
+                fp = {name: B.plane_rows(cols[name], n=n) for name in want}
                 if res.get("src") is not None:
                     fp["src"] = res["src"][:n]
                 if fp:
@@ -1130,9 +1141,10 @@ class TpuMergeEngine:
         compaction, a reset), over the largest bucket, or not this
         engine's to trust forces the whole-plane rebuild from host.
 
-        `res["cols"]` is the only copy of a family's planes, always
-        int64: bulk rounds, micro rounds, the grow path and the patch all
-        read and replace the same arrays."""
+        `res["cols"]` is the only copy of a family's planes, each an
+        ops/bulk.py `Plane` — (hi int32, lo uint32), never an int64 array:
+        bulk rounds, micro rounds, the grow path and the patch all read
+        and replace the same pairs."""
         res = self._res.get(fam)
         ver = store.fam_ver[fam]
         stale = res is not None and res.get("ver") != ver
@@ -1181,8 +1193,7 @@ class TpuMergeEngine:
             delta = cap - res["cap"]
             with self.stages.stage("mirror_rebuild", fam):
                 if fam == "env":
-                    cols = {"stack": self._grow(old["stack"], delta, 0,
-                                                cols=len(spec))}
+                    cols = {"stack": self._grow(old["stack"], delta, 0)}
                 else:
                     cols = {c: self._grow(old[c], delta, fill)
                             for c, fill in spec}
@@ -1215,8 +1226,8 @@ class TpuMergeEngine:
         """Repair a stale mirror in place: gather the host columns at the
         journaled `rows` (sorted, distinct), upload them as one [Bp, C]
         block behind its int32 idx, and SET them into the resident planes
-        (donated).  Bp is one of MIRROR_PATCH_BUCKETS, padded by repeating
-        the last row — a set is idempotent."""
+        (donated).  Bp is one of MIRROR_PATCH_BUCKETS; the pad targets
+        distinct rows past the plane and drops, as a batch's does."""
         k = len(rows)
         self.mirror_patches[fam] += 1
         self.mirror_patch_rows[fam] += k
@@ -1224,11 +1235,12 @@ class TpuMergeEngine:
             return   # the version moved and no row did (a lost LWW write)
         with self.stages.stage("mirror_patch", fam):
             bp = next(b for b in self.MIRROR_PATCH_BUCKETS if b >= k)
-            idx = _pad(rows, bp, rows[-1])
             table = _host_table(store, fam)
-            vals = np.stack([table.col(c)[idx] for c, _ in _FAMILIES[fam]],
+            vals = np.stack([table.col(c)[rows] for c, _ in _FAMILIES[fam]],
                             axis=-1)
-            self._run_patch(fam, cols, idx.astype(_I32), vals)
+            cap = next(iter(cols.values())).shape[0]
+            self._run_patch(fam, cols, _pad_idx(rows, cap, bp),
+                            _pad(vals, bp, 0))
 
     def _run_patch(self, fam: str, cols: dict, idx: np.ndarray,
                    vals: np.ndarray) -> None:
@@ -1257,7 +1269,7 @@ class TpuMergeEngine:
             with self.stages.stage("state_alloc", fam):
                 for bp in self.MIRROR_PATCH_BUCKETS:
                     self._run_patch(
-                        fam, res["cols"], np.full(bp, cap, dtype=_I32),
+                        fam, res["cols"], _pad_idx(np.zeros(0, _I32), cap, bp),
                         np.zeros((bp, len(_FAMILIES[fam])), dtype=_I64))
                     if bp >= cap:
                         break
@@ -1630,7 +1642,7 @@ class TpuMergeEngine:
         # plane cap, not per row count
         ids = self._put_batch(_pad(store.cnt.kid[:n].astype(_I32),
                                    res["cap"], 0))
-        sums = D.segment_sum(ids, cols["val"] - cols["base"],
+        sums = D.segment_sum(ids, B.plane_diff(cols["val"], cols["base"]),
                              n_seg=K.next_pow2(nk))
         store.keys.cnt_sum[:nk] = np.asarray(self._device_get(sums))[:nk]
 
@@ -2244,11 +2256,7 @@ class TpuMergeEngine:
         r0 = self._iota_r0(rows, base)
         if r0 is not None:
             return self._iota_idx(np_)(r0, np.int32(n), np.int32(sp))
-        idx = np.empty(np_, dtype=_I32)
-        idx[:n] = rows - base
-        if np_ > n:
-            idx[n:] = sp + np.arange(np_ - n, dtype=_I32)
-        return self._put_batch(idx)
+        return self._put_batch(_pad_idx(rows - base, sp, np_))
 
     def _iota_idx(self, np_: int):
         """Jitted idx builder for one padded batch length (cached).  On a
@@ -2279,7 +2287,8 @@ class TpuMergeEngine:
     def _i32_up(arr: np.ndarray, fill64: int):
         """Opportunistic int32 upload spec: halves the bytes whenever the
         column's values fit (node ids, small counter values); the kernels
-        promote against the int64 state, so results are bit-identical."""
+        sign-extend and split every batch column themselves, so results
+        are bit-identical."""
         arr = np.asarray(arr)
         if len(arr) and -(1 << 31) <= int(arr.min()) and \
                 int(arr.max()) < (1 << 31):
@@ -2486,7 +2495,7 @@ class TpuMergeEngine:
             if self.resident:
                 self._family_done("env", {"stack": state}, n, sp)
                 return
-            out = np.asarray(self._device_get(state))[:size]
+            out = self._plane_get(state, size)
             store.keys.ct[base:n] = out[:, 0]
             store.keys.mt[base:n] = out[:, 1]
             store.keys.dt[base:n] = out[:, 2]
@@ -2628,8 +2637,8 @@ class TpuMergeEngine:
             if self.resident:
                 self._family_done("reg", {"rv_t": t, "rv_node": nd}, n, sp)
             else:
-                store.keys.rv_t[base:n] = np.asarray(t)[:size]
-                store.keys.rv_node[base:n] = np.asarray(nd)[:size]
+                store.keys.rv_t[base:n] = self._plane_get(t, size)
+                store.keys.rv_node[base:n] = self._plane_get(nd, size)
             reg_val = store.reg_val
             if fold:
                 winb_h = np.asarray(winb)
@@ -2809,10 +2818,10 @@ class TpuMergeEngine:
                 self._family_done("cnt", {"val": val, "uuid": uuid,
                                           "base": cb, "base_t": cbt}, n, sp)
                 return
-            store.cnt.val[base:n] = np.asarray(val)[:size]
-            store.cnt.uuid[base:n] = np.asarray(uuid)[:size]
-            store.cnt.base[base:n] = np.asarray(cb)[:size]
-            store.cnt.base_t[base:n] = np.asarray(cbt)[:size]
+            store.cnt.val[base:n] = self._plane_get(val, size)
+            store.cnt.uuid[base:n] = self._plane_get(uuid, size)
+            store.cnt.base[base:n] = self._plane_get(cb, size)
+            store.cnt.base_t[base:n] = self._plane_get(cbt, size)
             return  # sums re-derived in one pass by merge_many
 
         self._drop_family(store, "cnt")
@@ -3104,10 +3113,10 @@ class TpuMergeEngine:
                 self._family_done("el", {"add_t": at, "add_node": an,
                                          "del_t": dt}, n, sp)
             else:
-                m_at = np.asarray(at)[:size]
-                m_dt = np.asarray(dt)[:size]
+                m_at = self._plane_get(at, size)
+                m_dt = self._plane_get(dt, size)
                 store.el.add_t[base:n] = m_at
-                store.el.add_node[base:n] = np.asarray(an)[:size]
+                store.el.add_node[base:n] = self._plane_get(an, size)
                 store.el.del_t[base:n] = m_dt
                 self._enqueue_elem_garbage(store, np.arange(base, n), m_at,
                                            m_dt, old_dt)

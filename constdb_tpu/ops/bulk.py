@@ -17,6 +17,17 @@ the device hangs off a slow host link; compact moves each row exactly once.
 Measured on v5e: the merge step itself is ~0.5 ms for 8×1M rows — bulk
 merge throughput is bounded by the interconnect, not the VPU.
 
+Plane layout: a per-slot int64 stamp plane lives on the device as a
+`Plane` — two flat arrays `(hi: int32[cap], lo: uint32[cap])` with
+`value = (hi << 32) | lo`.  Signed hi, unsigned lo: lexicographic
+(hi, lo) order IS signed int64 order, so every merge below compares and
+scatters 32-bit lanes.  The TPU has no 64-bit integer unit: an int64
+plane is split into halves at a program's entry and recombined at its
+exit, whole-plane passes whatever the batch holds.  The pair is the ONLY
+form a state operand has here, on every backend; BATCH operands (`bt`,
+`bn`, patch `vals`) stay int64 (or int32) and the program splits them
+itself over the batch's rows, and gathers join their rows back to int64.
+
 Padding protocol: rows are padded to a power-of-two count; padded rows get
 slot id = state_size + offset (distinct, out of bounds), so scatters drop
 them (`mode='drop'`), gathers clamp, and win-flags mask them off.
@@ -30,6 +41,7 @@ All semantics mirror crdt/semantics.py exactly:
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 
@@ -39,7 +51,9 @@ import jax.numpy as jnp  # noqa: E402
 
 from ..crdt.semantics import NEUTRAL_T  # noqa: E402
 
-__all__ = ["NEUTRAL_T", "device_full", "bulk_max", "bulk_max1", "bulk_lww",
+__all__ = ["NEUTRAL_T", "Plane", "plane_split", "plane_rows", "plane_diff",
+           "neutral_plane", "grown_plane", "device_full", "bulk_max",
+           "bulk_max1", "bulk_lww",
            "bulk_counters", "bulk_counters_vu", "bulk_counters_vu_src",
            "bulk_counters_src", "bulk_elems",
            "bulk_lww_src", "bulk_elems_src_nodt", "bulk_elems_nodt",
@@ -67,36 +81,126 @@ __all__ = ["NEUTRAL_T", "device_full", "bulk_max", "bulk_max1", "bulk_lww",
 # dominated by exactly these downloads).
 
 
+class Plane(NamedTuple):
+    """One int64 stamp plane as the device holds it (see the module
+    docstring): a pytree of two same-shape arrays, so it passes through
+    `jit`, `donate_argnums` and shardings as one operand."""
+    hi: jax.Array   # int32: the signed high word
+    lo: jax.Array   # uint32: the unsigned low word
+
+    @property
+    def shape(self):
+        return self.hi.shape
+
+
+def _split(x) -> Plane:
+    """A batch operand's int64 (or int32) values as a pair, in-program."""
+    x = x.astype(jnp.int64)
+    return Plane((x >> 32).astype(jnp.int32),
+                 (x & 0xFFFFFFFF).astype(jnp.uint32))
+
+
+def _join(p: Plane):
+    return (p.hi.astype(jnp.int64) << 32) | p.lo.astype(jnp.int64)
+
+
+def _take(p: Plane, ic) -> Plane:
+    return Plane(p.hi[ic], p.lo[ic])
+
+
+def _where(m, a: Plane, b: Plane) -> Plane:
+    return Plane(jnp.where(m, a.hi, b.hi), jnp.where(m, a.lo, b.lo))
+
+
+def _set(p: Plane, idx, v: Plane, **kw) -> Plane:
+    return Plane(p.hi.at[idx].set(v.hi, mode="drop", **kw),
+                 p.lo.at[idx].set(v.lo, mode="drop", **kw))
+
+
+def _gt(a: Plane, b: Plane):
+    """a > b as int64s: (signed hi, unsigned lo) lexicographic."""
+    return (a.hi > b.hi) | ((a.hi == b.hi) & (a.lo > b.lo))
+
+
+def _eq(a: Plane, b: Plane):
+    return (a.hi == b.hi) & (a.lo == b.lo)
+
+
 @jax.jit
 def gather_rows(state, idx):
     """Compact dirty-row gather: the flush path downloads ONLY the rows a
     resident plane's merges touched since the last flush — gather them
     into one contiguous [D] (or [D, C]) buffer on device, then a single
-    small transfer replaces the whole-plane download.  Non-donating: the
+    small transfer replaces the whole-plane download.  A Plane's rows
+    join to int64 here, over D rows; any other array (the int32 src
+    plane, a tensor payload pool) gathers as it is.  Non-donating: the
     resident plane stays put."""
-    return jnp.take(state, idx, axis=0)
+    got = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), state)
+    return _join(got) if isinstance(state, Plane) else got
 
 
-@partial(jax.jit, static_argnames=("n", "fill", "i32"))
-def device_full(n: int, fill: int, i32: bool = False):
+@jax.jit
+def plane_split(x) -> Plane:
+    """An uploaded int64 array as a Plane — the build edge (engine/tpu.py
+    _put_state): one split pass over a plane built whole, where a host
+    split of 16,777,216 rows costs 25 times the upload itself."""
+    return _split(x)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def plane_rows(plane: Plane, *, n: int):
+    """Rows [0, n) of a Plane as int64: the whole-plane download of a
+    bulk-merged family, joined once a flush."""
+    return _join(Plane(plane.hi[:n], plane.lo[:n]))
+
+
+@jax.jit
+def plane_diff(a: Plane, b: Plane):
+    """a - b as int64 (counter contributions val - base, for the
+    flush-time segment sum)."""
+    return _join(a) - _join(b)
+
+
+def neutral_plane(shape, fill: int) -> Plane:
+    """A Plane of `shape` holding `fill` everywhere (traced: the engine
+    wraps it where it needs an output sharding)."""
+    return Plane(jnp.full(shape, fill >> 32, dtype=jnp.int32),
+                 jnp.full(shape, fill & 0xFFFFFFFF, dtype=jnp.uint32))
+
+
+def grown_plane(old: Plane, delta: int, fill: int) -> Plane:
+    """`old` extended by `delta` rows of `fill` (traced or eager, like
+    neutral_plane)."""
+    new = neutral_plane((delta,) + old.shape[1:], fill)
+    return Plane(jnp.concatenate([old.hi, new.hi]),
+                 jnp.concatenate([old.lo, new.lo]))
+
+
+@partial(jax.jit, static_argnames=("n", "fill", "i32", "cols"))
+def device_full(n: int, fill: int, i32: bool = False, cols: int = 0):
     """Neutral state created ON device (avoids uploading zeros when every
-    touched slot is brand new).  `i32` for the src plane — pool ids fit
-    int32, halving its flush download."""
-    return jnp.full((n,), fill, dtype=jnp.int32 if i32 else jnp.int64)
+    touched slot is brand new): a Plane of [n] (or [n, cols]) rows.
+    `i32` for the src plane — pool ids fit one int32 array, halving its
+    flush download."""
+    if i32:
+        return jnp.full((n,), fill, dtype=jnp.int32)
+    return neutral_plane((n, cols) if cols else (n,), fill)
 
 
 def _mirror_patch_fn(fam: str):
     """Mirror repair for one family: SET the host's values at the rows
     the op path wrote (engine/tpu.py _patch_mirror).  cols = the family's
-    resident int64 planes (donated), idx [Bp] int32 sorted, vals [Bp, C]
-    the host columns gathered at idx.  A plain assignment, not a merge:
-    duplicate rows carry identical values (the pad repeats the last row),
-    and an out-of-range idx drops (the warm-up call).  Named outside
+    resident Planes (donated), idx [Bp] int32 sorted, vals [Bp, C] int64
+    the host columns gathered at idx.  A plain assignment, not a merge.
+    The rows are distinct and the pad targets distinct rows past the plane
+    and drops (the one pad protocol every scatter here has; the warm-up
+    call is all pad).  The chip scatters row by row either way: 1.2 ms of
+    device time for a 1,024-row bucket whatever it holds.  Named outside
     `jit_bulk_*` / `jit_dense_*` — it moves no merge byte, and the
     benchmark's merge roofline reads those modules' seconds."""
     def patch(cols, idx, vals):
-        return tuple(c.at[idx].set(vals[:, i], mode="drop",
-                                   indices_are_sorted=True)
+        return tuple(_set(c, idx, _split(vals[:, i]),
+                          indices_are_sorted=True, unique_indices=True)
                      for i, c in enumerate(cols))
     patch.__name__ = patch.__qualname__ = f"mirror_patch_{fam}"
     return jax.jit(patch, donate_argnums=(0,))
@@ -110,11 +214,19 @@ def _iota_src(base, np_: int):
     return base + jax.lax.iota(jnp.int32, np_)
 
 
+def _max_body(state: Plane, idx, vals) -> Plane:
+    """Per-slot max as gather-compare-set (each slot once per batch, so
+    the set is collision-free); rows past the plane drop."""
+    ic = jnp.minimum(idx, state.shape[0] - 1)
+    cur, b = _take(state, ic), _split(vals)
+    return _set(state, idx, _where(_gt(b, cur), b, cur), unique_indices=True)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def bulk_max(state, idx, cols):
     """state [Sp, C] ← elementwise max with one batch; idx [Np] int32,
     cols [Np, C].  Envelope merge (ct/mt/dt/expire are all max-merges)."""
-    return state.at[idx].max(cols, mode="drop", unique_indices=True)
+    return _max_body(state, idx, cols)
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -123,15 +235,33 @@ def bulk_max1(state, idx, vals):
     element DEL plane on the resident micro path — the host column and
     the device mirror advance together so a later bulk round never
     merges against a stale device del_t)."""
-    return state.at[idx].max(vals, mode="drop", unique_indices=True)
+    return _max_body(state, idx, vals)
 
 
-
-
-def _pair_win(cv, ct, vi, ti, in_range):
+def _pair_win(cv: Plane, ct: Plane, vi: Plane, ti: Plane, in_range):
     """Lexicographic (t, v) winner — shared by registers/elements/counters
-    (the tie-rule core of crdt/semantics.py lww_wins/merge_counter_slot)."""
-    return ((ti > ct) | ((ti == ct) & (vi > cv))) & in_range
+    (the tie-rule core of crdt/semantics.py lww_wins/merge_counter_slot),
+    as a four-level compare on 32-bit lanes."""
+    return (_gt(ti, ct) | (_eq(ti, ct) & _gt(vi, cv))) & in_range
+
+
+def _merge_pair(t: Plane, v: Plane, idx, bt, bv):
+    """One (t, v) LWW pair merged with a batch -> (t, v, win [Np] bool):
+    gather the current rows, pick the winner, set both planes."""
+    size = t.shape[0]
+    ic = jnp.minimum(idx, size - 1)
+    ct, cv = _take(t, ic), _take(v, ic)
+    bt, bv = _split(bt), _split(bv)
+    win = _pair_win(cv, ct, bv, bt, idx < size)
+    return (_set(t, idx, _where(win, bt, ct), unique_indices=True),
+            _set(v, idx, _where(win, bv, cv), unique_indices=True), win)
+
+
+def _track_src(src, idx, win, base):
+    """Winners' pool ids (`base + iota`) scattered into the src plane."""
+    cs = src[jnp.minimum(idx, src.shape[0] - 1)]
+    return src.at[idx].set(jnp.where(win, _iota_src(base, idx.shape[0]), cs),
+                           mode="drop", unique_indices=True)
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -139,13 +269,7 @@ def bulk_lww(t, n, idx, bt, bn):
     """Plain LWW slots (registers): lexicographic (t, node) winner.
     -> (t [Sp], n [Sp], win [Np] bool) — win marks batch rows whose VALUE
     must replace the slot's value."""
-    size = t.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    ct, cn = t[ic], n[ic]
-    win = _pair_win(cn, ct, bn, bt, idx < size)
-    t = t.at[idx].set(jnp.where(win, bt, ct), mode="drop", unique_indices=True)
-    n = n.at[idx].set(jnp.where(win, bn, cn), mode="drop", unique_indices=True)
-    return t, n, win
+    return _merge_pair(t, n, idx, bt, bn)
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -153,14 +277,7 @@ def bulk_counters_vu(val, uuid, idx, bv, bt):
     """Counter value pair only — batches with a neutral base plane (no
     counter deletes anywhere in the batch, the overwhelmingly common case)
     skip uploading and merging the base columns entirely."""
-    size = val.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    cv, ct = val[ic], uuid[ic]
-    win = _pair_win(cv, ct, bv, bt, idx < size)
-    val = val.at[idx].set(jnp.where(win, bv, cv), mode="drop",
-                          unique_indices=True)
-    uuid = uuid.at[idx].set(jnp.where(win, bt, ct), mode="drop",
-                            unique_indices=True)
+    uuid, val, _ = _merge_pair(uuid, val, idx, bt, bv)
     return val, uuid
 
 
@@ -169,36 +286,14 @@ def bulk_counters(val, uuid, base, base_t, idx, bv, bt, bb, bbt):
     """Counter slots: two independent (value @ time) pairs per slot, each
     LWW on time with max-value tie-break.  -> merged (val, uuid, base,
     base_t), all [Sp]."""
-    size = val.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    in_range = idx < size
-
-    cv, ct = val[ic], uuid[ic]
-    win = _pair_win(cv, ct, bv, bt, in_range)
-    val = val.at[idx].set(jnp.where(win, bv, cv), mode="drop",
-                          unique_indices=True)
-    uuid = uuid.at[idx].set(jnp.where(win, bt, ct), mode="drop",
-                            unique_indices=True)
-
-    cb, cbt = base[ic], base_t[ic]
-    win = _pair_win(cb, cbt, bb, bbt, in_range)
-    base = base.at[idx].set(jnp.where(win, bb, cb), mode="drop",
-                            unique_indices=True)
-    base_t = base_t.at[idx].set(jnp.where(win, bbt, cbt), mode="drop",
-                                unique_indices=True)
+    uuid, val, _ = _merge_pair(uuid, val, idx, bt, bv)
+    base_t, base, _ = _merge_pair(base_t, base, idx, bbt, bb)
     return val, uuid, base, base_t
 
 
 def _lww_src_body(t, n, src, idx, bt, bn, base):
-    size = t.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    ct, cn, cs = t[ic], n[ic], src[ic]
-    win = _pair_win(cn, ct, bn, bt, idx < size)
-    t = t.at[idx].set(jnp.where(win, bt, ct), mode="drop", unique_indices=True)
-    n = n.at[idx].set(jnp.where(win, bn, cn), mode="drop", unique_indices=True)
-    src = src.at[idx].set(jnp.where(win, _iota_src(base, idx.shape[0]), cs),
-                          mode="drop", unique_indices=True)
-    return t, n, src
+    t, n, win = _merge_pair(t, n, idx, bt, bn)
+    return t, n, _track_src(src, idx, win, base)
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -225,17 +320,8 @@ def bulk_lww_src_iota(t, n, src, r0, nrows, bt, bn, base, *, np_: int):
 
 
 def _counters_vu_src_body(val, uuid, src, idx, bv, bt, base):
-    size = val.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    cv, ct, cs = val[ic], uuid[ic], src[ic]
-    win = _pair_win(cv, ct, bv, bt, idx < size)
-    val = val.at[idx].set(jnp.where(win, bv, cv), mode="drop",
-                          unique_indices=True)
-    uuid = uuid.at[idx].set(jnp.where(win, bt, ct), mode="drop",
-                            unique_indices=True)
-    src = src.at[idx].set(jnp.where(win, _iota_src(base, idx.shape[0]), cs),
-                          mode="drop", unique_indices=True)
-    return val, uuid, src
+    uuid, val, win = _merge_pair(uuid, val, idx, bt, bv)
+    return val, uuid, _track_src(src, idx, win, base)
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -261,25 +347,8 @@ def bulk_counters_src(val, uuid, base_c, base_t, src, idx, bv, bt, bb, bbt,
     """bulk_counters with deferred win resolution on the val/uuid pair
     (the base pair keeps its own winner on device and downloads when
     written — counter deletes are rare)."""
-    size = val.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    in_range = idx < size
-
-    cv, ct, cs = val[ic], uuid[ic], src[ic]
-    win = _pair_win(cv, ct, bv, bt, in_range)
-    val = val.at[idx].set(jnp.where(win, bv, cv), mode="drop",
-                          unique_indices=True)
-    uuid = uuid.at[idx].set(jnp.where(win, bt, ct), mode="drop",
-                            unique_indices=True)
-    src = src.at[idx].set(jnp.where(win, _iota_src(base, idx.shape[0]), cs),
-                          mode="drop", unique_indices=True)
-
-    cb, cbt = base_c[ic], base_t[ic]
-    win = _pair_win(cb, cbt, bb, bbt, in_range)
-    base_c = base_c.at[idx].set(jnp.where(win, bb, cb), mode="drop",
-                                unique_indices=True)
-    base_t = base_t.at[idx].set(jnp.where(win, bbt, cbt), mode="drop",
-                                unique_indices=True)
+    val, uuid, src = _counters_vu_src_body(val, uuid, src, idx, bv, bt, base)
+    base_t, base_c, _ = _merge_pair(base_t, base_c, idx, bbt, bb)
     return val, uuid, base_c, base_t, src
 
 
@@ -289,16 +358,8 @@ def bulk_elems(at, an, dt, idx, bat, ban, bdt):
     (add_t, add_node) LWW, del side = plain max.
     -> (at, an, dt [Sp], win [Np] bool) — win marks rows whose dict VALUE
     must replace the slot's value."""
-    size = at.shape[0]
-    ic = jnp.minimum(idx, size - 1)
-    ca, cn, cd = at[ic], an[ic], dt[ic]
-    win = _pair_win(cn, ca, ban, bat, idx < size)
-    at = at.at[idx].set(jnp.where(win, bat, ca), mode="drop",
-                        unique_indices=True)
-    an = an.at[idx].set(jnp.where(win, ban, cn), mode="drop",
-                        unique_indices=True)
-    dt = dt.at[idx].max(bdt, mode="drop", unique_indices=True)
-    return at, an, dt, win
+    at, an, win = _merge_pair(at, an, idx, bat, ban)
+    return at, an, _max_body(dt, idx, bdt), win
 
 
 bulk_elems_src_nodt = bulk_lww_src

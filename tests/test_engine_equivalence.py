@@ -221,10 +221,11 @@ def test_mesh_engine_state_is_sharded(kv_mesh):
     assert eng._res, "resident state missing"
     from jax.sharding import PartitionSpec
     for fam, res in eng._res.items():
-        for name, arr in res["cols"].items():
-            spec = arr.sharding.spec
-            assert spec and spec[0] == "kv", \
-                f"{fam}.{name} not kv-sharded: {arr.sharding}"
+        for name, plane in res["cols"].items():
+            for arr in plane:                    # (hi, lo): each half
+                spec = arr.sharding.spec
+                assert spec and spec[0] == "kv", \
+                    f"{fam}.{name} not kv-sharded: {arr.sharding}"
     eng.flush(b)
 
 

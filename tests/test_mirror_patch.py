@@ -58,8 +58,9 @@ def req(*parts) -> list:
 
 
 def mirror_cols(eng, fam: str) -> dict:
-    """The family's resident planes, downloaded whole."""
-    return {c: np.asarray(a) for c, a in eng._res[fam]["cols"].items()}
+    """The family's resident planes, joined and downloaded whole."""
+    return {c: np.asarray(B.plane_rows(a, n=a.shape[0]))
+            for c, a in eng._res[fam]["cols"].items()}
 
 
 def assert_mirror_is_host(eng, ks, fam: str) -> None:
@@ -242,10 +243,11 @@ def rebuilt_from_scratch_is_the_same(node) -> None:
         n = _fam_rows(ks, fam)
         eng._resident_state(ks, fam, n)
         patched = mirror_cols(eng, fam)
-        cols, cap = fresh._resident_state(ks, fam, n)
+        _cols, cap = fresh._resident_state(ks, fam, n)
         assert cap == eng._res[fam]["cap"]
+        built = mirror_cols(fresh, fam)
         for c, _ in _FAMILIES[fam]:
-            np.testing.assert_array_equal(patched[c], np.asarray(cols[c]),
+            np.testing.assert_array_equal(patched[c], built[c],
                                           err_msg=f"{fam}.{c}")
 
 
@@ -421,7 +423,7 @@ def test_a_second_engine_on_the_store_cannot_trust_the_journal():
     other._resident_state(node.ks, "el", n)
     assert other.mirror_rebuilds["el"] == 1 and other.mirror_patches["el"] == 0
     np.testing.assert_array_equal(
-        np.asarray(other._res["el"]["cols"]["add_t"])[:n],
+        np.asarray(B.plane_rows(other._res["el"]["cols"]["add_t"], n=n)),
         node.ks.el.add_t[:n])
 
 
@@ -495,7 +497,8 @@ def test_patch_programs_are_named_apart_from_the_merge_kernels():
     assert set(B.MIRROR_PATCH) == set(JOURNAL_FAMILIES)
     for fam, fn in B.MIRROR_PATCH.items():
         nc = len(_FAMILIES[fam])
-        cols = tuple(jax.ShapeDtypeStruct((64,), jnp.int64)
+        cols = tuple(B.Plane(jax.ShapeDtypeStruct((64,), jnp.int32),
+                             jax.ShapeDtypeStruct((64,), jnp.uint32))
                      for _ in range(nc))
         text = fn.lower(cols, jax.ShapeDtypeStruct((16,), jnp.int32),
                         jax.ShapeDtypeStruct((16, nc), jnp.int64)).as_text()

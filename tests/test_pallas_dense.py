@@ -120,10 +120,23 @@ def _pad1(arr, n, fill):
     return out
 
 
+def _plane(a):
+    """A host int64 column as the device holds it (ops/bulk.py Plane)."""
+    return B.plane_split(jnp.array(np.asarray(a, np.int64)))
+
+
+def _host(x):
+    """A device plane (Plane or the int32 src array) back as numpy."""
+    if isinstance(x, B.Plane):
+        return np.asarray(B.plane_rows(x, n=x.shape[0]))
+    return np.asarray(x)
+
+
 def _scatter_xla(p, s, src, idx, bp, bs, base):
     """bulk_lww_src over a pow2-padded batch, as the engine pads it
     (`_batch_idx`): pad rows carry NEUTRAL values and target rows >= sp.
-    Takes and returns device arrays (the planes are donated)."""
+    Takes and returns the device planes (two Planes and the int32 src
+    array, donated); the batch columns stay int64."""
     sp, n = p.shape[0], len(idx)
     np2 = next_pow2(n)
     idx_x = np.concatenate([idx, (sp + np.arange(np2 - n)).astype(np.int32)])
@@ -170,11 +183,11 @@ def test_scatter_pair_xla_twin_matches_host(seed):
     for _ in range(25):
         sp = int(2 ** rng.integers(0, 7))
         p, s, src, idx, bp, bs, base = _scatter_case(rng, sp)
-        got = _scatter_xla(jnp.array(p), jnp.array(s), jnp.array(src),
+        got = _scatter_xla(_plane(p), _plane(s), jnp.array(src),
                            idx, bp, bs, base)
         want = _host_scatter_ref(p, s, src, idx, bp, bs, base)
         for g, w, name in zip(got, want, ("primary", "secondary", "src")):
-            np.testing.assert_array_equal(np.asarray(g), w, err_msg=name)
+            np.testing.assert_array_equal(_host(g), w, err_msg=name)
 
 
 @pytest.mark.parametrize("seed", [0, 1])
@@ -186,7 +199,7 @@ def test_scatter_chained_rounds(seed):
     want = (rng.integers(-(1 << 60), 1 << 60, sp).astype(np.int64),
             rng.integers(-(1 << 40), 1 << 40, sp).astype(np.int64),
             np.full(sp, -1, np.int32))
-    got = tuple(jnp.array(x) for x in want)
+    got = (_plane(want[0]), _plane(want[1]), jnp.array(want[2]))
     base = 0
     for _ in range(5):
         n = int(rng.integers(1, sp))
@@ -197,7 +210,7 @@ def test_scatter_chained_rounds(seed):
         want = _host_scatter_ref(*want, idx, bp, bs, base)
         base += next_pow2(n)
     for g, w, name in zip(got, want, ("primary", "secondary", "src")):
-        np.testing.assert_array_equal(np.asarray(g), w, err_msg=name)
+        np.testing.assert_array_equal(_host(g), w, err_msg=name)
 
 
 def _reg_batch(keys, u0):
