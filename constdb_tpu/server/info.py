@@ -176,6 +176,14 @@ def _section_stats(node, out):
     # — the oracle for "the native leg actually engaged" (scripts/ci.sh)
     out.append(("native_intake_chunks", st.native_intake_chunks))
     out.append(("native_intake_msgs", st.native_intake_msgs))
+    # the loop-pass gather (server/io.py): msgs / passes is what one pass
+    # of the event loop planned as one chunk (1.0 = nothing gathered),
+    # conns / passes how many connections it spanned, lone_cmds the
+    # passes of one message (the exact per-command path)
+    out.append(("serve_gather_passes", st.serve_gather_passes))
+    out.append(("serve_gather_msgs", st.serve_gather_msgs))
+    out.append(("serve_gather_conns", st.serve_gather_conns))
+    out.append(("serve_lone_cmds", st.serve_lone_cmds))
     rc = node.read_cache
     x = st.extra
     rc_bytes = rc.used_bytes() + sum(

@@ -30,6 +30,166 @@ log = logging.getLogger(__name__)
 _READ_CHUNK = 1 << 16
 
 
+def _has_conn_state_cmd(msgs) -> bool:
+    """Does any of these parsed messages give its connection state the
+    planner would need (HELLO, CLIENT ...)?"""
+    for m in msgs:
+        if isinstance(m, Arr) and m.items and isinstance(m.items[0], Bulk) \
+                and m.items[0].val.lower() in (b"client", b"hello"):
+            return True
+    return False
+
+
+class _PassGather:
+    """The loop-pass gather in front of the node's one ServeCoalescer
+    (server/serve.py; docs/INVARIANTS.md "Client-serving coalescing").
+
+    A connection task parses its socket read and hands the messages over
+    (`hand_over`); the first hand-over of a pass schedules `_run_pass`
+    with `loop.call_soon`, so every task the same `select()` woke has
+    had its turn before it runs.  The pass plans everything handed over
+    as ONE chunk, in hand-over order, cuts the replies at the
+    connections' boundaries and wakes each task with its slice; the
+    task writes and drains its own socket.  A pass of one message is the
+    lone command on the exact per-command path; a pass of one
+    connection's pipeline is the chunk that connection used to run
+    alone.
+
+    A segment is `(ops, payloads)` — the native scanner's form, a pure
+    message riding as opcode 0 — or `(None, msgs)` on a node without the
+    native intake stage; a node uses one form throughout.
+
+    A connection with HELLO / CLIENT TRACKING state (or whose segment
+    carries the command that gives it such state) keeps its own path:
+    `run_alone` runs its chunk at once with its ClientConn, so its
+    replies reach its transport before any later write's invalidation
+    push can (server/tracking.py: invalidate-before-visible)."""
+
+    def __init__(self, app: "ServerApp", coal) -> None:
+        self.app = app
+        self.node = app.node
+        self.coal = coal
+        self.segs: list = []     # (ops | None, payloads, future)
+        self.scheduled = False
+        self.loop = None         # the serving loop, from the first hand-over
+        self.stage = app.node.stages.stage
+        self.barriers: set = set()   # passes waiting on their group commit
+
+    @staticmethod
+    def keeps_own_path(client, ops, payloads) -> bool:
+        if client.tracking or client.resp3:
+            return True
+        if ops is None:
+            return _has_conn_state_cmd(payloads)
+        return 0 in ops and _has_conn_state_cmd(
+            [pl for op, pl in zip(ops, payloads) if not op])
+
+    def run_alone(self, client, ops, payloads, out: bytearray) -> None:
+        coal = self.coal
+        coal.client = client
+        try:
+            if ops is None:
+                coal.run_chunk(payloads, out)
+            else:
+                coal.run_native_chunk(ops, payloads, out)
+        finally:
+            coal.client = None
+
+    def hand_over(self, ops, payloads) -> "asyncio.Future":
+        """One connection's messages of this pass -> the future of its
+        reply bytes."""
+        with self.stage("gather"):
+            loop = self.loop
+            if loop is None:
+                loop = self.loop = asyncio.get_running_loop()
+            fut = loop.create_future()
+            self.segs.append((ops, payloads, fut))
+            if not self.scheduled:
+                self.scheduled = True
+                loop.call_soon(self._run_pass)
+        return fut
+
+    def _run_pass(self) -> None:
+        self.scheduled = False
+        segs, self.segs = self.segs, []
+        with self.stage("gather"):
+            st = self.node.stats
+            if len(segs) == 1:
+                ops, payloads, _ = segs[0]
+                solo = None
+            else:
+                payloads = []
+                for seg in segs:
+                    payloads += seg[1]
+                ops = None if segs[0][0] is None else \
+                    b"".join([seg[0] for seg in segs])
+                # which messages arrived alone on their connections
+                solo = b"".join([b"\x01" if len(seg[1]) == 1
+                                 else bytes(len(seg[1])) for seg in segs])
+                if not any(solo):
+                    solo = None
+            n = len(payloads)
+            st.serve_gather_passes += 1
+            st.serve_gather_msgs += n
+            st.serve_gather_conns += len(segs)
+            if n == 1:
+                st.serve_lone_cmds += 1
+            out = bytearray()
+            spans: list = []
+            try:
+                if ops is None:
+                    self.coal.run_chunk(payloads, out, None, spans, solo)
+                else:
+                    self.coal.run_native_chunk(ops, payloads, out, spans,
+                                               solo)
+            except Exception as e:  # noqa: BLE001 - every waiter must wake
+                log.exception("a gathered chunk raised")
+                self._fail(segs, e)
+                return
+            # cut the replies at the connections' boundaries
+            if len(segs) == 1:
+                cuts = [out]
+            else:
+                cuts, a, i = [], 0, 0
+                for seg in segs:
+                    i += len(seg[1])
+                    b = spans[i - 1]
+                    cuts.append(out[a:b])
+                    a = b
+            oplog = self.node.oplog
+            if oplog is not None and oplog.ack_barrier_needed:
+                # fsync=always: ONE group commit covers the pass, and
+                # the replies leave after it
+                t = asyncio.ensure_future(self._wake_after_barrier(segs,
+                                                                   cuts))
+                self.barriers.add(t)
+                t.add_done_callback(self.barriers.discard)
+            else:
+                self._wake(segs, cuts)
+
+    @staticmethod
+    def _wake(segs: list, cuts: list) -> None:
+        for seg, cut in zip(segs, cuts):
+            fut = seg[2]
+            if not fut.done():   # its task was cancelled: only its own
+                fut.set_result(cut)  # replies are lost
+
+    @staticmethod
+    def _fail(segs: list, exc: Exception) -> None:
+        for seg in segs:
+            if not seg[2].done():
+                seg[2].set_exception(exc)
+
+    async def _wake_after_barrier(self, segs: list, cuts: list) -> None:
+        try:
+            await self.app._aof_ack_barrier()
+        except BaseException:
+            # no commit, no acknowledgement: the waiting connections end
+            self._fail(segs, ConnectionError("the group commit failed"))
+            raise
+        self._wake(segs, cuts)
+
+
 class ServerApp:
     """One node's process: listener, replica links, cron, config knobs."""
 
@@ -288,6 +448,9 @@ class ServerApp:
             # slots_lost — the migration half of the tracking laws)
             node.cluster.on_slots_lost = node.tracking.slots_lost
         self.serve_plane = None
+        # the loop-pass gather and the node's one coalescer (_PassGather);
+        # built by start() where the in-loop coalescer serves
+        self._gather: Optional[_PassGather] = None
         # awaited by start() AFTER the serve plane is up but BEFORE the
         # listener opens — the sharded boot restore (start_node) runs
         # here so a reconnecting peer can never observe the un-fenced
@@ -347,6 +510,18 @@ class ServerApp:
             from .serve_shards import ServeShardPlane
             self.serve_plane = ServeShardPlane(self, self.serve_shards)
             await self.serve_plane.start()
+        elif self.serve_batch > 1:
+            # gathered chunks are PLANNED instead of executed per message
+            # (server/serve.py): what every connection delivered in one
+            # pass of the loop is one chunk of the node's ONE coalescer.
+            # serve_batch <= 1 (CONSTDB_SERVE_BATCH=1) keeps the exact
+            # per-command loop; with a serve PLANE active a connection's
+            # chunk is ROUTED instead (server/serve_shards.py) — the
+            # workers own the coalescers.  Imported here, before the
+            # listener opens: no connection's first chunk pays for it.
+            from .serve import ServeCoalescer
+            self._gather = _PassGather(self, ServeCoalescer(
+                self.node, max_run=self.serve_batch))
         # bind (resolving an ephemeral port — advertised_addr is live
         # from here) but do NOT accept yet: the boot restore below must
         # land its watermark fences first
@@ -522,23 +697,18 @@ class ServerApp:
         # await, or another connection's work would be billed to it
         stage = self.node.stages.stage
         plane = self.serve_plane
-        coal = None
-        if plane is None and self.serve_batch > 1:
-            # pipelined chunks are PLANNED instead of executed
-            # per message (server/serve.py); serve_batch <= 1
-            # (CONSTDB_SERVE_BATCH=1) keeps the exact per-command loop.
-            # With a serve PLANE active the chunk is ROUTED instead
-            # (server/serve_shards.py) — the workers own the coalescers.
-            from .serve import ServeCoalescer
-            coal = ServeCoalescer(self.node, max_run=self.serve_batch,
-                                  client=client)
+        gather = self._gather
+        native = gather is not None and self.native_intake
+        # what this read's scans have parsed and no chunk has run yet (the
+        # salvage path below runs it before it answers a malformed frame)
+        held = None
         try:
             while True:
                 data = await reader.read(_READ_CHUNK)
                 if not data:
                     break
                 self.node.stats.net_in_bytes += len(data)
-                if coal is None and plane is None:
+                if gather is None and plane is None:
                     # the exact per-command loop (CONSTDB_SERVE_BATCH=1):
                     # its per-message parse is inside a per-operation
                     # loop and stays untimed
@@ -565,13 +735,13 @@ class ServerApp:
                     # frame, SYNC upgrade, malformed bytes, nested array
                     # — stays buffered for the pure drain(), which keeps
                     # the reference behavior for those frames byte for
-                    # byte.  Each native chunk runs before the next
-                    # scan, and drain() comes last.  One `intake` entry
-                    # a read in the common case: once the scanner has
-                    # taken every buffered byte there is no parse left
-                    # to time, and drain() only hands over what is
-                    # queued.
-                    native = coal is not None and self.native_intake
+                    # byte.  Every scan of this read joins ONE segment
+                    # (a pure message rides as opcode 0), and drain()
+                    # comes last.  One `intake` entry a read in the
+                    # common case: once the scanner has taken every
+                    # buffered byte there is no parse left to time, and
+                    # drain() only hands over what is queued.
+                    held = None
                     with stage("intake"):
                         parser.feed(data)
                         nat = parser.native_drain() if native else None
@@ -580,7 +750,8 @@ class ServerApp:
                         stats = self.node.stats
                         stats.native_intake_chunks += 1
                         stats.native_intake_msgs += len(nat[0])
-                        coal.run_native_chunk(nat[0], nat[1], out)
+                        held = nat if held is None else \
+                            (held[0] + nat[0], held[1] + nat[1])
                         if not parser.buffered:
                             msgs = parser.drain()
                             break
@@ -588,31 +759,33 @@ class ServerApp:
                             nat = parser.native_drain()
                             if nat is None:
                                 msgs = parser.drain()
-                    for i, msg in enumerate(msgs):
-                        if self._is_sync(msg):
-                            # messages after the SYNC belong to the
-                            # replica link's stream — hand them back
-                            # before the link adopts the parser
-                            parser.pushback(msgs[i + 1:])
-                            if i:
-                                await self._run_chunk(plane, coal,
-                                                      msgs[:i], out, client)
-                            await self._aof_ack_barrier()
-                            out = self._flush_out(writer, out)
-                            self._upgrade_to_replica(msg, reader, writer,
-                                                     parser)
-                            upgraded = True
-                            break
-                    else:
-                        if msgs:
-                            await self._run_chunk(plane, coal, msgs, out,
-                                                  client)
+                    sync_at = next((i for i, m in enumerate(msgs)
+                                    if self._is_sync(m)), -1)
+                    if sync_at >= 0:
+                        # messages after the SYNC belong to the replica
+                        # link's stream — hand them back before the link
+                        # adopts the parser
+                        parser.pushback(msgs[sync_at + 1:])
+                        syn, msgs = msgs[sync_at], msgs[:sync_at]
+                    if msgs or held is not None:
+                        seg, held = self._segment(held, msgs, native), None
+                        out = await self._run_chunk(plane, gather, seg, out,
+                                                    client)
+                    if sync_at >= 0:
+                        # the replies before the SYNC leave first, then
+                        # the handshake reply takes over the stream
+                        await self._aof_ack_barrier()
+                        out = self._flush_out(writer, out)
+                        self._upgrade_to_replica(syn, reader, writer,
+                                                 parser)
+                        upgraded = True
                 if upgraded:
                     return  # connection now owned by the replica link
                 if out:
                     # fsync=always ack gate: replies reach the socket
                     # only after the group commit covering this chunk's
-                    # appends lands — one fsync per pipelined chunk,
+                    # appends lands — one fsync per gathered pass (the
+                    # gather awaits it before it wakes its connections),
                     # riding the coalescer's end-of-chunk flush barrier
                     await self._aof_ack_barrier()
                     out = self._flush_out(writer, out)
@@ -639,15 +812,17 @@ class ServerApp:
                     head, syn = salvaged[:sync_at], salvaged[sync_at]
                     parser.pushback(salvaged[sync_at + 1:])
                     salvaged = head
-                if salvaged:
-                    if coal is not None or plane is not None:
-                        await self._run_chunk(plane, coal, salvaged, out,
-                                              client)
-                    else:
-                        for msg in salvaged:
-                            reply = self.node.execute(msg, client=client)
-                            if not isinstance(reply, NoReply):
-                                encode_into(out, reply)
+                if gather is not None or plane is not None:
+                    if salvaged or held is not None:
+                        seg, held = self._segment(held, salvaged,
+                                                  native), None
+                        out = await self._run_chunk(plane, gather, seg, out,
+                                                    client)
+                else:
+                    for msg in salvaged:
+                        reply = self.node.execute(msg, client=client)
+                        if not isinstance(reply, NoReply):
+                            encode_into(out, reply)
                 await self._aof_ack_barrier()
                 if sync_at >= 0:
                     out = self._flush_out(writer, out)
@@ -682,18 +857,40 @@ class ServerApp:
         if oplog is not None and oplog.ack_barrier_needed:
             await oplog.ack_barrier()
 
-    async def _run_chunk(self, plane, coal, msgs: list,
-                         out: bytearray, client=None) -> None:
-        """One drained pipelined chunk, through whichever machinery this
-        node runs: the shard-routing plane (serve_shards > 1) or the
-        in-loop coalescer (serve_batch > 1).  `client` is the
-        connection's ClientConn (HELLO / CLIENT TRACKING state) — the
-        coalescer already carries it; the shared plane takes it per
-        chunk."""
+    @staticmethod
+    def _segment(held, msgs: list, native: bool) -> tuple:
+        """One connection's messages of one read as a segment: the
+        native scans joined, the pure remainder riding as opcode 0 — or
+        `(None, msgs)` where the native intake stage is off."""
+        if not native:
+            return None, msgs
+        if held is None:
+            return bytes(len(msgs)), msgs
+        if msgs:
+            return held[0] + bytes(len(msgs)), held[1] + msgs
+        return held
+
+    async def _run_chunk(self, plane, gather, seg: tuple, out: bytearray,
+                         client) -> bytearray:
+        """One connection's segment, through whichever machinery this
+        node runs: the shard-routing plane (serve_shards > 1), or the
+        loop-pass gather in front of the in-loop coalescer (serve_batch
+        > 1) — where the segment joins whatever else this pass of the
+        loop delivers, unless its connection keeps its own path
+        (_PassGather).  `client` is the connection's ClientConn (HELLO /
+        CLIENT TRACKING state).  -> `out` with the replies appended."""
+        ops, payloads = seg
         if plane is not None:
-            await plane.run_chunk(msgs, out, client=client)
+            await plane.run_chunk(payloads, out, client=client)
+        elif gather.keeps_own_path(client, ops, payloads):
+            gather.run_alone(client, ops, payloads, out)
         else:
-            coal.run_chunk(msgs, out)
+            replies = await gather.hand_over(ops, payloads)
+            if out:
+                out += replies
+            else:
+                out = replies
+        return out
 
     def _outbuf_overflow(self, writer) -> bool:
         """Slow-client protection (CONSTDB_CLIENT_OUTBUF_MAX): a client

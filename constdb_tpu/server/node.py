@@ -127,6 +127,13 @@ class NodeStats:
     # missing extension pins both to zero — the pure path served)
     native_intake_chunks: int = 0
     native_intake_msgs: int = 0
+    # the loop-pass gather (server/io.py _PassGather): passes run, the
+    # messages and the connections (Σ over passes) they gathered, and the
+    # passes of one message, which took the exact per-command path
+    serve_gather_passes: int = 0
+    serve_gather_msgs: int = 0
+    serve_gather_conns: int = 0
+    serve_lone_cmds: int = 0
     serve_lat: deque = field(default_factory=lambda: deque(maxlen=2048))
     # overload governance (server/overload.py + server/io.py +
     # replica/link.py): client data writes shed at the maxmemory soft

@@ -59,15 +59,32 @@ Ordering discipline (docs/INVARIANTS.md "Client-serving coalescing"):
     barrier invalidates only the cached state it could actually have
     changed — the key in its first argument (_invalidate_after) — so
     the chunk's bulk-seeded probe caches (_preprobe) survive.
+  * a CHUNK is what one pass of the event loop gathered (server/io.py
+    _PassGather): the commands every connection delivered in that pass,
+    in the order the loop read them, each connection's own commands in
+    their own order — one connection's pipeline, or two hundred
+    connections' lone commands.  One coalescer per node plans it; the
+    gather cuts the replies at the connections' boundaries (`spans`).
+    It is a serial execution of the commands in the gathered order,
+    which is a legal history for connections that each have at most
+    one chunk outstanding.
   * a chunk that yields a single message takes the per-command path
-    untouched — a lone command pays ZERO added latency and no
-    micro-merge overhead.  `CONSTDB_SERVE_BATCH=1` pins every
-    connection to the exact per-command path (server/io.py never
-    constructs a coalescer).
-  * the run NEVER spans chunks: replies must reach the socket at
-    end-of-chunk, so the chunk epilogue always flushes.  Between chunks
-    the loop runs (peer streams, other clients), so all per-chunk state
-    caches reset at chunk entry.
+    untouched — a lone command in a pass of its own pays no micro-merge
+    overhead.  `CONSTDB_SERVE_BATCH=1` pins every connection to the
+    exact per-command path (server/io.py never constructs a coalescer).
+  * a plannable write opens a run only when it has company: an open
+    run or a plannable successor.  A command that arrived ALONE on its
+    connection (`solo`: a depth-1 client, which can never bring a
+    successor of its own) always rides: its pass is its pipeline.
+    Reads of other keys between them commute with the run, so the
+    depth-1 writes of a pass's connections land as one micro-batch —
+    and none of them takes execute(), whose version bump would stale
+    the device mirror and send the next rounds to the host twin (the
+    placement would flip with every pass that held one write).
+  * the run NEVER outlives the chunk: replies must reach the sockets at
+    the end of the pass, so the chunk epilogue always flushes.  Between
+    chunks the loop runs (peer streams, the next pass), so all
+    per-chunk state caches reset at chunk entry.
 
 Exactness notes (why planned == per-command, byte for byte):
   * every plannable command's local apply equals applying its own
@@ -202,8 +219,12 @@ def _materialize_msg(m):
 
 
 class ServeCoalescer:
-    """Per-connection planner driving pipelined client chunks into the
-    node (see module docstring for the discipline)."""
+    """The node's planner driving gathered client chunks into the node
+    (see module docstring for the discipline).  server/io.py builds ONE
+    per node; `client` is set by whoever runs a chunk — None for a
+    gathered chunk, the connection's ClientConn for a chunk that keeps
+    its own path because its connection has HELLO / CLIENT TRACKING
+    state."""
 
     CONFLICT = CONFLICT
 
@@ -239,7 +260,8 @@ class ServeCoalescer:
         self.els: dict = {}     # key -> {member -> visible?}
         self.tns: dict = {}     # key -> packed cfg of run-created tensors
         # the pending run
-        self._pending_keys: set = set()  # keys with un-landed rows
+        self._pending_keys: dict = {}  # key with un-landed rows -> the
+        #                                 rewrite name that put them there
         self._buf: dict = {}    # rewrite name -> encoder recs
         self._log: list = []    # (uuid, name, args) for push_many
         self._pending = 0
@@ -257,8 +279,8 @@ class ServeCoalescer:
     # -------------------------------------------------------------- chunk
 
     def run_chunk(self, msgs: list, out: bytearray, uuids: list = None,
-                  spans: list = None) -> None:
-        """Plan and execute one drained chunk of client messages,
+                  spans: list = None, solo: bytes = None) -> None:
+        """Plan and execute one gathered chunk of client messages,
         appending every reply to `out` in request order.  The pending
         run always lands before this returns.
 
@@ -268,12 +290,16 @@ class ServeCoalescer:
         demoted per-command executions consume the message's assigned
         uuid instead of ticking.  `spans`: when given, receives
         `len(out)` after each message — the parent slices per-command
-        replies out for in-order reassembly across shards."""
+        replies out for in-order reassembly across shards, the gather
+        (server/io.py) cuts them at the connections' boundaries.
+        `solo`: one byte per message, non-zero where the message arrived
+        alone on its connection (the module docstring's company rule),
+        or None where none did."""
         with self._stage("plan"):
-            self._plan_chunk(msgs, out, uuids, spans)
+            self._plan_chunk(msgs, out, uuids, spans, solo)
 
     def _plan_chunk(self, msgs: list, out: bytearray, uuids: list,
-                    spans: list) -> None:
+                    spans: list, solo: bytes = None) -> None:
         """run_chunk's body, under its `plan` stage (whose self time
         excludes the read batches, per-command executions and flushes
         nested in it)."""
@@ -376,7 +402,8 @@ class ServeCoalescer:
             # one-row micro-merge
             if fn is not None:
                 if self._pending or \
-                        (i + 1 < n and callable(plan[i + 1])):
+                        (i + 1 < n and callable(plan[i + 1])) or \
+                        (solo is not None and solo[i]):
                     reply = fn(self, msg.items)
                     if reply is not None:
                         encode_into(sink, reply)
@@ -402,23 +429,26 @@ class ServeCoalescer:
             self.flush()
 
     def run_native_chunk(self, ops: bytes, payloads: list,
-                         out: bytearray) -> None:
+                         out: bytearray, spans: list = None,
+                         solo: bytes = None) -> None:
         """Plan and execute one natively-scanned chunk (`ops`/`payloads`
         from native/intake.cpp intake_scan, via resp/codec.py
-        native_drain).  Control flow mirrors run_chunk exactly; native
-        opcodes skip message construction, classification, and planner
-        dispatch, but share every stateful primitive (tick /
-        resolve_key / count_elem_flips / add / flush / _exec), so
-        replies, uuid streams, planes, and repl_log entries stay
-        byte-identical to the pure path (tests/test_resp_fuzz.py pins
-        the differential).  Never used on the sharded plane — io.py
-        builds a coalescer only when no plane is active — so there are
-        no pre-minted uuids or reply spans here."""
+        native_drain; the gather joins the connections' scans and hands
+        a pure message over as opcode 0).  Control flow mirrors
+        run_chunk exactly; native opcodes skip message construction,
+        classification, and planner dispatch, but share every stateful
+        primitive (tick / resolve_key / count_elem_flips / add / flush /
+        _exec), so replies, uuid streams, planes, and repl_log entries
+        stay byte-identical to the pure path (tests/test_resp_fuzz.py
+        pins the differential).  `spans` and `solo` as run_chunk's.
+        Never used on the sharded plane — io.py builds a coalescer only
+        when no plane is active — so there are no pre-minted uuids."""
         with self._stage("plan"):
-            self._plan_native_chunk(ops, payloads, out)
+            self._plan_native_chunk(ops, payloads, out, spans, solo)
 
     def _plan_native_chunk(self, ops: bytes, payloads: list,
-                           out: bytearray) -> None:
+                           out: bytearray, spans: list = None,
+                           solo: bytes = None) -> None:
         """run_native_chunk's body, under its `plan` stage."""
         self._reset_caches()
         n = len(ops)
@@ -426,6 +456,8 @@ class ServeCoalescer:
             # lone command: the exact per-command path, zero overhead
             self._exec(_nat_msg(ops[0], payloads[0]), out,
                        count_barrier=False, invalidate=False)
+            if spans is not None:
+                spans.append(len(out))
             return
         # plan[i]: a native opcode int, or _planner_of's result for an
         # OP_OTHER message (callable / read-spec tuple / None)
@@ -526,7 +558,7 @@ class ServeCoalescer:
                 else:
                     key = self._confined_key(pl)
                 if key is None or key in run_keys:
-                    self._run_read_batch(read_run, out, None, deferred)
+                    self._run_read_batch(read_run, out, spans, deferred)
                     read_run = []
                     run_keys = set()
                     deferred = []
@@ -538,7 +570,8 @@ class ServeCoalescer:
             if fn is not None:
                 nxt = plan[i + 1] if i + 1 < n else None
                 if self._pending or callable(nxt) or \
-                        (type(nxt) is int and nxt < _FIRST_READ_OP):
+                        (type(nxt) is int and nxt < _FIRST_READ_OP) or \
+                        (solo is not None and solo[i]):
                     if type(fn) is int:
                         handled = self._nplan_native(fn, pl, sink)
                     else:
@@ -555,10 +588,12 @@ class ServeCoalescer:
                 self._exec(msg, sink, count_barrier=not isolated)
             if sink is not out:
                 deferred.append((i, bytes(sink)))
+            elif spans is not None:
+                spans.append(len(out))
             if handled and self._pending >= max_run:
                 self.flush()
         if read_run:
-            self._run_read_batch(read_run, out, None, deferred)
+            self._run_read_batch(read_run, out, spans, deferred)
         self._cur_uuid = None
         if self._pending:
             self.flush()
@@ -1337,12 +1372,19 @@ class ServeCoalescer:
         (`rec[0]` = key, `rec[1]` = uuid — see commands.SERVE_ENCODERS
         for the per-command tails) for the flush-time group encoders,
         queue its repl_log entry, account it."""
+        key = rec[0]
+        if self._pending_keys.setdefault(key, name) != name:
+            # an add and a remove of one key in one run (sadd + srem,
+            # hset + hdel): the encoders group records by name, which
+            # would land their new rows out of command order — and a
+            # scan's reply lists rows in row order.  Land the run first.
+            self.flush()
+            self._pending_keys[key] = name
         buf = self._buf
         recs = buf.get(name)
         if recs is None:
             recs = buf[name] = []
         recs.append(rec)
-        self._pending_keys.add(rec[0])
         self._log.append((rec[1], name, args))
         self._pending += 1
         self.node.stats.cmds_processed += 1

@@ -46,18 +46,20 @@ import threading
 from functools import partial
 from time import perf_counter_ns
 
-# served path, in order: socket read -> ... -> reply write; then the
+# served path, in order: socket read -> the loop-pass gather (server/io.py:
+# hand-over, concatenation, cut and wake-ups) -> ... -> reply write; then the
 # replication link (replica/link.py, replica/coalesce.py): a peer's stream
 # in (`repl_ingest` per socket read, `repl_flush` per landed batch) and the
 # node's own log out (`repl_push` per drained run and per wake-up's tail)
-STAGES = ("intake", "plan", "read_batch", "read_miss", "exec",
+STAGES = ("intake", "gather", "plan", "read_batch", "read_miss", "exec",
           "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
           "mirror_rebuild", "mirror_patch", "state_alloc", "d2h_flush",
           "reply_write", "repl_ingest", "repl_flush", "repl_push")
-# entered for every pipelined chunk, every socket read of a peer's stream
-# or every wake-up of a push loop: counters only.  The others come at most
+# entered for every pipelined chunk, every hand-over and pass of the gather,
+# every socket read of a peer's stream or every wake-up of a push loop:
+# counters only.  The others come at most
 # once per coalescer flush, or rarer, and also open a trace span
-PER_CHUNK = frozenset(("intake", "plan", "read_batch", "read_miss", "exec",
+PER_CHUNK = frozenset(("intake", "gather", "plan", "read_batch", "read_miss", "exec",
                        "reply_write", "repl_ingest", "repl_push"))
 ANNOTATED = frozenset(STAGES) - PER_CHUNK
 MAX_ANNOTATION = 40     # benchmark/trace_reduce.py cuts a host name at 48

@@ -331,3 +331,36 @@ def test_merge_stats_carry_transfer_deltas():
     assert st.dev_upload_bytes > 0
     eng.flush(n1.ks)
     assert eng.flush_rows_downloaded > 0
+
+
+def test_depth1_sets_of_fifty_connections_reach_the_device_planes(tmp_path):
+    """memtier's shape at a small size: 50 closed-loop connections, one
+    command in flight each, SET:GET 1:10 on shared keys.  The loop-pass
+    gather (server/io.py) plans what a pass delivers as one chunk, so the
+    SETs form runs, the runs land as resident `reg` rounds
+    (`merge_rows_dev_reg` > 0) — and the store equals a CPU-engine
+    node's fed the same commands in the gathered order."""
+    import random
+
+    from test_serve_coalesce import cmd
+    from test_serve_gather import drive_gathered, replay_per_command
+
+    rng = random.Random(37)
+    work = [[[cmd(b"set", b"memtier-%d" % rng.randrange(400),
+                  b"v%06d" % rng.getrandbits(19))
+              if rng.randrange(11) == 0 else
+              cmd(b"get", b"memtier-%d" % rng.randrange(400))]
+             for _ in range(40)] for _ in range(50)]
+    got = asyncio.run(drive_gathered(tmp_path, "tpu", work,
+                                     [[] for _ in work]))
+    want = replay_per_command("cpu", got)
+    assert got["raw"] == want["raw"]
+    assert got["canonical"] == want["canonical"]
+    assert got["repl"] == want["repl"]
+    info = got["info"]
+    assert info["merge_rows_dev_reg"] > 0
+    assert info["dev_rounds_resident"] > 0
+    st = got["stats"]
+    assert st.serve_gather_msgs == 50 * 40
+    assert st.serve_gather_msgs / st.serve_gather_passes > 2
+    assert st.serve_msgs_coalesced > 0

@@ -18,8 +18,9 @@ Pinned here:
     write is patched, a GC rebuilds), and the documented inclusive totals
     (`merge_<fam>_seconds`, `merge_seconds_total`,
     `flush_seconds_total`) still read above 0;
-  * every counter a per-layer metric names — the ten in BENCHMARK.json
-    and the fifteen specs of docs/stage_layers/ — is an INFO key of a
+  * every counter a per-layer metric names — the nineteen in
+    BENCHMARK.json (PR 37's four of the gather and the `reg` rows among
+    them) and the fifteen specs of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
 """
@@ -51,6 +52,9 @@ FAMS = ("env", "reg", "cnt", "el")
 # the replication link's counters beside its three stages (server/info.py)
 LINK_COUNTERS = ["repl_apply_lag_ms_sum", "repl_apply_lag_n", "repl_ops_out",
                  "merge_rows_repl", "merge_rows_serve"]
+# the loop-pass gather's counters beside its stage (server/io.py)
+GATHER_COUNTERS = ["serve_gather_passes", "serve_gather_msgs",
+                   "serve_gather_conns", "serve_lone_cmds"]
 
 
 @pytest.fixture
@@ -243,13 +247,14 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want += [f"mirror_rebuilds_cause_{c}" for c in TOUCH_CAUSES]
     want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
-    want += LINK_COUNTERS
-    assert len(want) == 2 * 18 + 8 + 6 + 7 + 5
+    want += LINK_COUNTERS + GATHER_COUNTERS
+    assert len(want) == 2 * 19 + 8 + 6 + 7 + 5 + 4
+    assert STAGES.index("gather") == 1 and "gather" not in ANNOTATED
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
     # a CPU-engine node has the clock, not the device engine's counters
     cpu = info_of(Node(node_id=2))
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
-    assert all(cpu[k] == 0 for k in LINK_COUNTERS)
+    assert all(cpu[k] == 0 for k in LINK_COUNTERS + GATHER_COUNTERS)
     assert "merge_rows_dev_el" not in cpu
 
 
@@ -283,14 +288,20 @@ def test_pipelined_chunk_through_a_socket_moves_the_loop_stages(tmp_path):
             await app.close()
 
     info, wall_us, st = asyncio.run(main())
-    for s in ("intake", "plan", "read_batch", "serve_flush", "reply_write"):
+    for s in ("intake", "gather", "plan", "read_batch", "serve_flush",
+              "reply_write"):
         assert info[f"span_{s}_us"] > 0 and info[f"span_{s}_n"] > 0, s
+    # one connection, three reads: three passes of 28 messages, each a
+    # hand-over and a pass of the gather
+    assert info["serve_gather_passes"] == info["serve_gather_conns"] == 3
+    assert info["serve_gather_msgs"] == 3 * 28
+    assert info["span_gather_n"] == 6 and info["serve_lone_cmds"] == 0
     assert info["span_read_miss_n"] > 0
     assert info["span_serve_flush_n"] == st.serve_flushes
     total = sum(info[f"span_{s}_us"] for s in STAGES)
     assert total < wall_us
     # fewer than one stage entry per operation
-    assert sum(info[f"span_{s}_n"] for s in STAGES) < 3 * 28
+    assert sum(info[f"span_{s}_n"] for s in STAGES) < 3 * 28 + 6
 
 
 def test_a_peers_stream_moves_the_links_stages_and_counters(tmp_path):
@@ -474,7 +485,12 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    assert len(specs) == 10 + 5 + 15     # the five of the replication link
+    # the five of the replication link, the four of the gather (PR 37)
+    assert len(specs) == 10 + 5 + 4 + 15
+    mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
+    assert sorted(s["name"] for s in mine) == [
+        "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
+        "lone_cmd_share.serve", "reg_rows_dev_share.serve"]
     link = [s for s in specs if s["layer"] == "replication link"]
     assert len(link) == 5 and all(
         s["workloads"] == ["aa-3node-ycsb-a"] for s in link)
@@ -496,7 +512,7 @@ def benchmark_module(name: str):
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     """docs/stage_layers/overlay.py on a scratch copy: 15 files beside the
-    15, 15 entries at the END of per_layer, nothing else changed — and
+    19, 15 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -516,11 +532,11 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     with open(tmp_path / "BENCHMARK.json") as f:
         after = json.load(f)
     assert validate.check_manifest(after) == []
-    assert after["per_layer"][:15] == before["per_layer"]
-    assert [m["name"] for m in after["per_layer"][15:]] == added
+    assert after["per_layer"][:19] == before["per_layer"]
+    assert [m["name"] for m in after["per_layer"][19:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 30
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 34
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
