@@ -87,6 +87,15 @@ def _pad(arr: np.ndarray, size: int, fill) -> np.ndarray:
     return out
 
 
+def _copy_async(arr) -> None:
+    """Start a device array's copy to the host, where the backend has
+    one to start (the later np.asarray then waits for it alone)."""
+    try:
+        arr.copy_to_host_async()
+    except AttributeError:
+        pass
+
+
 def _pad_idx(rows: np.ndarray, sp: int, size: int) -> np.ndarray:
     """int32 scatter idx of `size` rows: `rows`, then distinct slots past
     the plane (`sp`), which every scatter drops."""
@@ -339,6 +348,13 @@ class TpuMergeEngine:
         self._warm_streak: dict[str, tuple[int, int]] = {}
         self.dev_rounds_resident = 0
         self.host_micro_rounds = 0
+        # the micro round's link protocol (_scatter_pair): scatters that
+        # returned their win vector, micro scatters that fell back to the
+        # `src` plane (a whole-plane round's src still unflushed), and
+        # the rows the flush applied from win vectors
+        self.micro_win_scatters = 0
+        self.micro_src_scatters = 0
+        self.micro_win_rows = 0
         self.flush_rows_downloaded = 0
         # rows a whole-plane flush WOULD have downloaded at the same
         # points — the denominator that proves partial, not full,
@@ -820,13 +836,17 @@ class TpuMergeEngine:
         enqueues element tombstones whose del_t advanced on device.
 
         Dirty-row accounting: a family whose merges since the last flush
-        were all resident MICRO rounds carries an explicit dirty-row set —
-        only those rows are gathered on device (ops/bulk.py gather_rows)
-        and downloaded; whole-plane downloads happen only for bulk
-        catch-up merges (dirty=None) that really did touch the plane
-        wholesale, and an untouched family costs nothing.  Counter sums
-        update INCREMENTALLY over the dirty rows (old-vs-new contribution
-        delta) instead of the O(table) recompute.
+        were all resident MICRO rounds carries an explicit dirty-row set
+        and each round's win vector, already on its way to the host: the
+        flush launches NOTHING for it — it reads the vectors and applies
+        the rounds in order (_apply_wins).  Only a micro round that fell
+        back to `src` tracking (_scatter_pair) has its dirty rows
+        gathered on device (ops/bulk.py gather_rows) and downloaded;
+        whole-plane downloads happen only for bulk catch-up merges
+        (dirty=None) that really did touch the plane wholesale, and an
+        untouched family costs nothing.  Counter sums update
+        INCREMENTALLY over the dirty rows (old-vs-new contribution delta)
+        instead of the O(table) recompute.
 
         Download protocol: EVERY family's downloads dispatch up front
         (device-side [:n] slice / dirty-row gather so padding and
@@ -873,38 +893,34 @@ class TpuMergeEngine:
                 fp = {name: B.plane_rows(cols[name], n=n) for name in want}
                 if res.get("src") is not None:
                     fp["src"] = res["src"][:n]
-                if fp:
+                if fp or res.get("wins"):
                     pending[fam] = fp
                     self.flush_rows_downloaded += n
                 continue
             rows_d = np.unique(np.concatenate(dirty))
-            # pow2-padded gather idx (pad rows re-gather row 0 and are
-            # sliced off after download): with the FLUSH_GATHER_PAD
-            # floor, the gather jit re-traces per plane cap only
-            np2 = K.next_pow2(max(len(rows_d), self.FLUSH_GATHER_PAD))
-            idx_dev = self._put_batch(_pad(rows_d.astype(_I32), np2, 0))
-            g = {name: B.gather_rows(cols[name], idx_dev) for name in want}
-            src_dev = B.gather_rows(res["src"], idx_dev) \
-                if res.get("src") is not None else None
+            g, src_dev = {}, None
+            if want or res.get("src") is not None:
+                # a micro round fell back to the bulk protocol: gather
+                # its rows.  pow2-padded gather idx (pad rows re-gather
+                # row 0 and are sliced off after download): with the
+                # FLUSH_GATHER_PAD floor, the gather jit re-traces per
+                # plane cap only
+                np2 = K.next_pow2(max(len(rows_d), self.FLUSH_GATHER_PAD))
+                idx_dev = self._put_batch(_pad(rows_d.astype(_I32), np2, 0))
+                g = {name: B.gather_rows(cols[name], idx_dev)
+                     for name in want}
+                if res.get("src") is not None:
+                    src_dev = B.gather_rows(res["src"], idx_dev)
             partial[fam] = (rows_d, g, src_dev)
             self.flush_rows_downloaded += len(rows_d)
         for fp in pending.values():
             for arr in fp.values():
-                try:
-                    arr.copy_to_host_async()
-                except AttributeError:
-                    pass
+                _copy_async(arr)
         for _rows_d, g, src_dev in partial.values():
             for arr in g.values():
-                try:
-                    arr.copy_to_host_async()
-                except AttributeError:
-                    pass
+                _copy_async(arr)
             if src_dev is not None:
-                try:
-                    src_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
+                _copy_async(src_dev)
 
         for fam, fp in pending.items():
             res = self._res[fam]
@@ -915,6 +931,10 @@ class TpuMergeEngine:
                 self.bytes_d2h += int(h.nbytes)
                 host[name] = h
             table = _host_table(store, fam)
+            # micro rounds that preceded the whole-plane ones: their
+            # winners first, what the device says of the later rounds over
+            # them
+            self._apply_wins(store, fam, res)
             # the tombstone scan below only matters when the device could
             # have advanced del_t — skipped (all-add catch-up) it is
             # old_dt == del_t by construction
@@ -952,6 +972,9 @@ class TpuMergeEngine:
                 # incremental sum delta needs the PRE-flush host
                 # contributions of exactly the dirty rows
                 old_contrib = store.cnt.val[rows_d] - store.cnt.base[rows_d]
+            # win-vector rounds always precede a fallback's (once `src`
+            # is tracked every scatter falls back until this flush)
+            self._apply_wins(store, fam, res)
             host = {}
             for name in list(g):
                 h = np.asarray(g.pop(name))[:nd]
@@ -1039,6 +1062,45 @@ class TpuMergeEngine:
         self._tns_bytes = 0
         self._tns_epoch += 1
         self.needs_flush = False
+
+    def _apply_wins(self, store: KeySpace, fam: str, res: dict) -> None:
+        """Apply the family's micro rounds since the last flush to the
+        host columns, IN ROUND ORDER, from the win vector each scatter
+        returned (`bulk_lww_win`): a winning batch row's pair replaces the
+        host row's, a losing one leaves it.  Round k's vector was computed
+        against the planes after round k-1, and the host columns equalled
+        the mirror when the first round started (flush-before-touch), so
+        this reproduces the device's planes bit for bit.  Values by
+        _apply_src's rule: a winning row of a value-carrying key takes its
+        batch value — None where the batch carried none, which CLEARS the
+        slot; set members and counters carry no values.  Reading a vector
+        blocks on the scatter that produced it, and on nothing later."""
+        wins = res.get("wins")
+        if not wins:
+            return
+        table = _host_table(store, fam)
+        for pcol, scol, wr, win_dev, (_base, vals, cols) in wins:
+            win = np.asarray(win_dev)[:len(wr)]
+            self.bytes_d2h += len(wr)
+            at = np.flatnonzero(win)
+            if not len(at):
+                continue
+            rw = wr[at]
+            self.micro_win_rows += len(rw)
+            table.col(pcol)[rw] = np.asarray(cols[pcol])[at]
+            table.col(scol)[rw] = np.asarray(cols[scol])[at]
+            if fam == "reg":
+                target = store.reg_val
+            elif fam == "el":
+                keep = np.isin(store.keys.enc[store.el.kid[rw]],
+                               S.VALUE_ENCS)
+                rw, at = rw[keep], at[keep]
+                target = store.el_val
+            else:
+                continue  # counters carry no object values
+            for r, j in zip(rw.tolist(), at.tolist()):
+                target[r] = None if vals is None else vals[j]
+        res["wins"] = []
 
     def _apply_src(self, store: KeySpace, fam: str, src_h: np.ndarray,
                    res: dict, rows: Optional[np.ndarray] = None) -> None:
@@ -1162,7 +1224,7 @@ class TpuMergeEngine:
             # loud (a real raise, not an assert: `python -O` must not
             # strip the only guard between a dispatch-table bug and
             # silent data loss)
-            if res.get("written"):
+            if res.get("written") or res.get("wins"):
                 raise RuntimeError(
                     f"{fam} mirror invalidated with unflushed merge data "
                     "(flush-before-touch invariant broken upstream)")
@@ -1208,7 +1270,7 @@ class TpuMergeEngine:
         jepoch = res.get("jepoch") if res else None
         if journal is not None and (res is None or rows is not None):
             jepoch = journal.reset()
-        # `dirty`/`recon` survive a reuse/grow (the micro path
+        # `dirty`/`recon`/`wins` survive a reuse/grow (the micro path
         # appends touched rows between flushes); a fresh build starts
         # CLEAN (dirty=[] — host == device at build, nothing to download).
         # A patch writes nothing the host lacks: neither written nor dirty
@@ -1218,6 +1280,7 @@ class TpuMergeEngine:
                           "written": res.get("written", set()) if res
                           else set(),
                           "recon": res.get("recon") if res else None,
+                          "wins": res.get("wins", []) if res else [],
                           "dirty": res.get("dirty") if res else []}
         return cols, cap
 
@@ -1291,7 +1354,10 @@ class TpuMergeEngine:
                           "jepoch": prev.get("jepoch"),
                           "src": src if src is not None else prev.get("src"),
                           "recon": recon if recon is not None
-                          else prev.get("recon")}
+                          else prev.get("recon"),
+                          # earlier micro rounds' unapplied win vectors:
+                          # the flush applies them before this round's src
+                          "wins": prev.get("wins", [])}
         self.needs_flush = True
 
     def _drop_family(self, store: KeySpace, fam: str) -> None:
@@ -1307,15 +1373,17 @@ class TpuMergeEngine:
     # PLACE against the resident device planes instead of falling back to
     # the host micro strategy.  Duplicate slots fold on host with the exact
     # shared reductions from engine/hostbatch.py, the unique winners
-    # scatter once per family (ops/bulk.py bulk_lww_src), the env plane
-    # stays HOST-AUTHORITATIVE
+    # scatter once per family (ops/bulk.py bulk_lww_win: one block up,
+    # the win vector down), the env plane stays HOST-AUTHORITATIVE
     # (its merge is a collision-free max into host columns — zero device
     # bytes, and key-dt reads never need a flush), and every scatter's
-    # rows land in the family's dirty set so flush() downloads only them.
+    # rows land in the family's dirty set so flush() touches only them.
 
     def host_stale(self, families) -> bool:
         """True when any of `families` holds unflushed device-side merge
-        state (its host columns lag the device).  Callers that provably
+        state (its host columns lag the device: columns written and not
+        downloaded, a tracked `src`, or win vectors not yet applied).
+        Callers that provably
         read only planes OUTSIDE the stale set may skip the flush — the
         narrow read-barrier Node.ensure_flushed_for exposes to the
         steady-state coalescers (env is host-authoritative on the micro
@@ -1328,7 +1396,7 @@ class TpuMergeEngine:
                     return True
                 continue
             res = self._res.get(fam)
-            if res is not None and (res.get("written")
+            if res is not None and (res.get("written") or res.get("wins")
                                     or res.get("src") is not None):
                 return True
         return False
@@ -1459,7 +1527,7 @@ class TpuMergeEngine:
                             rows, b.cnt_uuid[sel], b.cnt_val[sel])
                         if not base_neutral:
                             # base pair (counter deletes — rare): no src
-                            # tracking, its dirty rows download at flush
+                            # tracking, its win vector tells the flush
                             wr2, wbt, wb, _ = fold_pair_rows(
                                 rows, bt, b.cnt_base[sel])
                 if on_dev:
@@ -1562,12 +1630,10 @@ class TpuMergeEngine:
         """Scatter one folded LWW pair in place against `fam`'s resident
         planes.  `pair` = (primary, secondary) column names; the win rule
         is lexicographic (primary, secondary) > current — exactly
-        hostbatch's fold rule and ops/bulk._pair_win.  With `src`
-        tracking (default) the winners' pool ids land in the resident
-        src plane: flush downloads the int32 src slice and reconstructs
-        both columns AND win values from the host pool.  src=False (the
-        rare counter base pair) keeps its winner on device and downloads
-        its dirty rows at flush."""
+        hostbatch's fold rule and ops/bulk._pair_win.  `vals` are the
+        batch rows' values (None: valueless).  How the host learns who
+        won is _scatter_pair's choice; src=False (the rare counter base
+        pair) is never tracked in the family's `src` plane."""
         if not len(wr):
             return
         with self.stages.stage("dispatch", fam):
@@ -1576,36 +1642,69 @@ class TpuMergeEngine:
     def _scatter_pair(self, store: KeySpace, fam: str, pair, wr, wp, ws,
                       vals, src: bool) -> None:
         """_micro_scatter_pair's body, under its `dispatch` stage (the
-        host-side launch: asynchronous, so launch time, not device time)."""
+        host-side launch: asynchronous, so launch time, not device time).
+
+        The round crosses the link once each way: ONE int32 [5, np2]
+        block up (the padded idx, then (hi, lo) of both columns, split
+        here over the batch's rows), ONE program (`bulk_lww_win`), and
+        its win vector down — `np2` bytes, copied as the program ends and
+        read at the flush (_apply_wins).  Pool ids never go to the device.
+
+        That holds while every round since the family's last flush was a
+        micro round (`dirty` is a list).  A family still carrying a
+        whole-plane round's unflushed state — `dirty` None, or a tracked
+        `src` — keeps the bulk protocol (`bulk_lww_src`: the winner's pool
+        id into the `src` plane, resolved by flush's gather): its flush
+        reads `src` anyway, and a later whole-plane round may win the same
+        rows again.  src=False (the counter base pair: the family's `src`
+        plane belongs to its value pair) always takes the win vector."""
         nw = len(wr)
         n = _fam_rows(store, fam)
         cols, sp = self._resident_state(store, fam, n)
+        res = self._res[fam]
         pcol, scol = pair
         # pad-floor the batch length (see MICRO_SCATTER_PAD); a batch
         # covering every plane row pads to itself (nw == sp == pow2)
         np2 = K.next_pow2(nw if nw >= sp
                           else max(nw, self.MICRO_SCATTER_PAD))
-        idx = self._batch_idx(wr, 0, sp, np2)
-        bp = self._put_batch(_pad(wp, np2, K.NEUTRAL_T))
-        bs = self._put_batch(_pad(ws, np2, K.NEUTRAL_T))
-        if src:
+        if src and (res["dirty"] is None or res["src"] is not None):
+            self.micro_src_scatters += 1
+            idx = self._batch_idx(wr, 0, sp, np2)
+            bp = self._put_batch(_pad(wp, np2, K.NEUTRAL_T))
+            bs = self._put_batch(_pad(ws, np2, K.NEUTRAL_T))
             pb = self._pool_add(vals, **{pcol: wp, scol: ws})
             p2, s2, src2 = B.bulk_lww_src(
                 cols[pcol], cols[scol], self._src_state(fam, sp),
                 idx, bp, bs, pb)
-            recon = {pcol: pcol, scol: scol}
-        else:
-            # (the rare counter base pair keeps its winner on device)
-            p2, s2, _win = B.bulk_lww(cols[pcol], cols[scol], idx, bp, bs)
-            src2 = recon = None
-        self._micro_done(fam, {pcol: p2, scol: s2}, src=src2, recon=recon,
-                         written={pcol, scol}, rows=wr)
+            self._micro_done(fam, {pcol: p2, scol: s2}, src=src2,
+                             recon={pcol: pcol, scol: scol},
+                             written={pcol, scol}, rows=wr)
+            return
+        blk = np.zeros((5, np2), dtype=_I32)
+        blk[0] = _pad_idx(wr, sp, np2)
+        for r, col in ((1, wp), (3, ws)):
+            # an int64's little-endian words: [lo, hi], lo's bit pattern
+            # as int32 (the program bitcasts it back to uint32)
+            words = np.ascontiguousarray(col, dtype="<i8").view("<i4")
+            blk[r, :nw] = words[1::2]
+            blk[r + 1, :nw] = words[0::2]
+        p2, s2, win = B.bulk_lww_win(cols[pcol], cols[scol],
+                                     self._put_batch(blk))
+        _copy_async(win)
+        # the pool entry pins the batch (values and both columns) until
+        # the flush applies it, under the same pool_flush_bytes bound
+        self._pool_add(vals, **{pcol: wp, scol: ws})
+        self._pool_bytes += np2
+        res["wins"].append((pcol, scol, wr, win, self._val_pool[-1]))
+        self.micro_win_scatters += 1
+        self._micro_done(fam, {pcol: p2, scol: s2}, rows=wr)
 
-    def _micro_done(self, fam: str, cols: dict, src, recon,
-                    written: set, rows: np.ndarray) -> None:
+    def _micro_done(self, fam: str, cols: dict, rows: np.ndarray,
+                    src=None, recon=None, written=frozenset()) -> None:
         """Fold a micro scatter's results into the family record: updated
-        device columns, src/recon tracking, written columns, and the
-        touched rows appended to the dirty set (a bulk-merged plane —
+        device columns, src/recon tracking, the columns the flush must
+        download (`written`: none where a win vector tells the host), and
+        the touched rows appended to the dirty set (a bulk-merged plane —
         dirty None — stays whole-plane)."""
         res = self._res[fam]
         res["cols"].update(cols)

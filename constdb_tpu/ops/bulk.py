@@ -26,7 +26,9 @@ plane is split into halves at a program's entry and recombined at its
 exit, whole-plane passes whatever the batch holds.  The pair is the ONLY
 form a state operand has here, on every backend; BATCH operands (`bt`,
 `bn`, patch `vals`) stay int64 (or int32) and the program splits them
-itself over the batch's rows, and gathers join their rows back to int64.
+itself over the batch's rows, and gathers join their rows back to int64;
+the micro round's `bulk_lww_win` takes its batch already split, as one
+int32 block.
 
 Padding protocol: rows are padded to a power-of-two count; padded rows get
 slot id = state_size + offset (distinct, out of bounds), so scatters drop
@@ -53,7 +55,7 @@ from ..crdt.semantics import NEUTRAL_T  # noqa: E402
 
 __all__ = ["NEUTRAL_T", "Plane", "plane_split", "plane_rows", "plane_diff",
            "neutral_plane", "grown_plane", "device_full", "bulk_max",
-           "bulk_max1", "bulk_lww",
+           "bulk_max1", "bulk_lww", "bulk_lww_win",
            "bulk_counters", "bulk_counters_vu", "bulk_counters_vu_src",
            "bulk_counters_src", "bulk_elems",
            "bulk_lww_src", "bulk_elems_src_nodt", "bulk_elems_nodt",
@@ -68,17 +70,30 @@ __all__ = ["NEUTRAL_T", "Plane", "plane_split", "plane_rows", "plane_diff",
 # never touches the device in the resident src path: del-merge is a plain
 # max the engine applies straight to the host column (engine/tpu.py).
 #
-# The *_src kernels track DEFERRED win resolution: instead of returning win
-# flags (whose download blocks the pipeline every call — fatal when the
-# device hangs off a high-latency link), the winning batch row's host
-# value-pool id scatters into a resident int32 `src` plane.  Ids are NOT
-# uploaded — pool entries are consecutive, so the kernel derives them as
-# `base + iota` (zero extra host→device bytes).  The engine downloads the
-# int32 `src` plane ONCE at flush and both resolves win values and
-# RECONSTRUCTS the winner-carried columns (el add_t/add_node, reg
-# rv_t/rv_node, cnt val/uuid) from host-side pools — those columns then
-# never cross the link at all (the round-4 flush was ~45% of wall time,
-# dominated by exactly these downloads).
+# Who won is told to the host in one of two ways, by the kind of round:
+#
+#   * A WHOLE-PLANE round (boot restore, catch-up: tens of thousands of
+#     rows a call, many calls before anything reads) runs a *_src kernel,
+#     which tracks DEFERRED win resolution: instead of returning win flags
+#     (whose download blocks the pipeline every call — fatal when the
+#     device hangs off a high-latency link), the winning batch row's host
+#     value-pool id scatters into a resident int32 `src` plane.  Ids are
+#     NOT uploaded — pool entries are consecutive, so the kernel derives
+#     them as `base + iota` (zero extra host→device bytes).  The engine
+#     downloads the int32 `src` plane ONCE at flush and both resolves win
+#     values and RECONSTRUCTS the winner-carried columns (el
+#     add_t/add_node, reg rv_t/rv_node, cnt val/uuid) from host-side
+#     pools — those columns then never cross the link at all (the round-4
+#     flush was ~45% of wall time, dominated by exactly these downloads).
+#   * A resident MICRO round (a coalesced run of served or replicated
+#     writes: a handful of rows, and a read flushes a few hundred
+#     microseconds later) runs `bulk_lww_win`, which RETURNS the win flags
+#     of its own batch — `np2` bytes, downloaded as the program ends.  The
+#     host holds the batch (its pool entry) and applies the rounds in
+#     order at the flush: no plane-sized `src` array is created, scattered
+#     into or gathered from.  A micro round that lands on a family still
+#     carrying a whole-plane round's unflushed `src` keeps the *_src
+#     kernel (engine/tpu.py _scatter_pair chooses on the family's record).
 
 
 class Plane(NamedTuple):
@@ -245,16 +260,21 @@ def _pair_win(cv: Plane, ct: Plane, vi: Plane, ti: Plane, in_range):
     return (_gt(ti, ct) | (_eq(ti, ct) & _gt(vi, cv))) & in_range
 
 
-def _merge_pair(t: Plane, v: Plane, idx, bt, bv):
-    """One (t, v) LWW pair merged with a batch -> (t, v, win [Np] bool):
-    gather the current rows, pick the winner, set both planes."""
+def _merge_planes(t: Plane, v: Plane, idx, bt: Plane, bv: Plane):
+    """One (t, v) LWW pair merged with a batch already in pairs
+    -> (t, v, win [Np] bool): gather the current rows, pick the winner,
+    set both planes.  32-bit lanes throughout."""
     size = t.shape[0]
     ic = jnp.minimum(idx, size - 1)
     ct, cv = _take(t, ic), _take(v, ic)
-    bt, bv = _split(bt), _split(bv)
     win = _pair_win(cv, ct, bv, bt, idx < size)
     return (_set(t, idx, _where(win, bt, ct), unique_indices=True),
             _set(v, idx, _where(win, bv, cv), unique_indices=True), win)
+
+
+def _merge_pair(t: Plane, v: Plane, idx, bt, bv):
+    """_merge_planes for int64 (or int32) batch columns, split here."""
+    return _merge_planes(t, v, idx, _split(bt), _split(bv))
 
 
 def _track_src(src, idx, win, base):
@@ -270,6 +290,20 @@ def bulk_lww(t, n, idx, bt, bn):
     -> (t [Sp], n [Sp], win [Np] bool) — win marks batch rows whose VALUE
     must replace the slot's value."""
     return _merge_pair(t, n, idx, bt, bn)
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def bulk_lww_win(t, n, blk):
+    """bulk_lww for a resident micro round, one operand up and the win
+    flags down: `blk` int32 [5, Np] = the padded idx, then (hi, lo) of the
+    primary and of the secondary column, split on the host over the
+    batch's rows (lo's bit pattern as int32).  No int64 in the program.
+    -> (t, n, win [Np] bool); the engine applies `win` to the host
+    columns at the flush (engine/tpu.py _apply_wins)."""
+    def col(r):
+        return Plane(blk[r], jax.lax.bitcast_convert_type(blk[r + 1],
+                                                          jnp.uint32))
+    return _merge_planes(t, n, blk[0], col(1), col(3))
 
 
 @partial(jax.jit, donate_argnums=(0, 1))

@@ -268,6 +268,11 @@ def _section_stats(node, out):
         out.append(("dev_upload_bytes", node.engine.bytes_h2d))
         out.append(("dev_download_bytes", node.engine.bytes_d2h))
     for gauge in ("dev_rounds_resident", "host_micro_rounds",
+                  # the micro round's link protocol: scatters that
+                  # returned a win vector / fell back to `src`, and rows
+                  # the flush applied from win vectors
+                  "micro_win_scatters", "micro_src_scatters",
+                  "micro_win_rows",
                   "flush_rows_downloaded", "flush_rows_full_equiv"):
         v = getattr(node.engine, gauge, None)
         if v is not None:

@@ -398,7 +398,7 @@ def test_unflushed_merge_data_under_a_stale_mirror_still_raises():
     node, eng = device_node(warmup=0)
     warm_el(node)
     sadd_round(node, 0)
-    assert eng._res["el"]["written"]             # merged, not flushed
+    assert eng._res["el"]["wins"]                # merged, not flushed
     node.ks.elem_add(node.ks.lookup(b"warm"), b"behind-the-node", None,
                      u(9), 1)
     node.ks.touch("el")                          # no flush before the touch

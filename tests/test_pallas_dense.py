@@ -239,13 +239,13 @@ def test_padded_micro_batch_equals_host(monkeypatch, n_keys, n_touch, np2):
     rule: pads target rows >= sp, the scatter drops them, and the merged
     planes equal the host engine's."""
     seen = []
-    real = B.bulk_lww_src
+    real = B.bulk_lww_win
 
-    def spy(t, n, src, idx, bt, bn, base):
-        seen.append((t.shape[0], np.asarray(idx)))
-        return real(t, n, src, idx, bt, bn, base)
+    def spy(t, n, blk):
+        seen.append((t.shape[0], np.asarray(blk)[0]))
+        return real(t, n, blk)
 
-    monkeypatch.setattr(B, "bulk_lww_src", spy)
+    monkeypatch.setattr(B, "bulk_lww_win", spy)
     keys = [b"r%03d" % i for i in range(n_keys)]
     ref, dev = KeySpace(), KeySpace()
     cpu = CpuMergeEngine()

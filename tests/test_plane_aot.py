@@ -66,6 +66,11 @@ def lowered(name: str, sharding=None):
 
     idx, src, i32 = sds((BP,), jnp.int32), sds((CAP,), jnp.int32), \
         sds((), jnp.int32)
+    if name == "bulk_lww_win":
+        # the donated planes and ONE int32 block (idx + both columns'
+        # halves); `win` is its only output that is not a plane
+        return B.bulk_lww_win.lower(plane(), plane(),
+                                    sds((5, BP), jnp.int32))
     if name.startswith("mirror_patch_"):
         fam, bp = name.removeprefix("mirror_patch_").split("@")
         nc = {"reg": 2, "cnt": 4, "el": 3}[fam]
@@ -95,23 +100,30 @@ def lowered(name: str, sharding=None):
     return getattr(B, name).lower(*args[name])
 
 
-# what a served window launches: the micro rounds' scatters (src-tracked
-# pair, the counter base pair, the element del side), a stale mirror's
-# patch at each bucket, the flush's dirty-row gather
-WINDOW = ["bulk_lww_src", "bulk_lww", "bulk_max1", "gather_rows",
+# what a served window launches: the micro round's scatter, which returns
+# its win vector (every pair of every family), the element del side, a
+# stale mirror's patch at each bucket.  Since PR 38 no served window
+# launches `bulk_lww_src` or `gather_rows` (a micro round tracks `src` only
+# on a family that still carries a whole-plane round's, and only then does
+# the flush gather), nor `bulk_lww` on the micro path: they are FALLBACK,
+# which a window after a catch-up may still reach — compiled for the chip
+# and lowered like the window's own.
+WINDOW = ["bulk_lww_win", "bulk_max1",
           *(f"mirror_patch_el@{bp}"
             for bp in TpuMergeEngine.MIRROR_PATCH_BUCKETS)]
 # ... and the rest of ops/bulk.py's state programs (boot restore, forced
-# folds, the other families' patches).  `plane_rows` and `plane_diff` are
-# the two that JOIN a plane on purpose, once a whole-plane flush.
-EVERY = WINDOW + ["bulk_max", "bulk_counters_vu", "bulk_counters",
+# folds, the other families' patches).
+# `plane_rows` and `plane_diff` are the two that JOIN a plane on purpose,
+# once a whole-plane flush.
+FALLBACK = ["bulk_lww_src", "bulk_lww", "gather_rows"]
+EVERY = WINDOW + FALLBACK + ["bulk_max", "bulk_counters_vu", "bulk_counters",
                   "bulk_counters_vu_src", "bulk_counters_src", "bulk_elems",
                   "bulk_lww_src_iota", "bulk_counters_vu_src_iota",
                   "device_full", "mirror_patch_reg@1024",
                   "mirror_patch_cnt@1024"]
 
 
-@pytest.mark.parametrize("name", WINDOW)
+@pytest.mark.parametrize("name", WINDOW + FALLBACK)
 def test_compiled_for_v5e_no_x64_pass_over_a_plane(one_chip, name):
     compiled = lowered(name, one_chip).compile()
     text = compiled.as_text()
@@ -136,3 +148,9 @@ def test_lowered_program_holds_no_plane_length_64_bit_array(name):
     assert f"tensor<{CAP}x" in text, "the plane is not in the program"
     wide = re.findall(r"tensor<%d(?:x\d+)*x(?:[su]?i|f)64>" % CAP, text)
     assert not wide, f"{name}: {sorted(set(wide))}"
+
+
+def test_the_micro_rounds_program_returns_two_planes_and_the_win_flags():
+    out = jax.tree.leaves(lowered("bulk_lww_win").out_info)
+    assert [(o.shape, o.dtype.name) for o in out] == \
+        [((CAP,), "int32"), ((CAP,), "uint32")] * 2 + [((BP,), "bool")]

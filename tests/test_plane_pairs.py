@@ -156,6 +156,28 @@ def run_lww(fn, st, sp, idx, rng, base, src, iota):
         np.testing.assert_array_equal(np.asarray(got_win)[: len(idx)], win)
 
 
+def run_lww_win(fn, st, sp, idx, rng, *_):
+    """The micro round's program: one int32 block (the engine's
+    `_scatter_pair`: idx, then both columns' words, lo's bits as int32),
+    the win vector back."""
+    n = len(idx)
+    bt, bn = draw(rng, n), draw(rng, n)
+    ties = rng.random(n) < 0.3
+    bt[ties] = st["t"][idx[ties]]
+    np2 = next_pow2(n)
+    blk = np.zeros((5, np2), dtype=np.int32)
+    blk[0] = _pad_idx(idx, sp, np2)
+    for r, col in ((1, bt), (3, bn)):
+        words = col.view(np.uint32).reshape(-1, 2)   # little-endian host
+        blk[r, :n], blk[r + 1, :n] = (words[:, 1].view(np.int32),
+                                      words[:, 0].view(np.int32))
+    win = ref_pair(st["t"], st["n"], idx, bt, bn)
+    st["T"], st["N"], got_win = fn(st["T"], st["N"], jnp.array(blk))
+    assert got_win.dtype == jnp.bool_ and got_win.shape == (np2,)
+    np.testing.assert_array_equal(np.asarray(got_win)[:n], win)
+    assert not np.asarray(got_win)[n:].any()         # pads never win
+
+
 def run_counters(fn, st, sp, idx, rng, base, src, iota, with_base):
     n = len(idx)
     bv, bt, bb, bbt = (draw(rng, n) for _ in range(4))
@@ -226,6 +248,7 @@ def run_patch(fn, st, sp, idx, rng, *_):
 
 PROGRAMS = {
     "bulk_lww": (B.bulk_lww, run_lww, False, False),
+    "bulk_lww_win": (B.bulk_lww_win, run_lww_win),
     "bulk_lww_src": (B.bulk_lww_src, run_lww, True, False),
     "bulk_lww_src_iota": (B.bulk_lww_src_iota, run_lww, True, True),
     "bulk_counters_vu": (B.bulk_counters_vu, run_counters, False, False,

@@ -17,7 +17,11 @@ The load-bearing claims of the round-12 routing inversion, each pinned:
   * `CONSTDB_RESIDENT=0` (and steady=False) pin the pre-round-12 host
     micro routing exactly;
   * `host_stale` reports exactly the families holding unflushed device
-    state.
+    state — unapplied win vectors included;
+  * a micro round crosses the link once each way (one block up, its win
+    vector down) and its flush launches nothing: rounds on the same rows
+    before one flush, either order of bulk and micro rounds, and the
+    stage-clock protocol test.
 """
 
 import asyncio
@@ -27,8 +31,12 @@ import pytest
 
 jax = pytest.importorskip("jax")  # noqa: F841
 
+from constdb_tpu.crdt import semantics as S
+from constdb_tpu.engine.base import ColumnarBatch
+from constdb_tpu.engine.cpu import CpuMergeEngine
 from constdb_tpu.engine.tpu import TpuMergeEngine
 from constdb_tpu.server.node import Node
+from constdb_tpu.store.keyspace import KeySpace
 from constdb_tpu.utils.hlc import SEQ_BITS
 
 from test_coalesce_apply import drive, frame, mixed_stream, u
@@ -364,3 +372,243 @@ def test_depth1_sets_of_fifty_connections_reach_the_device_planes(tmp_path):
     assert st.serve_gather_msgs == 50 * 40
     assert st.serve_gather_msgs / st.serve_gather_passes > 2
     assert st.serve_msgs_coalesced > 0
+
+
+# ------------------------------------------- the micro round's link protocol
+# A resident micro round sends one block up and gets its win vector back
+# (ops/bulk.py bulk_lww_win); the flush launches nothing and applies the
+# rounds in order (engine/tpu.py _apply_wins).  Differentials against the
+# host twin (engine/hostbatch.py through CpuMergeEngine): the same batches,
+# byte-identical host columns, values and canonical export after ONE flush.
+
+def micro(keys, enc, reg=(), cnt=(), el=(), unique=False):
+    """One op-stream batch.  reg: (ki, t, node, val); cnt: (ki, node, val,
+    uuid, base, base_t); el: (ki, member, val, add_t, add_node, del_t)."""
+    def col(rows, i):
+        return np.array([r[i] for r in rows], dtype=np.int64)
+
+    b = ColumnarBatch()
+    nk = len(keys)
+    b.keys = list(keys)
+    b.key_enc = np.full(nk, getattr(S, "ENC_" + enc), dtype=np.int8)
+    b.key_ct = np.full(nk, u(1), dtype=np.int64)
+    b.key_mt = np.full(nk, u(1), dtype=np.int64)
+    b.key_dt = np.zeros(nk, dtype=np.int64)
+    b.key_expire = np.zeros(nk, dtype=np.int64)
+    b.reg_val = [None] * nk
+    b.reg_t = np.zeros(nk, dtype=np.int64)
+    b.reg_node = np.zeros(nk, dtype=np.int64)
+    for ki, t, node, val in reg:
+        b.reg_val[ki], b.reg_t[ki], b.reg_node[ki] = val, t, node
+    if cnt:
+        (b.cnt_ki, b.cnt_node, b.cnt_val, b.cnt_uuid, b.cnt_base,
+         b.cnt_base_t) = (col(cnt, i) for i in range(6))
+    if el:
+        b.el_ki, b.el_add_t, b.el_add_node, b.el_del_t = (
+            col(el, i) for i in (0, 3, 4, 5))
+        b.el_member = [r[1] for r in el]
+        b.el_val = [r[2] for r in el]
+    b.rows_unique_per_slot = unique
+    return b
+
+
+def reg_rounds():
+    """Four rounds on the same three registers: a first write, a newer
+    one (wins), an older one (loses), a tie on t broken by node."""
+    keys = [b"r0", b"r1", b"r2"]
+
+    def rnd(ts, nodes, tag):
+        return micro(keys, "BYTES",
+                     reg=[(i, u(t), nd, b"%s%d" % (tag, i))
+                          for i, (t, nd) in enumerate(zip(ts, nodes))])
+    return [rnd((5, 5, 5), (1, 1, 1), b"a"), rnd((9, 2, 5), (1, 1, 2), b"b"),
+            rnd((7, 6, 5), (3, 3, 1), b"c"), rnd((9, 6, 1), (2, 1, 9), b"d")]
+
+
+def el_rounds(values: bool):
+    """Set members (valueless) or dict fields (valued): the same rows
+    written by four rounds, wins and losses interleaved, a valueless
+    winner over a valued slot, and a delete in the third batch."""
+    keys = [b"e0", b"e1"]
+    v = (lambda s: s) if values else (lambda s: None)
+
+    def rnd(rows):
+        return micro(keys, "DICT" if values else "SET",
+                     el=[(ki, m, val, u(t) if t else 0, nd, u(d) if d else 0)
+                         for ki, m, val, t, nd, d in rows])
+    return [rnd([(0, b"f0", v(b"a0"), 5, 1, 0), (0, b"f1", v(b"a1"), 5, 1, 0),
+                 (1, b"f0", v(b"a2"), 5, 1, 0)]),
+            rnd([(0, b"f0", v(b"b0"), 8, 1, 0), (0, b"f1", v(b"b1"), 3, 1, 0),
+                 (1, b"f0", None, 9, 2, 0)]),
+            rnd([(0, b"f0", v(b"c0"), 6, 4, 0), (0, b"f1", None, 0, 0, 7),
+                 (1, b"f0", v(b"c2"), 9, 3, 0), (1, b"f9", v(b"c3"), 2, 1, 0)]),
+            rnd([(0, b"f0", v(b"d0"), 8, 2, 0), (0, b"f1", v(b"d1"), 9, 1, 0),
+                 (1, b"f0", v(b"c2"), 9, 3, 0)])]    # a redelivery: no win
+
+
+def cnt_rounds():
+    """Counter slots of two keys: the (uuid, val) pair and a NON-neutral
+    (base_t, base) pair, each winning in some rounds and losing in others
+    on the same rows."""
+    keys = [b"c0", b"c1"]
+
+    def rnd(rows):
+        return micro(keys, "COUNTER",
+                     cnt=[(ki, nd, val, u(t), base, u(bt) if bt else S.NEUTRAL_T)
+                          for ki, nd, val, t, base, bt in rows])
+    return [rnd([(0, 1, 10, 5, 0, 0), (0, 2, 20, 5, 0, 0), (1, 1, 30, 5, 0, 0)]),
+            rnd([(0, 1, 11, 7, 4, 6), (0, 2, 19, 4, 0, 0), (1, 1, 31, 5, 9, 3)]),
+            rnd([(0, 1, 12, 6, 2, 6), (0, 2, 25, 8, 7, 2), (1, 1, 29, 5, 1, 2)]),
+            rnd([(0, 1, 13, 7, 5, 5), (0, 2, 1, 8, 3, 9), (1, 1, 40, 9, 9, 3)])]
+
+
+ROUNDS = {"reg": reg_rounds, "el-values": lambda: el_rounds(True),
+          "el-members": lambda: el_rounds(False), "cnt": cnt_rounds}
+
+
+def host_state(ks):
+    """Every host column the micro path writes, and the object values."""
+    n_el, n_cnt, n_k = ks.el.n, ks.cnt.n, ks.keys.n
+    cols = {"rv_t": ks.keys.rv_t[:n_k], "rv_node": ks.keys.rv_node[:n_k],
+            "cnt_sum": ks.keys.cnt_sum[:n_k]}
+    cols.update({c: ks.el.col(c)[:n_el]
+                 for c in ("add_t", "add_node", "del_t")})
+    # (the two engines hand out counter slots in different orders)
+    by_slot = np.lexsort((ks.cnt.node[:n_cnt], ks.cnt.kid[:n_cnt]))
+    cols.update({c: ks.cnt.col(c)[:n_cnt][by_slot]
+                 for c in ("kid", "node", "val", "uuid", "base", "base_t")})
+    return ({k: v.tolist() for k, v in cols.items()},
+            list(ks.reg_val[:n_k]), list(ks.el_val[:n_el]))
+
+
+def flush(engine, ks):
+    if getattr(engine, "needs_flush", False):      # the CPU engine has none
+        engine.flush(ks)
+
+
+def merged(engine, batches):
+    ks = KeySpace()
+    for b in batches:
+        engine.merge_many(ks, [b])
+    flush(engine, ks)
+    return ks
+
+
+@pytest.mark.parametrize("case", list(ROUNDS))
+def test_rounds_on_the_same_rows_before_one_flush_equal_the_host_twin(case):
+    eng = steady_engine()
+    got = merged(eng, ROUNDS[case]())
+    want = merged(CpuMergeEngine(), ROUNDS[case]())
+    assert host_state(got) == host_state(want)
+    assert got.canonical() == want.canonical()
+    # every scatter returned its win vector; nothing tracked `src`
+    pairs = 2 if case == "cnt" else 1
+    assert eng.dev_rounds_resident == 4
+    assert eng.micro_src_scatters == 0
+    assert eng.micro_win_scatters >= 4 * pairs - 1   # r1's base is neutral
+    assert eng.micro_win_rows > 0
+    assert eng.stages.snapshot()["state_alloc"][1] <= 1   # _warm_patch only
+    assert not eng.host_stale(("reg", "cnt", "el"))
+
+
+@pytest.mark.parametrize("case", list(ROUNDS))
+def test_a_family_is_stale_while_it_holds_unapplied_win_vectors(case):
+    fam = case.split("-")[0]
+    eng = steady_engine()
+    ks = KeySpace()
+    eng.merge_many(ks, [ROUNDS[case]()[0]])
+    res = eng._res[fam]
+    assert res["wins"] and not res["written"] and res["src"] is None
+    assert eng.host_stale((fam,))
+    assert not eng.host_stale(tuple({"reg", "cnt", "el"} - {fam}))
+    eng.flush(ks)
+    assert not res["wins"] and not eng.host_stale((fam,))
+
+
+@pytest.mark.parametrize("case", list(ROUNDS))
+def test_a_micro_round_after_a_bulk_round_falls_back_to_src(case):
+    """A whole-plane round (unique batch: the bulk path, `src` tracked,
+    dirty None), then micro rounds, then ONE flush: the micro scatters
+    keep the bulk protocol while the family carries unflushed `src`
+    (their vectors and the bulk round's `src` resolve in one flush), and
+    take the win-vector protocol again after the flush."""
+    rounds = ROUNDS[case]()
+    first = ROUNDS[case]()[0]
+    first.rows_unique_per_slot = True
+
+    def run(engine):
+        ks = KeySpace()
+        for b in [first] + rounds[1:3]:
+            engine.merge_many(ks, [b])
+        mid = (getattr(engine, "micro_src_scatters", 0),
+               getattr(engine, "micro_win_scatters", 0))
+        flush(engine, ks)
+        engine.merge_many(ks, [rounds[3]])
+        flush(engine, ks)
+        return ks, mid
+
+    eng = steady_engine()
+    got, (n_src, n_win) = run(eng)
+    want, _ = run(CpuMergeEngine())
+    assert host_state(got) == host_state(want)
+    assert got.canonical() == want.canonical()
+    # (the counter base pair is never tracked in `src`: win vectors)
+    assert n_src == 2 and n_win == (2 if case == "cnt" else 0)
+    assert eng.micro_win_scatters >= 1 and eng.micro_src_scatters == n_src
+
+
+@pytest.mark.parametrize("case", list(ROUNDS))
+def test_micro_rounds_then_a_bulk_round_before_one_flush(case):
+    """The other order: win-vector rounds, then a whole-plane round on
+    the same rows, one flush — the vectors apply first, `src` over them."""
+    rounds = ROUNDS[case]()
+    rounds[2].rows_unique_per_slot = True
+    got = merged(steady_engine(), rounds[:3])
+    want = merged(CpuMergeEngine(), ROUNDS[case]()[:3])
+    assert host_state(got) == host_state(want)
+    assert got.canonical() == want.canonical()
+
+
+@pytest.mark.parametrize("fam", ("reg", "el"))
+def test_a_round_and_its_flush_cross_the_link_once_each_way(fam):
+    """The protocol, on the stage clocks: k micro rounds each followed by
+    a flush make k uploads (`h2d` entries), k launches (`dispatch`), no
+    `state_alloc`, and no scatter tracks `src`."""
+    eng = steady_engine()
+    ks = KeySpace()
+
+    def rnd(i, rows=(0, 1)):
+        t = {0: u(i + (i % 3)), 1: u(9 - i)}     # row 0 wins two rounds of
+        if fam == "reg":                         # three, row 1 none
+            return micro([b"p%03d" % r for r in rows], "BYTES",
+                         reg=[(j, t.get(r, u(1)), 1, b"v%d-%d" % (r, i))
+                              for j, r in enumerate(rows)])
+        return micro([b"h"], "DICT",
+                     el=[(0, b"f%03d" % r, b"v%d-%d" % (r, i), t.get(r, u(1)),
+                          1, 0) for r in rows])
+
+    # 300 rows: the plane outgrows a batch, so a batch pads to the floor;
+    # this first round builds the mirror and its flush warms the patch
+    eng.merge_many(ks, [rnd(0, range(300))])
+    eng.flush(ks)
+    up0 = eng.bytes_h2d
+    before = eng.stages.snapshot()
+    k = 6
+    for i in range(1, k + 1):
+        eng.merge_many(ks, [rnd(i)])
+        assert eng.host_stale((fam,))
+        eng.flush(ks)
+    after = eng.stages.snapshot()
+
+    def moved(stage):
+        return after[stage][1] - before[stage][1]
+    assert moved("h2d") == k
+    assert moved("dispatch") == k
+    assert moved("d2h_flush") == k
+    assert moved("state_alloc") == 0
+    assert moved("mirror_rebuild") == moved("mirror_patch") == 0
+    assert eng.micro_src_scatters == 0
+    assert eng.micro_win_scatters == k + 1
+    assert eng.micro_win_rows == 300 + 4
+    # one 5 x 256 int32 block a round, nothing else up
+    assert eng.bytes_h2d - up0 == k * 5 * eng.MICRO_SCATTER_PAD * 4

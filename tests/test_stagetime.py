@@ -55,6 +55,10 @@ LINK_COUNTERS = ["repl_apply_lag_ms_sum", "repl_apply_lag_n", "repl_ops_out",
 # the loop-pass gather's counters beside its stage (server/io.py)
 GATHER_COUNTERS = ["serve_gather_passes", "serve_gather_msgs",
                    "serve_gather_conns", "serve_lone_cmds"]
+# the micro round's link protocol (engine/tpu.py _scatter_pair): scatters
+# that returned a win vector / fell back to `src`, rows applied from vectors
+MICRO_COUNTERS = ["micro_win_scatters", "micro_src_scatters",
+                  "micro_win_rows"]
 
 
 @pytest.fixture
@@ -247,8 +251,8 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want += [f"mirror_rebuilds_cause_{c}" for c in TOUCH_CAUSES]
     want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
-    want += LINK_COUNTERS + GATHER_COUNTERS
-    assert len(want) == 2 * 19 + 8 + 6 + 7 + 5 + 4
+    want += LINK_COUNTERS + GATHER_COUNTERS + MICRO_COUNTERS
+    assert len(want) == 2 * 19 + 8 + 6 + 7 + 5 + 4 + 3
     assert STAGES.index("gather") == 1 and "gather" not in ANNOTATED
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
     # a CPU-engine node has the clock, not the device engine's counters
@@ -256,6 +260,7 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
     assert all(cpu[k] == 0 for k in LINK_COUNTERS + GATHER_COUNTERS)
     assert "merge_rows_dev_el" not in cpu
+    assert not any(k in cpu for k in MICRO_COUNTERS)
 
 
 def test_node_adopts_the_engines_clock():
@@ -382,6 +387,12 @@ def test_device_engine_counts_rows_by_path_and_rebuilds_by_cause():
     assert info["mirror_patches_el"] == 1
     assert info["mirror_patch_rows_el"] == 1 + 6
     assert info["mirror_patch_overflows"] == 0
+    # both device rounds returned their win vector; the first is applied
+    # (the lone command's flush: six new members, six winners), the
+    # second still waits for the read barrier below
+    assert info["micro_win_scatters"] == 2
+    assert info["micro_src_scatters"] == 0
+    assert info["micro_win_rows"] == 6
     for s in ("serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
               "mirror_rebuild", "mirror_patch", "state_alloc"):
         assert info[f"span_{s}_n"] > 0, s
@@ -393,6 +404,7 @@ def test_device_engine_counts_rows_by_path_and_rebuilds_by_cause():
     assert info["span_d2h_flush_n"] == 1
     node.ensure_flushed()
     assert info_of(node)["span_d2h_flush_n"] == 2
+    assert info_of(node)["micro_win_rows"] == 12
     # rows moved (a GC's cause): the journal is whole, the plane rebuilds
     node.ks.touch("el", cause="gc")
     sadd_round(node, 24)
