@@ -15,11 +15,13 @@ import asyncio
 import logging
 import signal
 import sys
+from functools import partial
 
 from ..conf import Config, build_engine, load_config
 from ..persist.snapshot import NodeMeta, dump_keyspace
 from ..server.io import ServerApp, start_node
 from ..server.node import Node
+from ..utils.stagetime import TimedSelector
 
 log = logging.getLogger("constdb_tpu.server")
 
@@ -117,10 +119,19 @@ async def snapshot_cron(app: ServerApp, cfg: Config) -> None:
             log.error("background snapshot failed: %s", e)
 
 
-async def amain(cfg: Config) -> None:
+def loop_factory(selector: TimedSelector):
+    """asyncio.run's `loop_factory`: the default selector loop, polling
+    through `selector` (its wait in epoll is the stage `loop_poll`)."""
+    return partial(asyncio.SelectorEventLoop, selector)
+
+
+async def amain(cfg: Config, selector: TimedSelector) -> None:
     node = Node(node_id=cfg.node_id, alias=cfg.node_alias,
                 engine=build_engine(cfg.engine),
                 repl_log_cap=cfg.repl_log_cap)
+    # the loop's poll, its thread and every collection, on the node's
+    # clock (INFO span_loop_poll_*, loop_*, span_gc_*)
+    selector.watch(node.stages)
     app = await start_node(
         node, host=cfg.ip, port=cfg.port,
         advertised_addr=cfg.addr, work_dir=cfg.work_dir,
@@ -213,8 +224,10 @@ def main(argv=None) -> None:
             cfg.log = os.path.join(cfg.work_dir, "constdb.log")
         pid_path = daemonize(cfg)
     setup_logging(cfg)
+    selector = TimedSelector()
     try:
-        asyncio.run(amain(cfg))
+        asyncio.run(amain(cfg, selector),
+                    loop_factory=loop_factory(selector))
     except KeyboardInterrupt:
         pass
     finally:

@@ -314,14 +314,16 @@ class TpuMergeEngine:
         self.merge_rows_dev = dict.fromkeys(self.FAM_ORDER, 0)
         self.merge_rows_host = dict.fromkeys(self.FAM_ORDER, 0)
         # the served path's stage clock (utils/stagetime.py): every host
-        # clock below is taken through it, and its annotated stages land
-        # in the device trace's host plane.  The Node adopts it.
-        self.stages = StageClock(annotation=jax.profiler.TraceAnnotation)
+        # clock below is taken through it, and while a profiler trace runs
+        # its stages land in the device trace's host plane.  The Node
+        # adopts it.
+        ann = jax.profiler.TraceAnnotation
+        self.stages = StageClock(trace=(ann, ann.is_enabled))
         # cumulative host-side seconds per family on the CRITICAL PATH
-        # (stage-wait + dispatch; device work is async; INFO
-        # merge_<fam>_seconds).  Inclusive totals that overlap the stage
-        # clock's self times by design: "micro" covers a whole resident
-        # micro round, "flush" includes the blocking downloads.
+        # (stage-wait + dispatch; device work is async; read by bench.py,
+        # ROADMAP D1).  Inclusive totals that overlap the stage clock's
+        # self times by design: "micro" covers a whole resident micro
+        # round, "flush" includes the blocking downloads.
         self.family_secs = {"env": 0.0, "reg": 0.0, "cnt": 0.0, "el": 0.0,
                             "flush": 0.0, "host": 0.0, "micro": 0.0}
         from ..conf import env_flag, env_int

@@ -218,22 +218,17 @@ def _section_stats(node, out):
                     round(float(np.percentile(lat_ms, 99)), 3)))
     out.append(("merge_batches", st.merges))
     out.append(("merge_rows", st.merge_rows))
-    merge_secs = st.secs["merge"]
-    out.append(("merge_seconds_total", round(merge_secs, 6)))
-    if st.merges and merge_secs:
-        out.append(("merge_rows_per_sec", int(st.merge_rows / merge_secs)))
-    out.append(("flush_seconds_total", round(st.secs["flush"], 6)))
     # the served path's stage clock (utils/stagetime.py): SELF time per
     # stage in whole microseconds and entries, every declared stage from
-    # boot — nested stages on one thread add up to wall time, so these
-    # can be summed where the inclusive merge_*_seconds totals cannot
+    # boot — nested stages on one thread add up to wall time.  Then the
+    # loop thread's own clock: ready fds its polls returned, its CPU time
+    # and context switches, and the collections by generation — a window
+    # of the loop is Σ stages + `loop_poll` (waiting on clients) + the
+    # rest; wall - CPU - `loop_poll` is time it was runnable and not run
     for name, (us, n) in node.stages.snapshot().items():
         out.append((f"span_{name}_us", us))
         out.append((f"span_{name}_n", n))
-    fam = getattr(node.engine, "family_secs", None)
-    if fam:
-        for name, secs in sorted(fam.items()):
-            out.append((f"merge_{name}_seconds", round(secs, 6)))
+    out.extend(node.stages.loop_stats())
     folds = getattr(node.engine, "folds", None)
     if folds is not None:
         out.append(("merge_folds", folds))

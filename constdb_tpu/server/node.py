@@ -16,7 +16,7 @@ from typing import Optional
 from ..engine.cpu import CpuMergeEngine
 from ..store.keyspace import KeySpace
 from ..utils.hlc import HLC, SEQ_BITS, now_ms
-from ..utils.stagetime import StageClock, seconds_into
+from ..utils.stagetime import StageClock
 from .events import EVENT_REPLICATED, EventBus
 from .repl_log import ReplLog
 
@@ -152,11 +152,6 @@ class NodeStats:
     tracking_demotions: int = 0
     merges: int = 0
     merge_rows: int = 0
-    # inclusive seconds inside engine.merge*/flush as this node called
-    # them (INFO merge_seconds_total / flush_seconds_total; fed by
-    # utils/stagetime.seconds_into — they overlap the stage clock's self
-    # times by design)
-    secs: dict = field(default_factory=lambda: {"merge": 0.0, "flush": 0.0})
     gc_freed: int = 0
     start_time: float = 0.0
     extra: dict = field(default_factory=dict)
@@ -394,8 +389,7 @@ class Node:
         between calls; it flushes to the host lazily before the next read
         (`ensure_flushed`)."""
         self._invalidate_reads((batch,))
-        with seconds_into(self.stats.secs, "merge"):
-            st = self.engine.merge(self.ks, batch)
+        st = self.engine.merge(self.ks, batch)
         self.stats.merges += 1
         self.stats.merge_rows += batch.n_rows
         self._dump_stale()
@@ -453,8 +447,7 @@ class Node:
                 self.merge_batch(b)
             return
         self._invalidate_reads(batches)
-        with seconds_into(self.stats.secs, "merge"):
-            self.engine.merge_many(self.ks, batches)
+        self.engine.merge_many(self.ks, batches)
         self.stats.merges += 1
         self.stats.merge_rows += sum(b.n_rows for b in batches)
         if len(batches) > 1:
@@ -584,8 +577,7 @@ class Node:
         before any read/write of the numeric plane."""
         engine = self.engine
         if getattr(engine, "needs_flush", False):
-            with seconds_into(self.stats.secs, "flush"):
-                engine.flush(self.ks)
+            engine.flush(self.ks)
 
     def ensure_flushed_for(self, families) -> None:
         """Flush only when unflushed device-resident state actually

@@ -5,8 +5,14 @@ Pinned here:
   * self time: nested stages on one thread add up to the outer
     duration, a child's time is never counted twice, an exception still
     closes and counts the stage, threads never nest into each other;
-  * the vocabulary is closed (an undeclared name raises) and only the
-    per-flush stages open trace spans, named `cst.<name>[.<tag>]`;
+  * the vocabulary is closed (an undeclared name raises) and a stage
+    opens its trace span `cst.<name>[.<tag>]` only while a trace runs
+    (the engine's enabled check), a young generation's `gc` never;
+  * the event loop's own clock: `loop_poll` one entry per iteration of
+    a loop built by bin/server's factory, never under another stage; a
+    forced collection is the stage `gc`, taken out of the stage it
+    interrupted; the loop thread's CPU time, context switches and the
+    collections by generation in INFO from boot, never decreasing;
   * INFO lists every `span_*`, `merge_rows_*`, `mirror_rebuilds_cause_*`
     and `mirror_patch*` field from boot, at 0 — `readers._delta`
     (benchmark/readers.py) reads a missing counter as "no metric", and a
@@ -15,20 +21,22 @@ Pinned here:
     stages, and their sum stays under the wall time of the exchange;
   * a device engine on JAX-CPU counts merged rows by path, mirror
     rebuilds by cause and mirror patches with their rows (a row-scoped
-    write is patched, a GC rebuilds), and the documented inclusive totals
-    (`merge_<fam>_seconds`, `merge_seconds_total`,
-    `flush_seconds_total`) still read above 0;
+    write is patched, a GC rebuilds), the engine's inclusive
+    `family_secs` still read above 0, and the INFO totals that overlapped
+    the stages are gone;
   * every counter a per-layer metric names — the nineteen in
     BENCHMARK.json (PR 37's four of the gather and the `reg` rows among
-    them) and the fifteen specs of docs/stage_layers/ — is an INFO key of a
+    them) and the nineteen specs of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
 """
 
 import asyncio
+import gc
 import glob
 import json
 import os
+import socket
 import sys
 import threading
 import time
@@ -42,7 +50,7 @@ from constdb_tpu.server.node import Node
 from constdb_tpu.server.serve import ServeCoalescer
 from constdb_tpu.store.keyspace import JOURNAL_FAMILIES, TOUCH_CAUSES
 from constdb_tpu.utils import stagetime
-from constdb_tpu.utils.stagetime import ANNOTATED, STAGES, StageClock
+from constdb_tpu.utils.stagetime import STAGES, StageClock, TimedSelector
 
 from cluster_util import FAST, Client
 from test_serve_coalesce import cmd, read_replies
@@ -59,6 +67,10 @@ GATHER_COUNTERS = ["serve_gather_passes", "serve_gather_msgs",
 # that returned a win vector / fell back to `src`, rows applied from vectors
 MICRO_COUNTERS = ["micro_win_scatters", "micro_src_scatters",
                   "micro_win_rows"]
+# the event loop's four metrics (PR 39), specified for every cell
+LOOP_SPECS = {"loop_poll_share.serve", "loop_cpu_share.serve",
+              "loop_preempt_per_kop.serve", "gc_pause_share.serve"}
+CELLS = ["ycsb-b", "ycsb-a", "aa-3node-ycsb-a", "memtier-default"]
 
 
 @pytest.fixture
@@ -178,45 +190,77 @@ def test_undeclared_stage_and_long_annotation_raise():
     clock = StageClock()
     with pytest.raises(ValueError, match="not declared"):
         clock.stage("spans")
-    spans = StageClock(annotation=lambda name: None)
-    with pytest.raises(ValueError, match="over 40"):
-        spans.stage("mirror_rebuild", "x" * 30)
+    # a tag that would cut the span's name raises with or without a trace
+    for spans in (clock, StageClock(trace=(lambda name: None, lambda: True))):
+        with pytest.raises(ValueError, match="over 40"):
+            spans.stage("mirror_rebuild", "x" * 30)
     assert all(len(f"cst.{name}.tns_read") <= stagetime.MAX_ANNOTATION
-               for name in ANNOTATED)
+               for name in STAGES)
 
 
-def test_only_per_flush_stages_open_trace_spans():
-    seen = []
+class Spans:
+    """A fake annotation factory and enabled check: what was opened and
+    closed, and a switch for "a trace runs"."""
 
-    class Span:
-        def __init__(self, name: str) -> None:
-            self.name = name
+    def __init__(self) -> None:
+        self.seen = []
+        self.on = False
 
-        def __enter__(self):
-            seen.append(("enter", self.name))
+    def __call__(self, name: str):
+        seen = self.seen
 
-        def __exit__(self, *exc) -> None:
-            seen.append(("exit", self.name))
+        class Span:
+            def __enter__(self):
+                seen.append(("enter", name))
 
-    clock = StageClock(annotation=Span)
+            def __exit__(self, *exc) -> None:
+                seen.append(("exit", name))
+        return Span()
+
+    def enabled(self) -> bool:
+        return self.on
+
+
+def test_every_stage_opens_its_span_only_while_a_trace_runs():
+    spans = Spans()
+    clock = StageClock(trace=(spans, spans.enabled))
+    for name in STAGES:
+        with clock.stage(name):
+            pass
+    assert spans.seen == []          # no trace: no annotation is built
+    spans.on = True
     for name in STAGES:
         with clock.stage(name):
             pass
     with pytest.raises(RuntimeError):
         with clock.stage("mirror_rebuild", "el"):
             raise RuntimeError
-    names = [n for what, n in seen if what == "enter"]
-    assert names == [f"cst.{n}" for n in STAGES if n in ANNOTATED] + \
-        ["cst.mirror_rebuild.el"]
-    assert seen.count(("exit", "cst.mirror_rebuild.el")) == 1
-    assert ANNOTATED == {"serve_flush", "stage_rows", "h2d", "dispatch",
-                         "host_twin", "mirror_rebuild", "mirror_patch",
-                         "state_alloc", "d2h_flush", "repl_flush"}
-    # without an annotation the same stages are counters like the others
+    names = [n for what, n in spans.seen if what == "enter"]
+    assert names == [f"cst.{n}" for n in STAGES] + ["cst.mirror_rebuild.el"]
+    assert spans.seen.count(("exit", "cst.mirror_rebuild.el")) == 1
+    # without the pair every stage is a counter only
     plain = StageClock()
     with plain.stage("d2h_flush", "el"):
         pass
     assert plain.snapshot()["d2h_flush"][1] == 1
+
+
+@pytest.mark.parametrize("name,tag", [
+    ("intake", ""), ("plan", ""), ("read_miss", ""), ("reply_write", ""),
+    ("repl_ingest", ""), ("loop_poll", ""),          # once a chunk or more
+    ("serve_flush", ""), ("d2h_flush", ""), ("mirror_patch", "reg"),
+    ("dispatch", "tns_read")])                        # once a flush or rarer
+def test_a_span_opens_only_when_the_enabled_check_is_true(name, tag):
+    spans = Spans()
+    clock = StageClock(trace=(spans, spans.enabled))
+    label = f"cst.{name}.{tag}" if tag else f"cst.{name}"
+    for on in (False, True, False):
+        spans.on = on
+        before = len(spans.seen)
+        with clock.stage(name, tag):
+            assert spans.seen[before:] == ([("enter", label)] if on else [])
+    assert spans.seen == [("enter", label), ("exit", label)]
+    assert clock.snapshot()[name][1] == 3     # counted every time
 
 
 def test_inclusive_totals_ride_the_same_clock(ticks):
@@ -240,6 +284,222 @@ def test_snapshot_lists_every_stage_in_whole_microseconds(ticks):
     assert clock.snapshot()["plan"] == (2, 1)
 
 
+# ------------------------------------------------ the event loop's clock
+
+
+@pytest.fixture
+def collector():
+    """Automatic collection off (only the test's own `gc.collect` runs
+    the hook), and the hook of every clock attached here taken off."""
+    clocks = []
+    was = gc.isenabled()
+    gc.disable()
+    yield clocks
+    for c in clocks:
+        c.detach_loop()
+    if was:
+        gc.enable()
+
+
+@pytest.mark.parametrize("rounds", [1, 4, 16])
+def test_loop_poll_is_one_entry_per_iteration_and_never_nested(rounds):
+    """A loop built by bin/server's factory over a socket pair: every
+    iteration polls once, inside `loop_poll`, with no stage open around
+    it; a byte written is a ready fd that poll returns."""
+    from constdb_tpu.bin.server import loop_factory
+    clock = StageClock()
+    sel = TimedSelector()
+    parents = []
+    epoll = sel._selector.inner
+
+    class Spy:
+        """The epoll object, noting the stack at each timed wait."""
+
+        def poll(self, *args):
+            if sel.clock is not None:
+                top = clock._tls.th.top
+                assert STAGES[top.i] == "loop_poll"
+                parents.append(top.parent)
+            return epoll.poll(*args)
+
+        def __getattr__(self, name):
+            return getattr(epoll, name)
+    sel._selector.inner = Spy()
+    loop = loop_factory(sel)()
+    a, b = socket.socketpair()
+    a.setblocking(False)
+    b.setblocking(False)
+    iterations = [0]
+    run_once = loop._run_once
+
+    def counted() -> None:
+        iterations[0] += 1
+        run_once()
+    loop._run_once = counted
+    got = []
+
+    async def main() -> None:
+        sel.watch(clock)
+        done = loop.create_future()
+
+        def readable() -> None:
+            got.append(b.recv(64))
+            with clock.stage("plan"):        # a stage inside a callback
+                pass
+            if len(got) == rounds:
+                done.set_result(None)
+            else:
+                loop.call_soon(a.send, b"x")
+        loop.add_reader(b.fileno(), readable)
+        a.send(b"x")
+        await done
+        loop.remove_reader(b.fileno())
+
+    try:
+        loop.run_until_complete(main())
+    finally:
+        clock.detach_loop()
+        loop.close()
+        a.close()
+        b.close()
+    snap = clock.snapshot()
+    # every poll after `watch` is one entry; the first iteration polled
+    # before the clock was handed over
+    assert snap["loop_poll"][1] == iterations[0] - 1 == len(parents)
+    assert parents and all(p is None for p in parents)
+    assert len(got) == rounds and snap["plan"][1] == rounds
+    assert clock.poll_events >= rounds
+    assert clock.loop_stats()[0] == ("loop_poll_events", clock.poll_events)
+
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_a_forced_collection_is_taken_out_of_the_stage_it_interrupts(
+        generation, ticks, collector):
+    spans = Spans()
+    spans.on = True
+    clock = StageClock(trace=(spans, spans.enabled))
+    clock.attach_loop()
+    collector.append(clock)
+    with clock.stage("plan"):                    # 1
+        with clock.stage("read_miss"):           # 2, exit 3
+            pass
+        gc.collect(generation)                   # gc 4 .. 5
+    # plan exit 6
+    got = raw(clock)
+    assert got["gc"] == (1, 1)
+    assert got["read_miss"] == (1, 1)
+    assert got["plan"] == (5 - 1 - 1, 1)         # neither child billed
+    assert sum(ns for ns, _ in got.values()) == 5     # = plan's wall time
+    # a young generation writes no span; the eldest writes cst.gc.2
+    opened = [n for what, n in spans.seen if what == "enter"]
+    assert opened.count("cst.gc.2") == (generation == 2)
+    assert not any(n in ("cst.gc.0", "cst.gc.1", "cst.gc") for n in opened)
+    clock.detach_loop()
+    gc.collect(generation)                       # no hook: nothing counted
+    assert raw(clock)["gc"] == (1, 1)
+
+
+def test_a_real_collection_pause_is_its_own_stage(collector):
+    """The real clock: a generation-2 collection over a heap of cycles
+    inside an open stage; the stage's self time leaves it out and the
+    self times sum to at most the wall time."""
+    clock = StageClock()
+    clock.attach_loop()
+    collector.append(clock)
+    n0 = clock.snapshot()["gc"][1]
+    t0 = time.perf_counter_ns()
+    with clock.stage("exec"):
+        junk = [[] for _ in range(200_000)]
+        for x in junk:
+            x.append(x)                  # cycles only a collection frees
+        del junk, x
+        t1 = time.perf_counter_ns()
+        gc.collect(2)
+        t2 = time.perf_counter_ns()
+    wall_ns = time.perf_counter_ns() - t0
+    got = raw(clock)
+    assert got["gc"][1] == n0 + 1
+    assert 0 < got["gc"][0] <= t2 - t1
+    assert got["exec"][0] <= wall_ns - got["gc"][0]
+    assert got["exec"][0] + got["gc"][0] <= wall_ns
+
+
+@pytest.mark.parametrize("engine", ["cpu", "device"])
+def test_loop_counters_are_in_info_from_boot_and_never_decrease(engine):
+    node = device_node()[0] if engine == "device" else Node(node_id=2)
+    fields = ["loop_poll_events", "loop_cpu_us", "loop_nvcsw",
+              "loop_nivcsw"] + [f"gc_collections_gen{g}" for g in range(3)]
+    first = info_of(node)
+    assert all(isinstance(first[k], int) and first[k] >= 0 for k in fields)
+    assert first["loop_poll_events"] == 0
+    assert first["span_loop_poll_n"] == first["span_gc_n"] == 0
+    # the thread that built the clock is the loop's until one attaches
+    t = time.thread_time()
+    while time.thread_time() - t < 0.02:
+        pass
+    time.sleep(0.01)                      # a voluntary switch
+    gc.collect(2)
+    second = info_of(node)
+    assert all(second[k] >= first[k] for k in fields)
+    assert second["loop_cpu_us"] >= first["loop_cpu_us"] + 10_000
+    assert second["gc_collections_gen2"] > first["gc_collections_gen2"]
+    if os.path.exists(f"/proc/self/task/{threading.get_native_id()}"):
+        assert second["loop_nvcsw"] > first["loop_nvcsw"]
+    # read from another thread, the loop thread's clocks are the same
+    out = {}
+    th = threading.Thread(target=lambda: out.update(info_of(node)))
+    th.start()
+    th.join(5)
+    assert out["loop_cpu_us"] >= second["loop_cpu_us"]
+    assert out["loop_cpu_us"] < second["loop_cpu_us"] + 10_000
+
+
+def test_served_loop_splits_its_window_into_stages_poll_and_the_rest(
+        tmp_path):
+    """A ServerApp on a loop built like bin/server's, one socket
+    exchange: Σ stage self times + `loop_poll` stay under the wall time
+    of the loop's thread, and so do its CPU time + `loop_poll`."""
+    from constdb_tpu.bin.server import loop_factory
+    sel = TimedSelector()
+
+    async def main():
+        node = Node(node_id=1)
+        sel.watch(node.stages)
+        app = await start_node(node, host="127.0.0.1", port=0,
+                               work_dir=str(tmp_path), serve_batch=512,
+                               **FAST)
+        c = await Client().connect(app.advertised_addr)
+        try:
+            t0 = time.perf_counter()
+            before = info_of(node)
+            chunk = [cmd(b"hset", b"h%d" % (i % 4), b"f%d" % i, b"v")
+                     for i in range(24)]
+            for _ in range(5):
+                c.writer.write(b"".join(encode_msg(m) for m in chunk))
+                await c.writer.drain()
+                await read_replies(c, bytearray(), len(chunk))
+                await asyncio.sleep(0.01)        # the loop waits in poll
+            after = info_of(node)
+            return before, after, (time.perf_counter() - t0) * 1e6
+        finally:
+            node.stages.detach_loop()
+            await c.close()
+            await app.close()
+
+    before, after, wall_us = asyncio.run(main(),
+                                         loop_factory=loop_factory(sel))
+
+    def moved(k: str) -> int:
+        return after[k] - before[k]
+    poll = moved("span_loop_poll_us")
+    stages = sum(moved(f"span_{s}_us") for s in STAGES if s != "loop_poll")
+    assert poll >= 5 * 10_000 * 0.9 and moved("span_loop_poll_n") >= 10
+    assert moved("loop_poll_events") >= 5
+    assert stages + poll <= wall_us
+    assert moved("loop_cpu_us") + poll <= wall_us * 1.01
+    assert moved("span_reply_write_n") >= 5
+
+
 # ------------------------------------------------------------------- INFO
 
 
@@ -252,9 +512,15 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
     want += LINK_COUNTERS + GATHER_COUNTERS + MICRO_COUNTERS
-    assert len(want) == 2 * 19 + 8 + 6 + 7 + 5 + 4 + 3
-    assert STAGES.index("gather") == 1 and "gather" not in ANNOTATED
+    want += ["loop_poll_events"]
+    assert len(want) == 2 * 21 + 8 + 6 + 7 + 5 + 4 + 3 + 1
+    assert STAGES.index("gather") == 1
+    assert STAGES[-2:] == ("loop_poll", "gc")
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
+    # the overlapping inclusive totals are gone (ROADMAP D9)
+    assert not [k for k in info if k.endswith("_seconds_total")
+                or (k.startswith("merge_") and k.endswith("_seconds"))
+                or k == "merge_rows_per_sec"]
     # a CPU-engine node has the clock, not the device engine's counters
     cpu = info_of(Node(node_id=2))
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
@@ -266,8 +532,13 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
 def test_node_adopts_the_engines_clock():
     node, eng = device_node()
     assert node.stages is eng.stages
-    assert eng.stages.annotation is not None     # jax's TraceAnnotation
-    assert Node(node_id=2).stages.annotation is None
+    import jax
+    ann = jax.profiler.TraceAnnotation
+    assert (eng.stages.annotation, eng.stages.tracing) == \
+        (ann, ann.is_enabled)
+    assert not eng.stages.tracing()              # no trace runs here
+    plain = Node(node_id=2).stages
+    assert plain.annotation is None and plain.tracing is None
 
 
 def test_pipelined_chunk_through_a_socket_moves_the_loop_stages(tmp_path):
@@ -433,7 +704,44 @@ def test_touch_keeps_the_last_cause_and_refuses_an_unknown_one():
     assert set(ks.fam_cause.values()) == {"reset"}
 
 
+def test_a_device_node_writes_its_stages_into_a_running_trace(
+        tmp_path, collector):
+    """The engine's pair is jax's: under a real profiler trace (JAX-CPU)
+    a per-chunk stage (`plan`) lands in the host plane beside the
+    per-flush ones, a generation-2 collection as `cst.gc.2`, a young
+    one not at all."""
+    import jax
+    from jax.profiler import ProfileData
+    node, _eng = device_node(warmup=0)
+    node.stages.attach_loop()
+    collector.append(node.stages)
+    sadd_round(node, 0)                  # first build, outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sadd_round(node, 6)
+        node.ensure_flushed()
+        gc.collect(2)
+        gc.collect(0)
+    finally:
+        jax.profiler.stop_trace()
+    sadd_round(node, 12)                 # after the trace: no annotation
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("cst.")]
+    assert {"cst.plan", "cst.serve_flush", "cst.d2h_flush",
+            "cst.gc.2"} <= set(names)
+    assert names.count("cst.plan") == 1 and names.count("cst.gc.2") == 1
+    assert not any(n.startswith(("cst.gc.0", "cst.gc.1")) for n in names)
+
+
 def test_documented_totals_still_read_above_zero():
+    """The engine's inclusive `family_secs` (bench.py reads them, ROADMAP
+    D1) still read above 0; INFO's overlapping totals are gone, and the
+    stage counters cover what they timed: a merge is the engine's
+    stages, a flush `d2h_flush`."""
     node, eng = device_node(warmup=0)
     sadd_round(node, 0)
     sadd_round(node, 6)
@@ -442,10 +750,19 @@ def test_documented_totals_still_read_above_zero():
     assert eng.family_secs["micro"] > 0 and eng.family_secs["flush"] > 0
     for field in ("merge_seconds_total", "flush_seconds_total",
                   "merge_micro_seconds", "merge_flush_seconds"):
-        assert info[field] > 0, field
-    # inclusive totals contain the stages under them
-    assert info["merge_flush_seconds"] * 1e6 >= info["span_d2h_flush_us"]
-    assert info["merge_seconds_total"] >= info["merge_micro_seconds"]
+        assert field not in info, field
+    assert not hasattr(node.stats, "secs")
+    # what merge_seconds_total timed: the engine's stages of a round
+    merge_us = sum(info[f"span_{s}_us"] for s in (
+        "stage_rows", "h2d", "dispatch", "host_twin", "mirror_rebuild",
+        "mirror_patch", "state_alloc"))
+    assert merge_us > 0 and info["span_dispatch_n"] >= 1
+    # what flush_seconds_total timed: d2h_flush, one entry a flush, and
+    # no longer than the inclusive total the engine keeps
+    assert info["span_d2h_flush_n"] >= 1 and info["span_d2h_flush_us"] > 0
+    assert info["span_d2h_flush_us"] <= eng.family_secs["flush"] * 1e6 + 1
+    assert merge_us <= (eng.family_secs["micro"]
+                        + eng.family_secs["flush"]) * 1e6 + 1
     assert not hasattr(eng, "stage_secs")        # replaced by stage_rows
     # the legacy whole-round host fallback (steady off) feeds `host`
     from constdb_tpu.engine.tpu import TpuMergeEngine
@@ -461,7 +778,7 @@ def test_documented_totals_still_read_above_zero():
 
 
 def layer_specs() -> list:
-    """Every per-layer metric BENCHMARK.json names, and the fifteen the
+    """Every per-layer metric BENCHMARK.json names, and the nineteen the
     stage counters are for (docs/stage_layers/: a `benchmark` PR moves
     them under benchmark/layers/ — see docs/stage_layers/README.md)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -497,8 +814,9 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    # the five of the replication link, the four of the gather (PR 37)
-    assert len(specs) == 10 + 5 + 4 + 15
+    # the five of the replication link, the four of the gather (PR 37);
+    # of docs/stage_layers/ fifteen, and the event loop's four (PR 39)
+    assert len(specs) == 10 + 5 + 4 + 15 + 4
     mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
     assert sorted(s["name"] for s in mine) == [
         "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
@@ -523,8 +841,8 @@ def benchmark_module(name: str):
 
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
-    """docs/stage_layers/overlay.py on a scratch copy: 15 files beside the
-    19, 15 entries at the END of per_layer, nothing else changed — and
+    """docs/stage_layers/overlay.py on a scratch copy: 19 files beside the
+    19, 19 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -538,7 +856,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     added = mod.overlay(str(tmp_path))
-    assert len(added) == 15 and mod.overlay(str(tmp_path)) == []
+    assert len(added) == 19 and mod.overlay(str(tmp_path)) == []
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -548,7 +866,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     assert [m["name"] for m in after["per_layer"][19:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 34
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 38
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
@@ -560,7 +878,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
             "compared": {"reads_wrong": {"value": 0, "limit": 0}}}
     assert validate.check_line(line, before, "ycsb-b", True) == []
     refused = validate.check_line(line, after, "ycsb-b", True)
-    assert len(refused) == 15 and all("is missing" in e for e in refused)
+    assert len(refused) == 19 and all("is missing" in e for e in refused)
 
 
 def test_stage_layer_specs_read_through_the_benchmarks_readers():
@@ -585,7 +903,8 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
         with open(path) as f:
             spec = json.load(f)
         assert os.path.basename(path) == spec["name"] + ".json"
-        assert spec["workloads"] == ["ycsb-b"]
+        assert spec["workloads"] == (CELLS if spec["name"] in LOOP_SPECS
+                                     else ["ycsb-b"])
         assert spec["moves"] == "served_ops"
         got[spec["name"]] = readers.read(spec, window, None, {})
     assert all(isinstance(v, float) for v in got.values()), got
@@ -599,9 +918,37 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
     # traced share less rebuilds
     traced_us = got["loop_traced_share.serve"] * 2.0 * 1e4
     rebuild_us = got["mirror_rebuild_stall_share.serve"] * 2.0 * 1e4
-    assert sum(per_op) * 24 == pytest.approx(traced_us - rebuild_us)
+    gc_us = got["gc_pause_share.serve"] * 2.0 * 1e4
+    assert sum(per_op) * 24 == pytest.approx(traced_us - rebuild_us - gc_us)
     # a node without the counters (the parent commit) reads nothing
     bare = dict(window, info_after={}, info_before={})
     with open(os.path.join(ROOT, "docs", "stage_layers",
                            "loop_traced_share.serve.json")) as f:
         assert readers.read(json.load(f), bare, None, {}) is None
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("loop_poll_share.serve", 100 * 0.5 / 2.0),       # 0.5 s of a 2 s window
+    ("loop_cpu_share.serve", 100 * 1.2 / 2.0),
+    ("loop_preempt_per_kop.serve", 1000 * 30 / 24_000),
+    ("gc_pause_share.serve", 100 * 0.01 / 2.0),
+    ("loop_traced_share.serve", 100 * (0.3 + 0.01) / 2.0)])
+def test_loop_specs_read_their_counters(name, expected):
+    """Each event-loop spec through `readers.read` over a window whose
+    deltas are known: 2 s, 24,000 operations."""
+    readers = benchmark_module("readers")
+    with open(os.path.join(ROOT, "docs", "stage_layers",
+                           f"{name}.json")) as f:
+        spec = json.load(f)
+    before = {f"span_{s}_us": 1_000 for s in STAGES}
+    before.update(loop_cpu_us=5, loop_nivcsw=7)
+    after = dict(before, span_loop_poll_us=501_000, span_gc_us=11_000,
+                 span_plan_us=301_000, loop_cpu_us=1_200_005,
+                 loop_nivcsw=37)
+    window = {"info_before": before, "info_after": after, "ops": 24_000,
+              "kops": 24.0, "seconds": 2.0}
+    assert readers.read(spec, window, None, {}) == pytest.approx(expected)
+    # the parent commit's INFO has none of them: nothing is read
+    bare = {"info_before": {}, "info_after": {}, "ops": 24_000,
+            "kops": 24.0, "seconds": 2.0}
+    assert readers.read(spec, bare, None, {}) is None
