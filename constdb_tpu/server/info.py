@@ -20,6 +20,7 @@ import numpy as np
 from ..crdt import semantics as S
 from ..resp.message import Bulk
 from .commands import CMD_READONLY, register
+from .reply_pump import COUNTERS as REPLY_COUNTERS
 
 
 def _section_server(node, out):
@@ -184,6 +185,15 @@ def _section_stats(node, out):
     out.append(("serve_gather_msgs", st.serve_gather_msgs))
     out.append(("serve_gather_conns", st.serve_gather_conns))
     out.append(("serve_lone_cmds", st.serve_lone_cmds))
+    # the reply sender (server/reply_pump.py): replies and bytes handed
+    # to its thread, replies written to a transport instead, sends that
+    # would have blocked and were handed back (spills), wake-ups of the
+    # parked thread, and the thread's time inside send() — 0 from boot,
+    # and where the extension does not load
+    pump = getattr(getattr(node, "app", None), "reply_pump", None)
+    reply = dict(pump.counters()) if pump is not None else {}
+    reply["reply_transport_writes"] = st.reply_transport_writes
+    out.extend((name, reply.get(name, 0)) for name in REPLY_COUNTERS)
     rc = node.read_cache
     x = st.extra
     rc_bytes = rc.used_bytes() + sum(

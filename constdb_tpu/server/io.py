@@ -48,9 +48,11 @@ class _PassGather:
     (`hand_over`); the first hand-over of a pass schedules `_run_pass`
     with `loop.call_soon`, so every task the same `select()` woke has
     had its turn before it runs.  The pass plans everything handed over
-    as ONE chunk, in hand-over order, cuts the replies at the
-    connections' boundaries and wakes each task with its slice; the
-    task writes and drains its own socket.  A pass of one message is the
+    as ONE chunk, in hand-over order, and cuts the replies at the
+    connections' boundaries.  The slices of connections on the reply
+    sender (server/reply_pump.py) go to it in ONE call, and their tasks
+    wake with nothing to write; every other task wakes with its slice,
+    and writes and drains its own socket.  A pass of one message is the
     lone command on the exact per-command path; a pass of one
     connection's pipeline is the chunk that connection used to run
     alone.
@@ -69,7 +71,9 @@ class _PassGather:
         self.app = app
         self.node = app.node
         self.coal = coal
-        self.segs: list = []     # (ops | None, payloads, future)
+        # (ops | None, payloads, future, ClientConn | None): the client
+        # where its slice may go to the reply sender
+        self.segs: list = []
         self.scheduled = False
         self.loop = None         # the serving loop, from the first hand-over
         self.stage = app.node.stages.stage
@@ -95,15 +99,16 @@ class _PassGather:
         finally:
             coal.client = None
 
-    def hand_over(self, ops, payloads) -> "asyncio.Future":
+    def hand_over(self, ops, payloads, client=None) -> "asyncio.Future":
         """One connection's messages of this pass -> the future of its
-        reply bytes."""
+        reply bytes, or of None where the pass handed them to the reply
+        sender (only for a `client` given, on the sender at the pass)."""
         with self.stage("gather"):
             loop = self.loop
             if loop is None:
                 loop = self.loop = asyncio.get_running_loop()
             fut = loop.create_future()
-            self.segs.append((ops, payloads, fut))
+            self.segs.append((ops, payloads, fut, client))
             if not self.scheduled:
                 self.scheduled = True
                 loop.call_soon(self._run_pass)
@@ -115,7 +120,7 @@ class _PassGather:
         with self.stage("gather"):
             st = self.node.stats
             if len(segs) == 1:
-                ops, payloads, _ = segs[0]
+                ops, payloads = segs[0][:2]
                 solo = None
             else:
                 payloads = []
@@ -146,33 +151,43 @@ class _PassGather:
                 log.exception("a gathered chunk raised")
                 self._fail(segs, e)
                 return
-            # cut the replies at the connections' boundaries
+            # the connections' boundaries in the replies
             if len(segs) == 1:
-                cuts = [out]
+                ends = [len(out)]
             else:
-                cuts, a, i = [], 0, 0
+                ends, i = [], 0
                 for seg in segs:
                     i += len(seg[1])
-                    b = spans[i - 1]
-                    cuts.append(out[a:b])
-                    a = b
+                    ends.append(spans[i - 1])
             oplog = self.node.oplog
             if oplog is not None and oplog.ack_barrier_needed:
                 # fsync=always: ONE group commit covers the pass, and
                 # the replies leave after it
-                t = asyncio.ensure_future(self._wake_after_barrier(segs,
-                                                                   cuts))
+                t = asyncio.ensure_future(self._wake_after_barrier(
+                    segs, out, ends))
                 self.barriers.add(t)
                 t.add_done_callback(self.barriers.discard)
             else:
-                self._wake(segs, cuts)
+                self._wake(segs, out, ends)
 
-    @staticmethod
-    def _wake(segs: list, cuts: list) -> None:
-        for seg, cut in zip(segs, cuts):
-            fut = seg[2]
-            if not fut.done():   # its task was cancelled: only its own
-                fut.set_result(cut)  # replies are lost
+    def _wake(self, segs: list, out: bytearray, ends: list) -> None:
+        """Cut the replies: the slices of connections on the reply sender
+        go to it in one call; every other task wakes with its own."""
+        ids = []
+        a = 0
+        for (_, _, fut, client), b in zip(segs, ends):
+            if fut.done():   # its task was cancelled: only its own
+                ids.append(0)  # replies are lost
+            elif client is not None and client.on_pump:
+                ids.append(client.reply_id)
+                fut.set_result(None)
+            else:
+                ids.append(0)
+                fut.set_result(out if len(segs) == 1 else out[a:b])
+            a = b
+        if any(ids):
+            with self.stage("reply_write"):
+                self.app.reply_pump.post(out, ids, ends)
 
     @staticmethod
     def _fail(segs: list, exc: Exception) -> None:
@@ -180,14 +195,15 @@ class _PassGather:
             if not seg[2].done():
                 seg[2].set_exception(exc)
 
-    async def _wake_after_barrier(self, segs: list, cuts: list) -> None:
+    async def _wake_after_barrier(self, segs: list, out: bytearray,
+                                  ends: list) -> None:
         try:
             await self.app._aof_ack_barrier()
         except BaseException:
             # no commit, no acknowledgement: the waiting connections end
             self._fail(segs, ConnectionError("the group commit failed"))
             raise
-        self._wake(segs, cuts)
+        self._wake(segs, out, ends)
 
 
 class ServerApp:
@@ -451,6 +467,10 @@ class ServerApp:
         # the loop-pass gather and the node's one coalescer (_PassGather);
         # built by start() where the in-loop coalescer serves
         self._gather: Optional[_PassGather] = None
+        # the extension's reply sender (server/reply_pump.py); started by
+        # start() where the extension loads and chunks are gathered or
+        # routed, stopped and joined by close()
+        self.reply_pump = None
         # awaited by start() AFTER the serve plane is up but BEFORE the
         # listener opens — the sharded boot restore (start_node) runs
         # here so a reconnecting peer can never observe the un-fenced
@@ -537,6 +557,14 @@ class ServerApp:
                 self.node.cluster.my_gid, self.advertised_addr)
         if self._boot_restore is not None:
             await self._boot_restore()
+        if self._gather is not None or self.serve_plane is not None:
+            from ..utils.native_tables import load_ext
+            ext = load_ext()
+            if ext is not None:
+                from .reply_pump import ReplyPump
+                self.reply_pump = ReplyPump(ext, self.node.stats,
+                                            self._outbuf_overflow)
+                self.reply_pump.start(asyncio.get_running_loop())
         await self._server.start_serving()
         self._cron_task = asyncio.create_task(self._cron())
         # reconnect links for membership restored from a snapshot
@@ -576,6 +604,10 @@ class ServerApp:
                 await m.link.stop()
         if self.serve_plane is not None:
             await self.serve_plane.close()
+        if self.reply_pump is not None:
+            # every connection's task has ended and released its
+            # connection: nothing is left for the thread to send
+            self.reply_pump.close()
         if self.node.oplog is not None:
             # final group commit + close (policy `no` drains without
             # forcing an fsync — that is its contract)
@@ -678,6 +710,14 @@ class ServerApp:
         client = ClientConn(self._next_cid, addr, writer,
                             created=time.time())
         self.client_conns[client.cid] = client
+        pump = self.reply_pump
+        if pump is not None:
+            sock = writer.get_extra_info("socket")
+            if sock is not None:
+                try:
+                    pump.open(client, sock)
+                except OSError:  # no descriptor to spare: the transport
+                    pass         # writes this connection
         try:
             # bound the transport's userspace reply buffer: drain()
             # engages at the high-water mark, so one connection's
@@ -772,9 +812,12 @@ class ServerApp:
                         out = await self._run_chunk(plane, gather, seg, out,
                                                     client)
                     if sync_at >= 0:
-                        # the replies before the SYNC leave first, then
-                        # the handshake reply takes over the stream
+                        # the replies before the SYNC leave first (what
+                        # the reply sender held, then `out`), then the
+                        # handshake reply takes over the stream
                         await self._aof_ack_barrier()
+                        if pump is not None:
+                            pump.release(client)
                         out = self._flush_out(writer, out)
                         self._upgrade_to_replica(syn, reader, writer,
                                                  parser)
@@ -788,7 +831,7 @@ class ServerApp:
                     # gather awaits it before it wakes its connections),
                     # riding the coalescer's end-of-chunk flush barrier
                     await self._aof_ack_barrier()
-                    out = self._flush_out(writer, out)
+                    out = self._flush_out(writer, out, client)
                     if self._outbuf_overflow(writer):
                         return  # disconnected loudly; finally cleans up
                     await writer.drain()
@@ -799,7 +842,11 @@ class ServerApp:
             # `out` for earlier completed commands must still reach the
             # client (dropping them desyncs its pipeline accounting), and
             # messages that parsed cleanly before the bad frame still
-            # execute (the parser stashed them — take_queued)
+            # execute (the parser stashed them — take_queued).  The
+            # connection ends here, so its replies take the transport
+            # from now on, behind what the reply sender held
+            if pump is not None:
+                pump.release(client)
             try:
                 salvaged = parser.take_queued()
                 sync_at = next((i for i, m in enumerate(salvaged)
@@ -844,6 +891,10 @@ class ServerApp:
             # the moment it can no longer deliver pushes on it
             if client.tracking:
                 self.node.tracking.unsubscribe(client)
+            if pump is not None:
+                # what the sender still holds goes to the transport, which
+                # flushes it before the FIN of the close below
+                pump.release(client)
             client.writer = None
             self.client_conns.pop(client.cid, None)
             # an upgraded connection is owned by its replica link now
@@ -885,7 +936,11 @@ class ServerApp:
         elif gather.keeps_own_path(client, ops, payloads):
             gather.run_alone(client, ops, payloads, out)
         else:
-            replies = await gather.hand_over(ops, payloads)
+            # an empty `out` may go straight to the reply sender
+            replies = await gather.hand_over(ops, payloads,
+                                             None if out else client)
+            if replies is None:     # the pass handed them to the sender
+                return out
             if out:
                 out += replies
             else:
@@ -919,19 +974,26 @@ class ServerApp:
         tr.abort()
         return True
 
-    def _flush_out(self, writer, out: bytearray) -> bytearray:
-        """Queue accumulated replies on the transport and return a fresh
-        buffer.  Buffer SWAP instead of bytes(out): ownership moves to
+    def _flush_out(self, writer, out: bytearray, client=None) -> bytearray:
+        """Hand accumulated replies to the connection's path and return a
+        fresh buffer: the reply sender where `client` is on it
+        (server/reply_pump.py write: a copy into its queue), else the
+        transport.  Buffer SWAP instead of bytes(out): ownership moves to
         the transport (which copies only what it cannot send
         immediately) — no reply-buffer copy per chunk.  Also used before
         a SYNC upgrade takes the stream over, so pipelined-before-SYNC
         replies are not dropped."""
         if out:
-            # the write only: the `await writer.drain()` that follows at
-            # the call sites is outside the stage
+            # the hand-over or the write only: the `await writer.drain()`
+            # that follows at the call sites is outside the stage
             with self.node.stages.stage("reply_write"):
-                self.node.stats.net_out_bytes += len(out)
-                writer.write(out)
+                if client is not None and client.reply_id:
+                    self.reply_pump.write(client, out)
+                else:
+                    st = self.node.stats
+                    st.net_out_bytes += len(out)
+                    st.reply_transport_writes += 1
+                    writer.write(out)
             out = bytearray()
         return out
 

@@ -134,6 +134,10 @@ class NodeStats:
     serve_gather_msgs: int = 0
     serve_gather_conns: int = 0
     serve_lone_cmds: int = 0
+    # replies written to a client's transport (server/io.py _flush_out,
+    # server/reply_pump.py): beside the reply sender's own counters, the
+    # share of replies the sender took is posts / (posts + these)
+    reply_transport_writes: int = 0
     serve_lat: deque = field(default_factory=lambda: deque(maxlen=2048))
     # overload governance (server/overload.py + server/io.py +
     # replica/link.py): client data writes shed at the maxmemory soft

@@ -90,7 +90,8 @@ class ClientConn:
     on."""
 
     __slots__ = ("cid", "addr", "writer", "resp3", "tracking", "prefixes",
-                 "tracked", "pend", "_timer", "created")
+                 "tracked", "pend", "_timer", "created", "reply_id",
+                 "on_pump")
 
     def __init__(self, cid: int, addr: str, writer=None, created=0.0):
         self.cid = cid
@@ -103,6 +104,10 @@ class ClientConn:
         self.pend: dict = {}        # pending invalidation keys (ordered)
         self._timer = None          # armed latency-bound flush handle
         self.created = created
+        # the reply sender's id for this connection (0: none), and whether
+        # its replies go through the sender now (server/reply_pump.py)
+        self.reply_id = 0
+        self.on_pump = False
 
     def describe(self) -> str:
         mode = {TRACK_OFF: "off", TRACK_DEFAULT: "on",
@@ -382,6 +387,9 @@ class TrackingRegistry:
                 client.describe(), tr.get_write_buffer_size(), cap)
             tr.abort()
             return False
+        if client.on_pump:
+            # the push must not overtake replies the sender still holds
+            self.node.app.reply_pump.to_transport(client)
         try:
             w.write(payload)
         except (ConnectionError, RuntimeError):

@@ -18,6 +18,7 @@
 #include "intake.cpp"  // pipelined-command intake engine (py_intake_scan)
 #include "wire.cpp"    // REPLBATCH blob columns (py_wire_{pack,unpack}_blobs)
 #include "aof.cpp"     // AOF segment record scanner + frame decode (py_aof_scan)
+#include "reply.cpp"   // reply sender thread (py_reply_*)
 
 // Build stamp over native/*.cpp, generated by the Makefile into
 // build/abi_stamp.h.  utils/native_tables.py recomputes the hash from
@@ -361,6 +362,24 @@ PyMethodDef methods[] = {
      " raw]) -> (records, valid_pos); frame records pre-decode to"
      " (2, origin, uuid, name, args) when the classes are given; raw=1"
      " decodes flat all-bulk command arrays to plain bytes args"},
+    {"reply_new", py_reply_new, METH_NOARGS,
+     "reply_new() -> reply sender (server/reply_pump.py)"},
+    {"reply_start", py_reply_start, METH_VARARGS,
+     "reply_start(sender) -> eventfd the loop reads for spilled replies"},
+    {"reply_stop", py_reply_stop, METH_VARARGS,
+     "reply_stop(sender): stop and join the sender thread"},
+    {"reply_open", py_reply_open, METH_VARARGS,
+     "reply_open(sender, fd) -> connection id (the sender dups fd)"},
+    {"reply_post", py_reply_post, METH_VARARGS,
+     "reply_post(sender, buf, ids, ends) -> bytes handed to the sender"},
+    {"reply_detach", py_reply_detach, METH_VARARGS,
+     "reply_detach(sender, id, release) -> bytes the sender held | None"},
+    {"reply_resume", py_reply_resume, METH_VARARGS,
+     "reply_resume(sender, id) -> None (resumed) | bytes still handed back"},
+    {"reply_take_spills", py_reply_take_spills, METH_VARARGS,
+     "reply_take_spills(sender) -> [(id, bytes)] handed back to the loop"},
+    {"reply_stats", py_reply_stats, METH_VARARGS,
+     "reply_stats(sender) -> (posts, bytes, wakes, spills, send_us)"},
     {"abi_stamp", py_abi_stamp, METH_NOARGS,
      "abi_stamp() -> sha256 over the native/*.cpp sources this .so was "
      "built from"},

@@ -71,6 +71,9 @@ MICRO_COUNTERS = ["micro_win_scatters", "micro_src_scatters",
 LOOP_SPECS = {"loop_poll_share.serve", "loop_cpu_share.serve",
               "loop_preempt_per_kop.serve", "gc_pause_share.serve"}
 CELLS = ["ycsb-b", "ycsb-a", "aa-3node-ycsb-a", "memtier-default"]
+# the reply write's three, in every cell since the reply sender
+REPLY_SPECS = {"reply_write_us_per_op.serve", "reply_pump_share.serve",
+               "reply_sender_busy_share.serve"}
 
 
 @pytest.fixture
@@ -815,8 +818,9 @@ def test_every_counter_a_layer_file_names_is_in_info():
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
     # the five of the replication link, the four of the gather (PR 37);
-    # of docs/stage_layers/ fifteen, and the event loop's four (PR 39)
-    assert len(specs) == 10 + 5 + 4 + 15 + 4
+    # of docs/stage_layers/ fifteen, the event loop's four and the reply
+    # sender's two
+    assert len(specs) == 10 + 5 + 4 + 15 + 4 + 2
     mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
     assert sorted(s["name"] for s in mine) == [
         "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
@@ -841,8 +845,8 @@ def benchmark_module(name: str):
 
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
-    """docs/stage_layers/overlay.py on a scratch copy: 19 files beside the
-    19, 19 entries at the END of per_layer, nothing else changed — and
+    """docs/stage_layers/overlay.py on a scratch copy: 21 files beside the
+    19, 21 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -856,7 +860,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     added = mod.overlay(str(tmp_path))
-    assert len(added) == 19 and mod.overlay(str(tmp_path)) == []
+    assert len(added) == 21 and mod.overlay(str(tmp_path)) == []
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -866,7 +870,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     assert [m["name"] for m in after["per_layer"][19:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 38
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 40
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
@@ -878,7 +882,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
             "compared": {"reads_wrong": {"value": 0, "limit": 0}}}
     assert validate.check_line(line, before, "ycsb-b", True) == []
     refused = validate.check_line(line, after, "ycsb-b", True)
-    assert len(refused) == 19 and all("is missing" in e for e in refused)
+    assert len(refused) == 21 and all("is missing" in e for e in refused)
 
 
 def test_stage_layer_specs_read_through_the_benchmarks_readers():
@@ -903,10 +907,14 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
         with open(path) as f:
             spec = json.load(f)
         assert os.path.basename(path) == spec["name"] + ".json"
-        assert spec["workloads"] == (CELLS if spec["name"] in LOOP_SPECS
-                                     else ["ycsb-b"])
+        assert spec["workloads"] == (
+            CELLS if spec["name"] in LOOP_SPECS | REPLY_SPECS
+            else ["ycsb-b"])
         assert spec["moves"] == "served_ops"
         got[spec["name"]] = readers.read(spec, window, None, {})
+    # no reply left this node by a socket: the sender's share has nothing
+    # to read (test_loop_specs_read_their_counters reads it)
+    assert got.pop("reply_pump_share.serve") is None
     assert all(isinstance(v, float) for v in got.values()), got
     per_op = [v for k, v in got.items() if k.endswith("_us_per_op.serve")]
     assert len(per_op) == 10
@@ -928,23 +936,27 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
 
 
 @pytest.mark.parametrize("name,expected", [
+    ("reply_pump_share.serve", 100 * 2_970 / 3_000),  # 30 transport writes
+    ("reply_sender_busy_share.serve", 100 * 0.8 / 2.0),
     ("loop_poll_share.serve", 100 * 0.5 / 2.0),       # 0.5 s of a 2 s window
     ("loop_cpu_share.serve", 100 * 1.2 / 2.0),
     ("loop_preempt_per_kop.serve", 1000 * 30 / 24_000),
     ("gc_pause_share.serve", 100 * 0.01 / 2.0),
     ("loop_traced_share.serve", 100 * (0.3 + 0.01) / 2.0)])
 def test_loop_specs_read_their_counters(name, expected):
-    """Each event-loop spec through `readers.read` over a window whose
-    deltas are known: 2 s, 24,000 operations."""
+    """Each event-loop and reply-sender spec through `readers.read` over a
+    window whose deltas are known: 2 s, 24,000 operations."""
     readers = benchmark_module("readers")
     with open(os.path.join(ROOT, "docs", "stage_layers",
                            f"{name}.json")) as f:
         spec = json.load(f)
     before = {f"span_{s}_us": 1_000 for s in STAGES}
-    before.update(loop_cpu_us=5, loop_nivcsw=7)
+    before.update(loop_cpu_us=5, loop_nivcsw=7, reply_pump_posts=30,
+                  reply_transport_writes=0, reply_pump_send_us=100)
     after = dict(before, span_loop_poll_us=501_000, span_gc_us=11_000,
                  span_plan_us=301_000, loop_cpu_us=1_200_005,
-                 loop_nivcsw=37)
+                 loop_nivcsw=37, reply_pump_posts=3_000,
+                 reply_transport_writes=30, reply_pump_send_us=800_100)
     window = {"info_before": before, "info_after": after, "ops": 24_000,
               "kops": 24.0, "seconds": 2.0}
     assert readers.read(spec, window, None, {}) == pytest.approx(expected)

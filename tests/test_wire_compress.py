@@ -304,7 +304,8 @@ def test_info_broadcast_gauges(tmp_path):
     """Satellite: per-peer wire observability — replica<i> rows carry
     bytes_out / compressed_ratio / cache counts, and the node-level
     encode-cache + compression gauges ride the stats section."""
-    from cluster_util import Client, close_cluster, converge, make_cluster
+    from cluster_util import (Client, close_cluster, converge, full_mesh,
+                              make_cluster)
     from constdb_tpu.resp.codec import encode_msg
 
     async def main():
@@ -314,6 +315,10 @@ def test_info_broadcast_gauges(tmp_path):
             c = await Client().connect(apps[0].advertised_addr)
             await c.cmd("meet", apps[1].advertised_addr)
             await c.cmd("meet", apps[2].advertised_addr)
+            # both push loops stream before the run lands (a write that
+            # beats a link's full sync reaches that peer in the snapshot,
+            # and there is no second reader to share an encoding with)
+            await full_mesh(apps)
             # a pipelined chunk logs one consecutive run, so BOTH push
             # loops drain the same cursor range (encode-once food)
             buf = bytearray()
