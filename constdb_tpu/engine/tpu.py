@@ -226,6 +226,14 @@ class TpuMergeEngine:
     # mirror can go stale (_warm_patch); a journal over the largest falls
     # back to the whole-plane rebuild
     MIRROR_PATCH_BUCKETS = (1 << 10, 1 << 13, JOURNAL_MAX_ROWS)
+    # the least capacity of a family a micro round has grown (a table that
+    # grows under load): every new capacity loads its own programs (the
+    # grow, the scatter, the patch, the split: seven or eight), and on a
+    # TPU v5e those loads hold the loop 0.2-0.3 s at each capacity, so a
+    # table that starts near empty jumps to this at its first grow — 3 MB
+    # of `el` stamp planes — instead of doubling through each power of two
+    # below it while it serves.  Past it, each doubling still pays them
+    GROW_FLOOR = 1 << 17
     # staging order = dispatch order = the on-store plane contract
     FAM_ORDER = ("env", "reg", "cnt", "el")
 
@@ -299,6 +307,12 @@ class TpuMergeEngine:
         # ... and what invalidated the mirror each rebuild replaced (the
         # family's last KeySpace.touch cause)
         self.mirror_rebuild_causes = dict.fromkeys(TOUCH_CAUSES, 0)
+        # ... and the planes grown in place as the host table passed their
+        # capacity (INFO mirror_grows_<fam>; not rebuilds: nothing stale)
+        self.mirror_grows = dict.fromkeys(FAMILIES, 0)
+        # the families a micro round grew: tables that grow under load,
+        # whose planes keep GROW_FLOOR rows from then on
+        self._growing: set = set()
         # ... and the stale mirrors repaired in place instead: patches and
         # the distinct rows they scattered, per journaled family, and the
         # rebuilds taken because a journal outgrew the largest bucket
@@ -1192,7 +1206,8 @@ class TpuMergeEngine:
 
     # ------------------------------------------------------ resident state
 
-    def _resident_state(self, store: KeySpace, fam: str, n: int):
+    def _resident_state(self, store: KeySpace, fam: str, n: int,
+                        micro: bool = False):
         """Device state dict for family `fam` covering rows [0, n); grows
         (neutral-filled) as the host table grows.  Returns (cols, cap).
 
@@ -1208,8 +1223,14 @@ class TpuMergeEngine:
         `res["cols"]` is the only copy of a family's planes, each an
         ops/bulk.py `Plane` — (hi int32, lo uint32), never an int64 array:
         bulk rounds, micro rounds, the grow path and the patch all read
-        and replace the same pairs."""
+        and replace the same pairs.  `micro`: the caller is a micro round,
+        whose grow marks the family as growing under load: its planes keep
+        at least GROW_FLOOR rows from then on, rebuilt or grown.  (A boot
+        restore's bulk rounds grow a plane once, to the size its table
+        keeps: no floor.)"""
         res = self._res.get(fam)
+        if micro and res is not None and n > res["cap"]:
+            self._growing.add(fam)   # rebuilt or grown, past its planes
         ver = store.fam_ver[fam]
         stale = res is not None and res.get("ver") != ver
         journal = store.journal.get(fam) if self._mesh is None else None
@@ -1238,7 +1259,8 @@ class TpuMergeEngine:
                 self.mirror_rebuilds[fam] += 1
                 self.mirror_rebuild_causes[store.fam_cause[fam]] += 1
                 res = None
-        cap = self._sp_size(n)
+        cap = self._sp_size(max(n, self.GROW_FLOOR) if fam in self._growing
+                            else n)
         spec = _FAMILIES[fam]
         if res is None:
             # a whole-plane upload (first build or stale rebuild)
@@ -1255,6 +1277,7 @@ class TpuMergeEngine:
         elif n > res["cap"]:
             old = res["cols"]
             delta = cap - res["cap"]
+            self.mirror_grows[fam] += 1
             with self.stages.stage("mirror_rebuild", fam):
                 if fam == "env":
                     cols = {"stack": self._grow(old["stack"], delta, 0)}
@@ -1662,7 +1685,7 @@ class TpuMergeEngine:
         plane belongs to its value pair) always takes the win vector."""
         nw = len(wr)
         n = _fam_rows(store, fam)
-        cols, sp = self._resident_state(store, fam, n)
+        cols, sp = self._resident_state(store, fam, n, micro=True)
         res = self._res[fam]
         pcol, scol = pair
         # pad-floor the batch length (see MICRO_SCATTER_PAD); a batch

@@ -13,7 +13,7 @@ Replies leave through `encode_into` (a Msg tree: the per-command path) or,
 for the read planner's misses (server/serve.py _read_misses), through the
 direct encoders that build no tree: `scan_replier` for SMEMBERS / HGETALL
 (one native pass from the key's row list to the reply bytes),
-`encode_rows_into` for LRANGE (rows already gathered and sorted),
+`encode_rows_into` for LRANGE (rows already read from the list's index),
 `bulk_reply` / `int_reply` for the single-value kinds.  Each has a native
 pass in native/resp.cpp and a bit-identical pure twin here.
 """
@@ -112,35 +112,24 @@ def encode_msg(m: Msg) -> bytes:
 
 # row-reply kinds -> the codes both tiers take (native/resp.cpp
 # resp_encode_rows, resp_scan_reply); an LRANGE reaches them as "values"
-# over its sorted, sliced rows
+# over its range's rows in list order
 _ROW_KINDS = {"members": 0, "pairs": 1, "values": 2}
 
 
 def encode_rows_into(out: bytearray, kind: str, rows: list, el_member: list,
-                     el_val: list, start: int = 0, stop: int = -1) -> bytes:
+                     el_val: list) -> bytes:
     """Append the reply of one planned row-scan read straight from the
     element blob planes — the bytes `encode_into` gives for the Msg tree
     of the per-command handler (server/commands.py), with no tree built —
     and return the appended payload, which the reply cache stores as is.
-    `rows`: the key's live element rows (KeySpace.elem_live_rows_batch).
-    `kind` is the read's SERVE_READS kind: "members" (SMEMBERS: one bulk
-    per member), "pairs" (HGETALL: `*2` of member and value per row) or
-    "lrange" (the handler's order and inclusive `start`..`stop` slice,
-    then one bulk per value); a None value is the empty bulk.  Native
-    pass when the extension has it, bit-identical pure twin otherwise
-    (and for any shape the C pass declines)."""
+    `rows`: the element rows the reply lists, in its order.  `kind` is the
+    read's SERVE_READS kind: "members" (SMEMBERS: one bulk per member),
+    "pairs" (HGETALL: `*2` of member and value per row) or "lrange" (one
+    bulk per value, `rows` the range's rows in list order —
+    commands.list_range); a None value is the empty bulk.  Native pass
+    when the extension has it, bit-identical pure twin otherwise (and for
+    any shape the C pass declines)."""
     code = _ROW_KINDS["values" if kind == "lrange" else kind]
-    if kind == "lrange":
-        # the handler sorts (position, value) pairs; live positions of
-        # one list are distinct, so the position alone orders them
-        n = len(rows)
-        if start < 0:
-            start += n
-        if stop < 0:
-            stop += n
-        start = max(0, start)
-        rows = sorted(rows, key=el_member.__getitem__)[start:stop + 1] \
-            if stop >= start else []
     enc = _enc_rows()
     if enc is not None:
         payload = enc(out, code, rows, el_member, el_val)

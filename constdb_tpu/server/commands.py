@@ -36,6 +36,7 @@ import threading
 from typing import Callable, Optional, TYPE_CHECKING
 
 from ..crdt import semantics as S
+from ..crdt.sequence import pos_between_bytes
 from ..errors import (CstError, InvalidRequestMsg, UnknownCmd, UnknownSubCmd,
                       WrongArity)
 from ..resp.message import (Arr, Bulk, Err, Int, Msg, NIL, NO_REPLY, OK,
@@ -1046,13 +1047,9 @@ def delmv_command(node, ctx, args):
 # list commands (capability completion: the reference scaffolds an ordered
 # list — src/crdt/list.rs — wired to nothing.  Entries live as element rows
 # whose member bytes are LSEQ position ids; byte-lex member order IS list
-# order, so reads sort live members and merges are the element merge.)
+# order, so merges are the element merge, and reads walk the key's ordered
+# index (store/keyspace.py ListIndex) instead of sorting its rows.)
 # ====================================================================
-
-def _list_live(ks, kid) -> list:
-    """[(pos_bytes, value)] in list order."""
-    return sorted((m, v) for m, v, _t in ks.elem_live(kid))
-
 
 def _list_kid(node, ctx, key, for_write: bool):
     ks = node.ks
@@ -1067,31 +1064,43 @@ def _list_kid(node, ctx, key, for_write: bool):
     return kid
 
 
+def list_positions(lo, hi, n: int, nodeid: int, stats) -> list:
+    """`n` fresh serialized positions in order between `lo` and `hi`
+    (None: the list's edge), each after the one before, counted in
+    `list_inserts` / `list_pos_bytes_sum`.  The per-command push and the
+    planned one (`_plan_push`) both draw theirs here."""
+    out = []
+    for _ in range(n):
+        lo = pos_between_bytes(lo, hi, nodeid)
+        out.append(lo)
+    stats.list_inserts += n
+    stats.list_pos_bytes_sum += sum(map(len, out))
+    return out
+
+
 def _list_insert(node, ctx, key, index: int, values: list) -> int:
     """Insert `values` before live index `index` (clamped); returns the new
     live length.  Each insert replicates as the positional `lins`."""
-    from ..crdt.sequence import pos_between_bytes
-
     ks = node.ks
     kid = _list_kid(node, ctx, key, for_write=True)
-    live = _list_live(ks, kid)
-    index = max(0, min(index, len(live)))
-    lo = live[index - 1][0] if index > 0 else None
-    hi = live[index][0] if index < len(live) else None
+    with node.stages.stage("list_index"):
+        li = ks.list_index(kid)
+        lo, hi = li.neighbours(ks.el, index)
+        n_live = li.n_live
     rep = [Bulk(key)]
     dt = int(ks.keys.dt[kid])
-    for v in values:
-        pos = pos_between_bytes(lo, hi, ctx.nodeid)
-        ks.elem_add(kid, pos, v, ctx.uuid, ctx.nodeid)
-        if ctx.uuid < dt:
-            ks.elem_rem(kid, pos, dt)
+    for pos, v in zip(list_positions(lo, hi, len(values), ctx.nodeid,
+                                     node.stats), values):
+        if ks.elem_add(kid, pos, v, ctx.uuid, ctx.nodeid):
+            n_live += 1
+        if ctx.uuid < dt and ks.elem_rem(kid, pos, dt):
+            n_live -= 1
         rep.append(Bulk(pos))
         rep.append(Bulk(v))
-        lo = pos  # subsequent values land after the one just placed
     ks.updated_at(kid, ctx.uuid)
     # ONE replicated frame for the whole insert (repl_log uuids are unique)
     node.replicate_cmd(ctx.uuid, b"lins", rep)
-    return len(_list_live(ks, kid))
+    return n_live
 
 
 @register("linsert", CMD_WRITE | CMD_NO_REPLICATE | CMD_DENYOOM, families=("env", "el"))
@@ -1152,10 +1161,12 @@ def lrem_command(node, ctx, args):
     kid = _list_kid(node, ctx, key, for_write=False)
     if kid < 0:
         return Int(0)
-    live = _list_live(ks, kid)
-    if not 0 <= index < len(live):
+    with node.stages.stage("list_index"):
+        li = ks.list_index(kid)
+        rows = li.live_rows(ks.el, index, index + 1) if index >= 0 else []
+    if not rows:
         return Int(0)
-    pos = live[index][0]
+    pos = ks.el_member[rows[0]]
     ks.elem_rem(kid, pos, ctx.uuid)
     ks.updated_at(kid, ctx.uuid)
     node.replicate_cmd(ctx.uuid, b"lremat", [Bulk(key), Bulk(pos)])
@@ -1184,17 +1195,24 @@ def lrange_command(node, ctx, args):
     kid = _list_kid(node, ctx, key, for_write=False)
     if kid < 0:
         return Arr([])
-    vals = [v for _m, v in _list_live(node.ks, kid)]
-    n = len(vals)
+    ks = node.ks
+    with node.stages.stage("list_index"):
+        rows = list_range(ks, kid, start, stop)
+    el_val = ks.el_val
+    return Arr([Bulk(el_val[r] or b"") for r in rows])
+
+
+def list_range(ks, kid: int, start: int, stop: int) -> list:
+    """Rows of LRANGE's inclusive `start`..`stop` (negative from the end)
+    in list order, from the key's index: the per-command handler's and
+    the read planner's one reading of a range."""
+    li = ks.list_index(kid)
+    n = li.n_live
     if start < 0:
         start += n
     if stop < 0:
         stop += n
-    start = max(0, start)
-    if stop < start:
-        return Arr([])
-    return Arr([Bulk(v if v is not None else b"")
-                for v in vals[start:stop + 1]])
+    return li.live_rows(ks.el, max(0, start), min(stop, n - 1) + 1)
 
 
 @serve_read("llen", "llen", enc=S.ENC_LIST)
@@ -1204,7 +1222,8 @@ def llen_command(node, ctx, args):
     kid = _list_kid(node, ctx, key, for_write=False)
     if kid < 0:
         return Int(0)
-    return Int(len(_list_live(node.ks, kid)))
+    with node.stages.stage("list_index"):
+        return Int(node.ks.list_index(kid).n_live)
 
 
 @register("dellist", CMD_WRITE | CMD_REPL_ONLY | CMD_NO_REPLICATE | CMD_NO_REPLY, families=("env", "el"))
@@ -1792,6 +1811,7 @@ SERVE_ENCODERS[b"cntset"] = _senc_cntset
 SERVE_ENCODERS[b"tset"] = _senc_tset
 SERVE_ENCODERS[b"sadd"] = _senc_elem_adds(S.ENC_SET, with_vals=False)
 SERVE_ENCODERS[b"hset"] = _senc_elem_adds(S.ENC_DICT, with_vals=True)
+SERVE_ENCODERS[b"lins"] = _senc_elem_adds(S.ENC_LIST, with_vals=True)
 SERVE_ENCODERS[b"srem"] = _senc_elem_rems(S.ENC_SET)
 SERVE_ENCODERS[b"hdel"] = _senc_elem_rems(S.ENC_DICT)
 
@@ -2092,6 +2112,58 @@ def _plan_hset(coal, items):
     cnt = coal.count_elem_flips(key, kid, fields, True)
     coal.add(b"hset", (key, uuid, fields, vals), items[1:])
     return Int(cnt)
+
+
+def _plan_push(coal, items, head: bool):
+    # op twin: lpush / rpush -> _list_insert at the list's head (values
+    # reversed: Redis pushes them one at a time) or its tail; the reply is
+    # the new live length; the rewrite is the positional `lins`.  The run
+    # overlay (coal.lists, ServeCoalescer.list_overlay) holds the list's
+    # first and last member in its WHOLE index (the neighbours a push draws
+    # against), its live length, and the values pushed and not landed at
+    # each end — read from the key's index once a chunk and advanced by the
+    # run's own pushes, so a pass's pushes need no landing between them and
+    # a planned LRANGE / LLEN reads them where they will land.
+    if len(items) < 3:
+        return None
+    try:
+        key = as_bytes(items[1])
+        values = [as_bytes(v) for v in items[2:]]
+    except CstError:
+        return None
+    kid = coal.resolve_key(key, S.ENC_LIST)
+    if kid is coal.CONFLICT:
+        return None
+    st = coal.list_overlay(key, kid)
+    uuid = coal.tick()
+    stats = coal.node.stats
+    if head:
+        values.reverse()
+        poss = list_positions(None, st[0], len(values), coal.nodeid, stats)
+        st[3][:0] = values
+    else:
+        poss = list_positions(st[1], None, len(values), coal.nodeid, stats)
+        st[4].extend(values)
+    if head or st[0] is None:
+        st[0] = poss[0]
+    if not head or st[1] is None:
+        st[1] = poss[-1]
+    st[2] += len(values)
+    args = [items[1]]
+    for pos, v in zip(poss, values):
+        args += (Bulk(pos), Bulk(v))
+    coal.add(b"lins", (key, uuid, poss, values), args)
+    return Int(st[2])
+
+
+@serve_plan("lpush")
+def _plan_lpush(coal, items):
+    return _plan_push(coal, items, True)
+
+
+@serve_plan("rpush")
+def _plan_rpush(coal, items):
+    return _plan_push(coal, items, False)
 
 
 # membership + observability commands register themselves against this table

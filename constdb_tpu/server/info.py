@@ -185,6 +185,10 @@ def _section_stats(node, out):
     out.append(("serve_gather_msgs", st.serve_gather_msgs))
     out.append(("serve_gather_conns", st.serve_gather_conns))
     out.append(("serve_lone_cmds", st.serve_lone_cmds))
+    # list positions drawn (server/commands.py list_positions) and their
+    # bytes: the allocator's cost an element
+    out.append(("list_inserts", st.list_inserts))
+    out.append(("list_pos_bytes_sum", st.list_pos_bytes_sum))
     # the reply sender (server/reply_pump.py): replies and bytes handed
     # to its thread, replies written to a transport instead, sends that
     # would have blocked and were handed back (spills), wake-ups of the
@@ -246,6 +250,9 @@ def _section_stats(node, out):
     if rebuilds is not None:
         for name, cnt in sorted(rebuilds.items()):
             out.append((f"mirror_rebuilds_{name}", cnt))
+        # ... and the planes grown in place past their capacity, apart
+        for name, cnt in sorted(node.engine.mirror_grows.items()):
+            out.append((f"mirror_grows_{name}", cnt))
         # ... by what invalidated the mirror (KeySpace.touch cause), and
         # the rows each family merged on the device / on its host twin
         for cause, cnt in node.engine.mirror_rebuild_causes.items():

@@ -134,6 +134,11 @@ class NodeStats:
     serve_gather_msgs: int = 0
     serve_gather_conns: int = 0
     serve_lone_cmds: int = 0
+    # list positions drawn by pushes and inserts (server/commands.py
+    # list_positions, on both paths) and their serialized bytes summed:
+    # sum / inserts is what a list element's position costs
+    list_inserts: int = 0
+    list_pos_bytes_sum: int = 0
     # replies written to a client's transport (server/io.py _flush_out,
     # server/reply_pump.py): beside the reply sender's own counters, the
     # share of replies the sender took is posts / (posts + these)
@@ -393,6 +398,7 @@ class Node:
         between calls; it flushes to the host lazily before the next read
         (`ensure_flushed`)."""
         self._invalidate_reads((batch,))
+        self.ks.note_merge((batch,))
         st = self.engine.merge(self.ks, batch)
         self.stats.merges += 1
         self.stats.merge_rows += batch.n_rows
@@ -451,6 +457,7 @@ class Node:
                 self.merge_batch(b)
             return
         self._invalidate_reads(batches)
+        self.ks.note_merge(batches)
         self.engine.merge_many(self.ks, batches)
         self.stats.merges += 1
         self.stats.merge_rows += sum(b.n_rows for b in batches)

@@ -28,8 +28,9 @@ Ordering discipline (docs/INVARIANTS.md "Client-serving coalescing"):
     — a clean resident plane serves the batch with zero downloads),
     values gather vectorized per family (store/keyspace.py
     register_get_batch / counter_sum_batch / elem_probe_batch, and
-    elem_live_rows_batch for the scans that need a count or a sort:
-    scnt/hlen/llen/lrange) — except a missed smembers/hgetall, which
+    elem_live_rows_batch for the scans that need a count: scnt/hlen;
+    lrange/llen read the list's ordered index, store/keyspace.py
+    ListIndex) — except a missed smembers/hgetall, which
     gathers nothing: ONE native pass goes from the key's row list to its
     reply bytes (resp/codec.py scan_replier; INFO
     serve_read_scans_native counts them) — and finished reply bytes
@@ -121,7 +122,7 @@ from ..crdt import semantics as S
 from ..store.keyspace import KeySpace
 from .commands import (CMD_CTRL, CMD_READONLY, COMMANDS, SERVE_ENCODERS,
                        SERVE_KEY_SCOPED_READS, SERVE_PLANNERS,
-                       SERVE_READS)
+                       SERVE_READS, list_range)
 from .events import EVENT_REPLICATED
 
 _I64 = np.int64
@@ -229,7 +230,7 @@ class ServeCoalescer:
     CONFLICT = CONFLICT
 
     __slots__ = ("node", "max_run", "nodeid", "ks", "regs", "cnts", "els",
-                 "tns", "_keys", "_pending_keys", "_buf", "_log",
+                 "tns", "lists", "_keys", "_pending_keys", "_buf", "_log",
                  "_pending", "_planned", "_lat_pending", "_sample_every",
                  "_now", "_cur_uuid", "client", "_stage")
 
@@ -259,6 +260,9 @@ class ServeCoalescer:
         self.cnts: dict = {}    # key -> [visible_sum, my_slot_total]
         self.els: dict = {}     # key -> {member -> visible?}
         self.tns: dict = {}     # key -> packed cfg of run-created tensors
+        self.lists: dict = {}   # key -> [first member, last member, live
+        #                         length, values pushed at the head and at
+        #                         the tail not landed] (list_overlay)
         # the pending run
         self._pending_keys: dict = {}  # key with un-landed rows -> the
         #                                 rewrite name that put them there
@@ -882,6 +886,7 @@ class ServeCoalescer:
         self.cnts.clear()
         self.els.clear()
         self.tns.clear()
+        self.lists.clear()
         self.ks = self.node.ks
         self.nodeid = self.node.node_id
 
@@ -963,11 +968,21 @@ class ServeCoalescer:
         # a key with pending rows; reads of un-pending keys commute
         # with the whole pending run (the batched twin of
         # SERVE_KEY_SCOPED_READS)
+        # — except an LRANGE / LLEN of a list whose pending rows are the
+        # run's pushes: it reads them from the run overlay (list_overlay)
+        # beside the landed index, and never from the reply cache
+        over = frozenset()
         if self._pending:
             pend = self._pending_keys
-            if any(sp[4] in pend for sp in specs):
-                self.flush()
-                st.serve_read_flushes += 1
+            mine = [j for j, sp in enumerate(specs) if sp[4] in pend]
+            if mine:
+                if all(sp[2].kind in ("lrange", "llen")
+                       and pend[sp[4]] == b"lins" and sp[4] in self.lists
+                       for sp in map(specs.__getitem__, mine)):
+                    over = frozenset(mine)
+                else:
+                    self.flush()
+                    st.serve_read_flushes += 1
         ks = self.ks
         rc = node.read_cache
         use_cache = rc.enabled
@@ -979,8 +994,16 @@ class ServeCoalescer:
             # env must be host-fresh for the verify; probing is pure,
             # so running it before the ticks cannot affect uuid parity.
             node.ensure_flushed_for(_ENV_FAMS)
-            hits = rc.get_batch([(sp[3], sp[4], sp[5]) for sp in specs],
-                                ks)
+            if over:
+                probe = [j for j in range(n) if j not in over]
+                hits = [None] * n
+                for j, h in zip(probe, rc.get_batch(
+                        [(specs[j][3], specs[j][4], specs[j][5])
+                         for j in probe], ks)):
+                    hits[j] = h
+            else:
+                hits = rc.get_batch([(sp[3], sp[4], sp[5]) for sp in specs],
+                                    ks)
         else:
             if use_cache:
                 rc.misses += n
@@ -1000,13 +1023,14 @@ class ServeCoalescer:
                     spans.append(len(out))
             return
         with self._stage("read_miss"):
-            self._read_misses(specs, hits, miss, out, spans, extras)
+            self._read_misses(specs, hits, miss, out, spans, extras, over)
 
     def _read_misses(self, specs: list, hits: list, miss: list,
-                     out: bytearray, spans, extras) -> None:
+                     out: bytearray, spans, extras, over=frozenset()) -> None:
         """The miss branch of a planned read run, under its `read_miss`
         stage: key resolution, family gathers, reply build, cache fill —
-        and the in-order emit of hits and misses alike."""
+        and the in-order emit of hits and misses alike.  `over`: the
+        specs that read a list through the run overlay."""
         node = self.node
         st = node.stats
         ks = self.ks
@@ -1071,7 +1095,10 @@ class ServeCoalescer:
         # time, so the HLC stream is exactly the per-command path's)
         slots: list = [None] * n
         cacheable: list = [False] * n
-        miss_scan: list = []   # el-family full scans (members/pairs/...)
+        miss_scan: list = []   # el-family full scans (card)
+        miss_list: list = []   # lrange / llen: (kid, range or None)
+        miss_over: list = []   # the same through the run overlay: (key,
+        #                        kid, range or None)
         miss_probe: list = []  # el-family combo probes (hget/sismember)
         miss_cnt: list = []    # counter totals (one cnt_sum gather)
         miss_reg: list = []    # register blobs
@@ -1087,6 +1114,17 @@ class ServeCoalescer:
             ct_j, dt_j, exp_j = env[j]
             alive = kid >= 0 and ct_j >= dt_j
             kind = spec.kind
+            if j in over:
+                if not (kid >= 0 and exp_j):
+                    # a list with pushes pending: the overlay around the
+                    # landed index
+                    planned += 1
+                    slots[j] = ("over", len(miss_over))
+                    miss_over.append((key, kid, parsed))
+                    continue
+                # expiry-armed: it demotes below, after the run lands
+                self.flush()
+                st.serve_read_flushes += 1
             if kid >= 0 and exp_j:
                 demote = True  # expiry-armed: time-dependent visibility
             elif kind == "get":
@@ -1142,6 +1180,10 @@ class ServeCoalescer:
                     # no gather: the stitch loop's fused pass goes from
                     # the key's row list to the reply bytes
                     slots[j] = ("fused", kid)
+                elif kind in ("lrange", "llen"):
+                    # the list's ordered index: its range, or its length
+                    slots[j] = ("list", len(miss_list))
+                    miss_list.append((kid, parsed))
                 else:
                     slots[j] = ("scan", len(miss_scan))
                     miss_scan.append((j, kid))
@@ -1157,9 +1199,17 @@ class ServeCoalescer:
         st.cmds_processed += planned
         st.serve_reads_coalesced += planned
         st.serve_read_replies_direct += planned - (n - len(miss))
-        # ---- vectorized family gathers for the misses (card / llen /
-        # lrange scans need a count or a sort; members / pairs take the
-        # fused pass below)
+        # ---- vectorized family gathers for the misses (card scans need a
+        # count; members / pairs take the fused pass below, lrange / llen
+        # the list's index)
+        list_got: list = []
+        over_got: list = []
+        if miss_list or miss_over:
+            with self._stage("list_index"):
+                list_got = [ks.list_index(kid).n_live if rng is None
+                            else list_range(ks, kid, *rng)
+                            for kid, rng in miss_list]
+                over_got = [self._overlay_read(*m) for m in miss_over]
         scan_rows: list = []
         if miss_scan:
             scan_rows = ks.elem_live_rows_batch([m[1] for m in miss_scan])
@@ -1200,14 +1250,21 @@ class ServeCoalescer:
                         scan_reply = scan_replier(ks)
                     payload, native = scan_reply(out, k2, ref)
                     native_scans += native
-                elif kind == "scan":
-                    rows = scan_rows[ref]
+                elif kind == "over":
+                    got = over_got[ref]
+                    payload = got if k2 == "lrange" else int_reply(got)
+                    out += payload
+                elif kind == "list":
+                    got = list_got[ref]
                     if k2 == "lrange":
-                        payload = encode_rows_into(out, k2, rows.tolist(),
-                                                   el_member, el_val, *sp[6])
-                    else:  # card / llen
-                        payload = int_reply(len(rows))
+                        payload = encode_rows_into(out, k2, got, el_member,
+                                                   el_val)
+                    else:  # llen
+                        payload = int_reply(got)
                         out += payload
+                elif kind == "scan":  # card
+                    payload = int_reply(len(scan_rows[ref]))
+                    out += payload
                 else:
                     if kind == "cnt":
                         payload = int_reply(cnt_vals[ref])
@@ -1311,6 +1368,7 @@ class ServeCoalescer:
             self.cnts.pop(key, None)
             self.els.pop(key, None)
             self.tns.pop(key, None)
+            self.lists.pop(key, None)
             return
         self._reset_caches()
 
@@ -1341,6 +1399,60 @@ class ServeCoalescer:
             return kid if e == enc else CONFLICT
         self._keys[key] = (-1, enc)
         return -1
+
+    def list_overlay(self, key: bytes, kid: int) -> list:
+        """The run overlay of list `key` (kid -1: created by this run):
+        [first member, last member, live length, values pushed at the head
+        and not landed (list order), values pushed at the tail and not
+        landed] — from the key's index the first time a chunk asks."""
+        st = self.lists.get(key)
+        if st is None:
+            st = [None, None, 0, [], []]
+            if kid >= 0:
+                with self._stage("list_index"):
+                    li = self.ks.list_index(kid)
+                    st[:3] = li.rows.first(), li.rows.last(), li.n_live
+            self.lists[key] = st
+        return st
+
+    def _overlay_read(self, key: bytes, kid: int, rng):
+        """LRANGE (`rng` = (start, stop)) or LLEN (`rng` None) of list
+        `key` as the per-command path would answer it once the run had
+        landed: the pending head values, the landed index, the pending
+        tail values.  -> reply bytes, or the length."""
+        st = self.lists[key]
+        n = st[2]
+        if rng is None:
+            return n
+        head, tail = st[3], st[4]
+        start, stop = rng
+        if start < 0:
+            start += n
+        if stop < 0:
+            stop += n
+        start, stop = max(0, start), min(stop, n - 1) + 1
+        if stop <= start:
+            return b"*0\r\n"
+        n_head = len(head)
+        n_landed = n - n_head - len(tail)
+        vals = head[start:stop]
+        a, b = max(start - n_head, 0), min(stop - n_head, n_landed)
+        landed = b""
+        if b > a and kid >= 0:
+            ks = self.ks
+            # the landed rows through the row encoder (native pass), less
+            # its own array header
+            landed = encode_rows_into(
+                bytearray(), "lrange", ks.list_index(kid).live_rows(ks.el, a,
+                                                                    b),
+                ks.el_member, ks.el_val)
+            landed = landed[landed.index(b"\n") + 1:]
+        after = tail[max(start - n_head - n_landed, 0):
+                     max(stop - n_head - n_landed, 0)]
+        return b"".join([b"*%d\r\n" % (stop - start)]
+                        + [b"$%d\r\n%b\r\n" % (len(v), v) for v in vals]
+                        + [landed]
+                        + [b"$%d\r\n%b\r\n" % (len(v), v) for v in after])
 
     def count_elem_flips(self, key: bytes, kid: int, members: list,
                          add: bool) -> int:
@@ -1404,6 +1516,9 @@ class ServeCoalescer:
         if not n:
             return
         self._pending_keys.clear()
+        for st in self.lists.values():
+            st[3].clear()   # the pushed values land with the run
+            st[4].clear()
         log, self._log = self._log, []
         with self._stage("serve_flush"):
             self._land(buf, n, log)

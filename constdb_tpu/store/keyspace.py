@@ -41,6 +41,7 @@ import numpy as np
 
 from ..crdt import semantics as S
 from ..crdt import tensor as T
+from ..crdt.sequence import Sorted
 from ..errors import InvalidType
 from ..utils.native_tables import I64Dict, StrTable
 from .columns import Columns, TensorCols
@@ -199,6 +200,85 @@ class BlobList(list):
         return (list, (list(self),))
 
 
+class ListIndex:
+    """The element rows of one list key in list order: member (position)
+    bytes -> row, for EVERY row of the key, tombstones included — a push
+    draws its position between whole-index neighbours, so a fresh one never
+    lands on a deleted one (docs/INVARIANTS.md, LIST-INDEX).  Built and
+    brought up to date by KeySpace.list_index, never written by a caller.
+
+    `synced`: how many of the key's `el_rows_by_kid` rows are in (the
+    rows a key gains, by any writer, are appended there first); `epoch`:
+    the `el_compact_epoch` its row ids belong to; `n_live`: its live rows,
+    None where a tombstone or a revival may have moved it since the last
+    count (KeySpace._lists_stale)."""
+
+    __slots__ = ("rows", "synced", "epoch", "n_live")
+
+    def __init__(self, rows: Sorted, synced: int, epoch: int) -> None:
+        self.rows = rows
+        self.synced = synced
+        self.epoch = epoch
+        self.n_live: Optional[int] = None
+
+    def _walk(self, el):
+        """(member, row, alive) in list order; every row alive where no
+        tombstone is in the index (the push-only list: no column read)."""
+        if self.n_live == len(self.rows):
+            for ks, rs in self.rows.chunks():
+                for m, r in zip(ks, rs):
+                    yield m, r, True
+            return
+        add_t, del_t = el.add_t, el.del_t
+        for ks, rs in self.rows.chunks():
+            arr = np.array(rs, dtype=_I64)
+            alive = (add_t[arr] >= del_t[arr]).tolist()
+            yield from zip(ks, rs, alive)
+
+    def live_rows(self, el, start: int, stop: int) -> list:
+        """Rows of live elements [start, stop) in list order (0 <= start):
+        O(stop) where the list holds no tombstone."""
+        if stop <= start:
+            return []
+        if self.n_live == len(self.rows):
+            out: list = []
+            skip = start
+            for _ks, rs in self.rows.chunks():
+                if skip >= len(rs):
+                    skip -= len(rs)
+                    continue
+                out.extend(rs[skip:skip + stop - start - len(out)])
+                skip = 0
+                if len(out) >= stop - start:
+                    break
+            return out
+        out = []
+        i = 0
+        for _m, r, alive in self._walk(el):
+            if alive:
+                if i >= start:
+                    out.append(r)
+                    if len(out) >= stop - start:
+                        break
+                i += 1
+        return out
+
+    def neighbours(self, el, index: int) -> tuple:
+        """(lo, hi) member bytes a value inserted before live element
+        `index` goes between (None: the list's edge)."""
+        if index <= 0:
+            return None, self.rows.first()
+        if index >= self.n_live:
+            return self.rows.last(), None
+        i = 0
+        for m, _r, alive in self._walk(el):
+            if alive:
+                if i == index:
+                    return self.rows.before(m), m
+                i += 1
+        return self.rows.last(), None
+
+
 class _KeyCols(Columns):
     def __init__(self) -> None:
         super().__init__(
@@ -291,6 +371,8 @@ class KeySpace:
         # the stage→dispatch window (engine/tpu.py) and fails loudly if a
         # compaction slipped in between.
         self.el_compact_epoch = 0
+        # list keys' ordered indexes (ListIndex), built on first use
+        self.lists: dict[int, ListIndex] = {}
 
         # incremental crc32 caches for the anti-entropy digest
         # (store/digest.py): key/member bytes are hashed ONCE, in append
@@ -356,6 +438,8 @@ class KeySpace:
             fc[f] = cause
             if whole and f in self.journal:
                 self.journal[f].mark_whole()
+        if cause in ("reset", "compact") and "el" in families:
+            self.lists.clear()     # rows may have moved: rebuilt on use
 
     @property
     def version(self) -> int:
@@ -521,9 +605,52 @@ class KeySpace:
         if kid >= 0 and t > int(self.keys.expire[kid]):
             self.keys.expire[kid] = t
 
+    def note_merge(self, batches) -> None:
+        """Before an engine merges `batches` (Node.merge_batch /
+        merge_batches): a list whose index already holds an element a
+        batch rewrites recounts its live rows at its next use.  A push's
+        fresh position is no such element, so the served path's landings
+        keep their counts."""
+        lists = self.lists
+        if not lists:
+            return
+        lookup = self.key_index.lookup
+        for b in batches:
+            if not len(b.el_ki):
+                continue
+            kis = {}
+            for ki in np.unique(b.el_ki).tolist():
+                li = lists.get(lookup(b.keys[ki]))
+                if li is not None and li.n_live is not None:
+                    kis[ki] = li
+            if not kis:
+                continue
+            members = b.el_member
+            for j, ki in enumerate(b.el_ki.tolist()):
+                li = kis.get(ki)
+                if li is not None and li.n_live is not None and \
+                        li.rows.get(members[j]) is not None:
+                    li.n_live = None
+
     def _enqueue_garbage(self, t: int, key: bytes, member: Optional[bytes]) -> None:
         self._garbage_seq += 1
         heapq.heappush(self.garbage, (t, self._garbage_seq, key, member))
+        if self.lists and member is not None:
+            self._lists_stale((key,))
+
+    def _lists_stale(self, keys) -> None:
+        """An element of these keys may have died or come back to life:
+        a list among them recounts its live rows at its next use.  Every
+        writer that kills an element queues it for GC (the two enqueue
+        methods), and a revival rewrites an element the index already
+        holds (elem_add, elem_merge, or an engine merge: note_merge) — so
+        this sees every change of a list's live count that a fresh
+        position does not make."""
+        lists, lookup = self.lists, self.key_index.lookup
+        for key in keys:
+            li = lists.get(lookup(key))
+            if li is not None:
+                li.n_live = None
 
     def enqueue_garbage_bulk(self, ts: list, keys: list, members: list) -> None:
         """Bulk tombstone enqueue.  A snapshot-merge flush queues millions
@@ -533,6 +660,8 @@ class KeySpace:
         n = len(ts)
         if not n:
             return
+        if self.lists:
+            self._lists_stale(set(keys))
         seq0 = self._garbage_seq
         self._garbage_seq = seq0 + n
         seqs = range(seq0 + 1, seq0 + 1 + n)
@@ -859,7 +988,10 @@ class KeySpace:
             self.el_val[row] = val
             self.journal["el"].add(row)
             at = uuid
-        return S.elem_alive(at, dt) and not was_alive
+        revived = S.elem_alive(at, dt) and not was_alive
+        if revived and kid in self.lists:
+            self.lists[kid].n_live = None
+        return revived
 
     def elem_rem(self, kid: int, member: bytes, uuid: int) -> bool:
         """SREM member / HDEL field: pure pointwise del-side max (see
@@ -917,6 +1049,44 @@ class KeySpace:
             yield (self.el_member[row], int(self.el.add_t[row]),
                    int(self.el.add_node[row]), int(self.el.del_t[row]),
                    self.el_val[row])
+
+    def list_index(self, kid: int) -> ListIndex:
+        """List `kid`'s ordered index, brought up to date: built from the
+        key's rows on first use (and after a compaction or a reset), then
+        kept by inserting the rows the key gained since — whatever wrote
+        them: a push on either path, a replicated `lins`/`lremat`, a state
+        merge, a snapshot.  GC removes what it collects (gc below).  The
+        caller has flushed the element plane (a device-resident engine's
+        rows are host-exact before anything reads them)."""
+        self._sync_el_lists()
+        by_kid = self.el_rows_by_kid.get(kid, ())
+        li = self.lists.get(kid)
+        el = self.el
+        members = self.el_member
+        if li is None or li.epoch != self.el_compact_epoch:
+            rows = np.asarray(by_kid, dtype=_I64)
+            rows = rows[el.kid[rows] == kid].tolist() if len(rows) else []
+            li = ListIndex(Sorted(sorted(zip(map(members.__getitem__, rows),
+                                             rows))),
+                           len(by_kid), self.el_compact_epoch)
+            self.lists[kid] = li
+        elif li.synced < len(by_kid):
+            new = by_kid[li.synced:]
+            li.synced = len(by_kid)
+            el_kid, add_t, del_t = el.kid, el.add_t, el.del_t
+            insert = li.rows.insert
+            for r in new:
+                if el_kid[r] == kid and insert(members[r], r) and \
+                        li.n_live is not None:
+                    li.n_live += int(add_t[r] >= del_t[r])
+        if li.n_live is None:
+            n = len(li.rows)
+            if n:
+                rows = np.fromiter((r for _k, rs in li.rows.chunks()
+                                    for r in rs), dtype=_I64, count=n)
+                n = int(np.count_nonzero(el.add_t[rows] >= el.del_t[rows]))
+            li.n_live = n
+        return li
 
     # ------------------------------------------------- batched read gathers
     # The serve coalescer's read planner (server/serve.py) resolves a
@@ -1024,6 +1194,8 @@ class KeySpace:
 
         a0, n0, d0 = int(self.el.add_t[row]), int(self.el.add_node[row]), int(self.el.del_t[row])
         at, an, dt, local_wins = S.merge_elem(a0, n0, d0, add_t, add_node, del_t)
+        if kid in self.lists and S.elem_alive(at, dt) != S.elem_alive(a0, d0):
+            self.lists[kid].n_live = None
         self.el.add_t[row], self.el.add_node[row], self.el.del_t[row] = at, an, dt
         self.journal["el"].add(row)
         if not local_wins:
@@ -1207,6 +1379,9 @@ class KeySpace:
                 continue
             at, dt = int(self.el.add_t[row]), int(self.el.del_t[row])
             if at < dt and dt <= horizon:
+                li = self.lists.get(kid)
+                if li is not None:
+                    li.rows.remove(member)   # a tombstone: n_live stands
                 mid = self.member_index.lookup(member)
                 self.el_index.delete((kid << self.MEMBER_BITS) | mid)
                 self.el.kid[row] = -1

@@ -66,9 +66,11 @@ from time import perf_counter_ns
 # in (`repl_ingest` per socket read, `repl_flush` per landed batch) and the
 # node's own log out (`repl_push` per drained run and per wake-up's tail);
 # then the loop's poll (`loop_poll`, one entry per iteration) and the
-# garbage collector (`gc`, one entry per collection)
+# garbage collector (`gc`, one entry per collection).  `list_index`: a list
+# key's ordered index brought up to date and read (store/keyspace.py
+# ListIndex) — by a push, LRANGE / LLEN / LREM on either path
 STAGES = ("intake", "gather", "plan", "read_batch", "read_miss", "exec",
-          "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
+          "list_index", "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
           "mirror_rebuild", "mirror_patch", "state_alloc", "d2h_flush",
           "reply_write", "repl_ingest", "repl_flush", "repl_push",
           "loop_poll", "gc")

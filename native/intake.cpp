@@ -16,7 +16,7 @@
 // NATIVE-INTAKE-TABLE-BEGIN (parsed by analysis/rules.py NATIVE-CONTRACT)
 //   native: set incr decr sadd srem hset hdel
 //   native-reads: get scnt sismember smembers hget hgetall llen hlen
-//   python-only: cntundo tensor.set tensor.merge lrange
+//   python-only: cntundo tensor.set tensor.merge lrange lpush rpush
 // NATIVE-INTAKE-TABLE-END
 //
 // Routability contract (cluster mode): every native/native-reads entry

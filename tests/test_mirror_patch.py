@@ -99,9 +99,12 @@ def sadd_round(node, first: int, members: int = 6, key: bytes = b"s"):
 
 
 def warm_el(node, rounds: int = 2) -> None:
-    """Coalesced SADD rounds until the el mirror is resident and fresh."""
+    """Coalesced SADD rounds until the el mirror is resident and fresh:
+    ten members, then two a round, inside its first build's capacity (a
+    plane a micro round grows keeps the engine's GROW_FLOOR rows)."""
     for i in range(rounds + node.engine.warmup):
-        sadd_round(node, 1000 + 6 * i, key=b"warm")
+        sadd_round(node, 1000 + 10 * i, members=2 if i else 10,
+                   key=b"warm")
     assert node.engine._res["el"]["ver"] == node.ks.fam_ver["el"]
 
 
@@ -237,6 +240,7 @@ def rebuilt_from_scratch_is_the_same(node) -> None:
     node.ensure_flushed()
     eng, ks = node.engine, node.ks
     fresh = TpuMergeEngine(resident=True)
+    fresh._growing |= eng._growing      # the tables micro rounds grew
     for fam in JOURNAL_FAMILIES:
         if fam not in eng._res:
             continue
@@ -550,9 +554,10 @@ def test_patch_programs_compile_at_the_flush_before_a_mirror_can_go_stale():
         sadd_round(node, 4, members=2)           # patched cold, at cap2,
         node.ensure_flushed()                    # warmed at its next flush
         cap2 = eng._res["el"]["cap"]
-        assert cap2 > cap
-        assert calls[n_warm + 1:] == [("patch", "el", 1 << 10, cap2),
-                                      ("warm", "el", 1 << 10, cap2)]
+        # a micro round grew it: the floor, which every bucket fits
+        assert cap2 == eng.GROW_FLOOR > cap
+        assert calls[n_warm + 1:] == [("patch", "el", 1 << 10, cap2)] + [
+            ("warm", "el", bp, cap2) for bp in eng.MIRROR_PATCH_BUCKETS]
         repair_and_check(node)
     finally:
         B.MIRROR_PATCH.update(real)

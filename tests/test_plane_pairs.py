@@ -376,10 +376,15 @@ def test_every_resident_plane_the_engine_holds_is_the_pair():
     assert {f for f, c in eng.mirror_patches.items() if c} == \
         set(JOURNAL_FAMILIES)
     cap0 = eng._res["el"]["cap"]
-    for i in range(2 * cap0):                                      # grow
-        for nd in (node, ref):
-            nd.execute(req(b"hset", b"big", b"g%d" % i, b"v"),
-                       uuid=u(10 ** 6) + i)
+    # a grow: a peer's hash of cap0 fields lands as one bulk round (the
+    # micro rounds above grew the plane to the floor, 2^17 rows)
+    big = Node(node_id=3)
+    fields = []
+    for i in range(cap0):
+        fields += [b"g%d" % i, b"v"]
+    big.execute(req(b"hset", b"big", *fields), uuid=u(10 ** 6))
+    for nd in (node, ref):
+        nd.merge_batch(batch_from_keyspace(big.ks))
     check(node)
     assert eng._res["el"]["cap"] > cap0
     assert sum(eng.mirror_rebuilds.values()) == 0

@@ -24,9 +24,10 @@ Pinned here:
     write is patched, a GC rebuilds), the engine's inclusive
     `family_secs` still read above 0, and the INFO totals that overlapped
     the stages are gone;
-  * every counter a per-layer metric names — the nineteen in
-    BENCHMARK.json (PR 37's four of the gather and the `reg` rows among
-    them) and the nineteen specs of docs/stage_layers/ — is an INFO key of a
+  * every counter a per-layer metric names — the twenty-four in
+    BENCHMARK.json (the gather's four and the `reg` rows, and the list
+    index's and the plane grows' five among them) and the nineteen specs
+    of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
 """
@@ -67,6 +68,11 @@ GATHER_COUNTERS = ["serve_gather_passes", "serve_gather_msgs",
 # that returned a win vector / fell back to `src`, rows applied from vectors
 MICRO_COUNTERS = ["micro_win_scatters", "micro_src_scatters",
                   "micro_win_rows"]
+# the list index's counters beside its stage (server/commands.py
+# list_positions) and the engine's plane grows, apart from its rebuilds
+LIST_COUNTERS = ["list_inserts", "list_pos_bytes_sum"]
+GROW_COUNTERS = [f"mirror_grows_{f}" for f in ("cnt", "el", "env", "reg",
+                                               "tns")]
 # the event loop's four metrics (PR 39), specified for every cell
 LOOP_SPECS = {"loop_poll_share.serve", "loop_cpu_share.serve",
               "loop_preempt_per_kop.serve", "gc_pause_share.serve"}
@@ -515,8 +521,8 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     want += [f"mirror_patch{k}_{f}" for k in ("es", "_rows")
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
     want += LINK_COUNTERS + GATHER_COUNTERS + MICRO_COUNTERS
-    want += ["loop_poll_events"]
-    assert len(want) == 2 * 21 + 8 + 6 + 7 + 5 + 4 + 3 + 1
+    want += ["loop_poll_events"] + LIST_COUNTERS + GROW_COUNTERS
+    assert len(want) == 2 * 22 + 8 + 6 + 7 + 5 + 4 + 3 + 1 + 2 + 5
     assert STAGES.index("gather") == 1
     assert STAGES[-2:] == ("loop_poll", "gc")
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
@@ -527,7 +533,8 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     # a CPU-engine node has the clock, not the device engine's counters
     cpu = info_of(Node(node_id=2))
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
-    assert all(cpu[k] == 0 for k in LINK_COUNTERS + GATHER_COUNTERS)
+    assert all(cpu[k] == 0 for k in LINK_COUNTERS + GATHER_COUNTERS
+               + LIST_COUNTERS)
     assert "merge_rows_dev_el" not in cpu
     assert not any(k in cpu for k in MICRO_COUNTERS)
 
@@ -817,14 +824,21 @@ def test_every_counter_a_layer_file_names_is_in_info():
     finally:
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
-    # the five of the replication link, the four of the gather (PR 37);
+    # the five of the replication link, the four of the gather, the five
+    # of the list index and the engine (redis-benchmark's cell);
     # of docs/stage_layers/ fifteen, the event loop's four and the reply
     # sender's two
-    assert len(specs) == 10 + 5 + 4 + 15 + 4 + 2
+    assert len(specs) == 10 + 5 + 4 + 5 + 15 + 4 + 2
     mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
     assert sorted(s["name"] for s in mine) == [
         "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
         "lone_cmd_share.serve", "reg_rows_dev_share.serve"]
+    lists = [s for s in specs
+             if s["workloads"] == ["redis-benchmark-default"]]
+    assert sorted(s["name"] for s in lists) == [
+        "cnt_rows_dev_share.serve", "el_rows_dev_share.serve",
+        "list_index_us_per_op.serve", "list_pos_bytes_per_insert.serve",
+        "mirror_grows_per_kop.serve"]
     link = [s for s in specs if s["layer"] == "replication link"]
     assert len(link) == 5 and all(
         s["workloads"] == ["aa-3node-ycsb-a"] for s in link)
@@ -866,11 +880,12 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     with open(tmp_path / "BENCHMARK.json") as f:
         after = json.load(f)
     assert validate.check_manifest(after) == []
-    assert after["per_layer"][:19] == before["per_layer"]
-    assert [m["name"] for m in after["per_layer"][19:]] == added
+    n = len(before["per_layer"])
+    assert after["per_layer"][:n] == before["per_layer"]
+    assert [m["name"] for m in after["per_layer"][n:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == 40
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == n + 21
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
