@@ -20,6 +20,7 @@ import numpy as np
 from ..crdt import semantics as S
 from ..resp.message import Bulk
 from .commands import CMD_READONLY, register
+from .read_pump import COUNTERS as READ_COUNTERS
 from .reply_pump import COUNTERS as REPLY_COUNTERS
 
 
@@ -198,6 +199,15 @@ def _section_stats(node, out):
     reply = dict(pump.counters()) if pump is not None else {}
     reply["reply_transport_writes"] = st.reply_transport_writes
     out.extend((name, reply.get(name, 0)) for name in REPLY_COUNTERS)
+    # the reader (server/read_pump.py): takes of the loop, bytes taken or
+    # handed back, the thread's recv calls and its time inside them,
+    # signals to the loop, connections handed to their transport, and
+    # reads a client's transport took instead — 0 from boot, and where
+    # the extension does not load
+    rpump = getattr(getattr(node, "app", None), "read_pump", None)
+    read = dict(rpump.counters()) if rpump is not None else {}
+    read["read_transport_reads"] = st.read_transport_reads
+    out.extend((name, read.get(name, 0)) for name in READ_COUNTERS)
     rc = node.read_cache
     x = st.extra
     rc_bytes = rc.used_bytes() + sum(

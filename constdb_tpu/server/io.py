@@ -24,10 +24,13 @@ from ..replica.manager import ReplicaManager, ReplicaMeta
 from ..resp.codec import RespParser, encode_into, make_parser
 from ..resp.message import Arr, Bulk, Err, Int, NoReply, as_bytes, as_int
 from .node import Node
+from .read_pump import ClientProtocol, ReadPump
+from .reply_pump import ReplyPump, has_transport_state
 
 log = logging.getLogger(__name__)
 
 _READ_CHUNK = 1 << 16
+_STREAM_LIMIT = 1 << 16     # asyncio.start_server's StreamReader limit
 
 
 def _has_conn_state_cmd(msgs) -> bool:
@@ -44,16 +47,21 @@ class _PassGather:
     """The loop-pass gather in front of the node's one ServeCoalescer
     (server/serve.py; docs/INVARIANTS.md "Client-serving coalescing").
 
-    A connection task parses its socket read and hands the messages over
-    (`hand_over`); the first hand-over of a pass schedules `_run_pass`
-    with `loop.call_soon`, so every task the same `select()` woke has
-    had its turn before it runs.  The pass plans everything handed over
-    as ONE chunk, in hand-over order, and cuts the replies at the
-    connections' boundaries.  The slices of connections on the reply
-    sender (server/reply_pump.py) go to it in ONE call, and their tasks
-    wake with nothing to write; every other task wakes with its slice,
-    and writes and drains its own socket.  A pass of one message is the
-    lone command on the exact per-command path; a pass of one
+    Where the extension's reader serves the node (server/read_pump.py),
+    its take delivers what every connection sent since the last take in
+    ONE call: each read is parsed and joins the pass (`join`) in take
+    order, and the pass runs at the end of the take (`run_pending`).  A
+    connection its transport reads parses its socket read in its task
+    and hands the messages over (`hand_over`); the first hand-over of a
+    pass schedules `_run_pass` with `loop.call_soon`, so every task the
+    same `select()` woke has had its turn before it runs.  The pass
+    plans everything joined or handed over as ONE chunk, in that order,
+    and cuts the replies at the connections' boundaries.  The slices of
+    connections on the reply sender (server/reply_pump.py) go to it in
+    ONE call, and the reader's connections among them are released to
+    the reader after it; every other connection's task wakes with its
+    slice, and writes and drains its own socket.  A pass of one message
+    is the lone command on the exact per-command path; a pass of one
     connection's pipeline is the chunk that connection used to run
     alone.
 
@@ -72,7 +80,8 @@ class _PassGather:
         self.node = app.node
         self.coal = coal
         # (ops | None, payloads, future, ClientConn | None): the client
-        # where its slice may go to the reply sender
+        # where its slice may go to the reply sender; no future for a
+        # read the reader delivered
         self.segs: list = []
         self.scheduled = False
         self.loop = None         # the serving loop, from the first hand-over
@@ -81,7 +90,7 @@ class _PassGather:
 
     @staticmethod
     def keeps_own_path(client, ops, payloads) -> bool:
-        if client.tracking or client.resp3:
+        if has_transport_state(client):
             return True
         if ops is None:
             return _has_conn_state_cmd(payloads)
@@ -98,6 +107,30 @@ class _PassGather:
                 coal.run_native_chunk(ops, payloads, out)
         finally:
             coal.client = None
+
+    def join(self, client, parser, data: bytes) -> bool:
+        """A read the reader delivered for `client` joins this pass, or
+        wakes the connection's task where it needs its own path (a SYNC,
+        a malformed frame, HELLO / CLIENT or their state).  -> False
+        where it completed no frame: nothing of it is in flight."""
+        app = self.app
+        native = app.native_intake
+        held, msgs, err = app._intake(parser, data, native)
+        ops, payloads = app._segment(held, msgs, native)
+        if err is not None or any(map(app._is_sync, msgs)) or \
+                self.keeps_own_path(client, ops, payloads):
+            app.read_pump.hand(client, (held, msgs, err, bytearray()))
+        elif payloads:
+            self.segs.append((ops, payloads, None, client))
+        else:
+            return False
+        return True
+
+    def run_pending(self) -> None:
+        """Run the pass of what joined, unless a hand-over has already
+        scheduled it."""
+        if self.segs and not self.scheduled:
+            self._run_pass()
 
     def hand_over(self, ops, payloads, client=None) -> "asyncio.Future":
         """One connection's messages of this pass -> the future of its
@@ -172,28 +205,43 @@ class _PassGather:
 
     def _wake(self, segs: list, out: bytearray, ends: list) -> None:
         """Cut the replies: the slices of connections on the reply sender
-        go to it in one call; every other task wakes with its own."""
+        go to it in one call, and the reader reads those of its
+        connections again after it; every other task wakes with its
+        own."""
         ids = []
+        free = []
         a = 0
         for (_, _, fut, client), b in zip(segs, ends):
-            if fut.done():   # its task was cancelled: only its own
-                ids.append(0)  # replies are lost
+            if fut is None and not client.read_id:
+                ids.append(0)  # ended meanwhile: only its own replies
+            elif fut is not None and fut.done():  # its task was cancelled
+                ids.append(0)
             elif client is not None and client.on_pump:
                 ids.append(client.reply_id)
-                fut.set_result(None)
+                if fut is None:
+                    free.append(client.read_id)
+                else:
+                    fut.set_result(None)
             else:
                 ids.append(0)
-                fut.set_result(out if len(segs) == 1 else out[a:b])
+                mine = out if len(segs) == 1 else out[a:b]
+                if fut is None:   # its task writes, drains and releases
+                    self.app.read_pump.hand(client, (None, [], None, mine))
+                else:
+                    fut.set_result(mine)
             a = b
         if any(ids):
             with self.stage("reply_write"):
                 self.app.reply_pump.post(out, ids, ends)
+        if free:
+            self.app.read_pump.release(free)
 
-    @staticmethod
-    def _fail(segs: list, exc: Exception) -> None:
-        for seg in segs:
-            if not seg[2].done():
-                seg[2].set_exception(exc)
+    def _fail(self, segs: list, exc: Exception) -> None:
+        for _, _, fut, client in segs:
+            if fut is None:
+                self.app.read_pump.hand(client, exc)
+            elif not fut.done():
+                fut.set_exception(exc)
 
     async def _wake_after_barrier(self, segs: list, out: bytearray,
                                   ends: list) -> None:
@@ -471,6 +519,10 @@ class ServerApp:
         # start() where the extension loads and chunks are gathered or
         # routed, stopped and joined by close()
         self.reply_pump = None
+        # the extension's reader (server/read_pump.py); started by start()
+        # where the extension loads and chunks are gathered, stopped and
+        # joined by close()
+        self.read_pump = None
         # awaited by start() AFTER the serve plane is up but BEFORE the
         # listener opens — the sharded boot restore (start_node) runs
         # here so a reconnecting peer can never observe the un-fenced
@@ -545,8 +597,16 @@ class ServerApp:
         # bind (resolving an ephemeral port — advertised_addr is live
         # from here) but do NOT accept yet: the boot restore below must
         # land its watermark fences first
-        self._server = await asyncio.start_server(
-            self._on_connection, self.host, self.port,
+        loop = asyncio.get_running_loop()
+
+        def client_protocol():
+            # asyncio.start_server's factory, with a transport that starts
+            # paused: the connection's task picks its reading side
+            return ClientProtocol(asyncio.StreamReader(limit=_STREAM_LIMIT,
+                                                       loop=loop),
+                                  self._on_connection, loop)
+        self._server = await loop.create_server(
+            client_protocol, self.host, self.port,
             backlog=self.tcp_backlog, start_serving=False)
         self.port = self._server.sockets[0].getsockname()[1]
         if self.node.cluster is not None:
@@ -561,10 +621,13 @@ class ServerApp:
             from ..utils.native_tables import load_ext
             ext = load_ext()
             if ext is not None:
-                from .reply_pump import ReplyPump
                 self.reply_pump = ReplyPump(ext, self.node.stats,
                                             self._outbuf_overflow)
-                self.reply_pump.start(asyncio.get_running_loop())
+                self.reply_pump.start(loop)
+                if self._gather is not None:
+                    self.read_pump = ReadPump(ext, self._gather,
+                                              self.node.stages)
+                    self.read_pump.start(loop)
         await self._server.start_serving()
         self._cron_task = asyncio.create_task(self._cron())
         # reconnect links for membership restored from a snapshot
@@ -608,6 +671,8 @@ class ServerApp:
             # every connection's task has ended and released its
             # connection: nothing is left for the thread to send
             self.reply_pump.close()
+        if self.read_pump is not None:
+            self.read_pump.close()
         if self.node.oplog is not None:
             # final group commit + close (policy `no` drains without
             # forcing an fsync — that is its contract)
@@ -711,13 +776,26 @@ class ServerApp:
                             created=time.time())
         self.client_conns[client.cid] = client
         pump = self.reply_pump
-        if pump is not None:
-            sock = writer.get_extra_info("socket")
-            if sock is not None:
-                try:
-                    pump.open(client, sock)
-                except OSError:  # no descriptor to spare: the transport
-                    pass         # writes this connection
+        rpump = self.read_pump
+        sock = writer.get_extra_info("socket")
+        if pump is not None and sock is not None:
+            try:
+                pump.open(client, sock)
+            except OSError:  # no descriptor to spare: the transport
+                pass         # writes this connection
+        parser = make_parser()
+        if rpump is not None and sock is not None:
+            try:
+                rpump.open(client, sock, parser)
+            except OSError:  # no descriptor to spare: the transport
+                pass         # reads this connection
+        if client.read_id:
+            # a transport lost under a parked task (the outbuf cap's
+            # abort) ends the connection as its stream's end would
+            writer.transport.get_protocol().on_lost = \
+                lambda: rpump.hand(client, None)
+        else:
+            writer.transport.resume_reading()
         try:
             # bound the transport's userspace reply buffer: drain()
             # engages at the high-water mark, so one connection's
@@ -729,7 +807,6 @@ class ServerApp:
                 high=min(self.client_outbuf_max or (1 << 18), 1 << 18))
         except (AttributeError, RuntimeError):  # pragma: no cover
             pass
-        parser = make_parser()
         out = bytearray()
         upgraded = False
         # the node's stage clock (utils/stagetime.py).  `intake` is taken
@@ -742,18 +819,33 @@ class ServerApp:
         # what this read's scans have parsed and no chunk has run yet (the
         # salvage path below runs it before it answers a malformed frame)
         held = None
+        # bytes to parse before the transport's next read (b"": what the
+        # reader held, fed to the parser when the connection left it)
+        data = None
         try:
             while True:
-                data = await reader.read(_READ_CHUNK)
-                if not data:
-                    break
-                self.node.stats.net_in_bytes += len(data)
+                # what a pass of the reader left to this task (server/
+                # read_pump.py wait): a parse for its own path, replies for
+                # its transport; None while the transport reads
+                got = None
+                if data is None:
+                    if client.read_id:
+                        got = await rpump.wait(client)
+                        if got is None:
+                            break
+                    else:
+                        data = await reader.read(_READ_CHUNK)
+                        if not data:
+                            break
+                        self.node.stats.read_transport_reads += 1
                 if gather is None and plane is None:
                     # the exact per-command loop (CONSTDB_SERVE_BATCH=1):
                     # its per-message parse is inside a per-operation
                     # loop and stays untimed
+                    self.node.stats.net_in_bytes += len(data)
                     with stage("intake"):
                         parser.feed(data)
+                    data = None
                     while (msg := parser.next_msg()) is not None:
                         if self._is_sync(msg):
                             # replies for commands pipelined BEFORE the
@@ -769,36 +861,13 @@ class ServerApp:
                         if not isinstance(reply, NoReply):
                             encode_into(out, reply)
                 else:
-                    # native intake stage: the C scanner owns every
-                    # leading well-formed flat frame (split + classify
-                    # in one call); whatever it stops at — partial
-                    # frame, SYNC upgrade, malformed bytes, nested array
-                    # — stays buffered for the pure drain(), which keeps
-                    # the reference behavior for those frames byte for
-                    # byte.  Every scan of this read joins ONE segment
-                    # (a pure message rides as opcode 0), and drain()
-                    # comes last.  One `intake` entry a read in the
-                    # common case: once the scanner has taken every
-                    # buffered byte there is no parse left to time, and
-                    # drain() only hands over what is queued.
-                    held = None
-                    with stage("intake"):
-                        parser.feed(data)
-                        nat = parser.native_drain() if native else None
-                        msgs = parser.drain() if nat is None else None
-                    while nat is not None:
-                        stats = self.node.stats
-                        stats.native_intake_chunks += 1
-                        stats.native_intake_msgs += len(nat[0])
-                        held = nat if held is None else \
-                            (held[0] + nat[0], held[1] + nat[1])
-                        if not parser.buffered:
-                            msgs = parser.drain()
-                            break
-                        with stage("intake"):
-                            nat = parser.native_drain()
-                            if nat is None:
-                                msgs = parser.drain()
+                    if got is None:
+                        held, msgs, err = self._intake(parser, data, native)
+                        data = None
+                    else:
+                        held, msgs, err, out = got
+                    if err is not None:
+                        raise err
                     sync_at = next((i for i, m in enumerate(msgs)
                                     if self._is_sync(m)), -1)
                     if sync_at >= 0:
@@ -818,6 +887,7 @@ class ServerApp:
                         await self._aof_ack_barrier()
                         if pump is not None:
                             pump.release(client)
+                        self._off_reader(client, writer, parser)
                         out = self._flush_out(writer, out)
                         self._upgrade_to_replica(syn, reader, writer,
                                                  parser)
@@ -835,6 +905,14 @@ class ServerApp:
                     if self._outbuf_overflow(writer):
                         return  # disconnected loudly; finally cleans up
                     await writer.drain()
+                if got is not None and client.read_id:
+                    if has_transport_state(client):
+                        # its transport reads it from here; what the reader
+                        # held is parsed before the transport's next read
+                        self._off_reader(client, writer, parser)
+                        data = b""
+                    else:
+                        rpump.release((client.read_id,))
         except (ConnectionError, OSError, asyncio.IncompleteReadError):
             pass
         except CstError as e:
@@ -872,6 +950,7 @@ class ServerApp:
                             encode_into(out, reply)
                 await self._aof_ack_barrier()
                 if sync_at >= 0:
+                    self._off_reader(client, writer, parser)
                     out = self._flush_out(writer, out)
                     self._upgrade_to_replica(syn, reader, writer, parser)
                     upgraded = True
@@ -891,6 +970,8 @@ class ServerApp:
             # the moment it can no longer deliver pushes on it
             if client.tracking:
                 self.node.tracking.unsubscribe(client)
+            if rpump is not None:
+                rpump.close_conn(client)
             if pump is not None:
                 # what the sender still holds goes to the transport, which
                 # flushes it before the FIN of the close below
@@ -900,6 +981,57 @@ class ServerApp:
             # an upgraded connection is owned by its replica link now
             if not upgraded and not writer.is_closing():
                 writer.close()
+
+    def _intake(self, parser, data: bytes, native: bool) -> tuple:
+        """Parse one read of a gathering connection -> `(held, msgs,
+        err)`: the native scans joined (None where none ran), the pure
+        remainder, and the CstError of a malformed frame (the messages
+        that parsed before it stay queued in the parser for the salvage).
+
+        The C scanner owns every leading well-formed flat frame (split +
+        classify in one call); whatever it stops at — partial frame, SYNC
+        upgrade, malformed bytes, nested array — stays buffered for the
+        pure drain(), which keeps the reference behavior for those frames
+        byte for byte.  Every scan of this read joins ONE segment (a pure
+        message rides as opcode 0), and drain() comes last.  One `intake`
+        entry a read in the common case: once the scanner has taken every
+        buffered byte there is no parse left to time, and drain() only
+        hands over what is queued."""
+        stats = self.node.stats
+        stats.net_in_bytes += len(data)
+        stage = self.node.stages.stage
+        held = None
+        try:
+            with stage("intake"):
+                parser.feed(data)
+                nat = parser.native_drain() if native else None
+                msgs = parser.drain() if nat is None else None
+            while nat is not None:
+                stats.native_intake_chunks += 1
+                stats.native_intake_msgs += len(nat[0])
+                held = nat if held is None else \
+                    (held[0] + nat[0], held[1] + nat[1])
+                if not parser.buffered:
+                    msgs = parser.drain()
+                    break
+                with stage("intake"):
+                    nat = parser.native_drain()
+                    if nat is None:
+                        msgs = parser.drain()
+        except CstError as e:
+            return held, [], e
+        return held, msgs, None
+
+    def _off_reader(self, client, writer, parser) -> None:
+        """The connection's transport reads it from here on (a SYNC
+        upgrade, RESP3 / tracking state): the bytes the reader held go to
+        `parser` — behind the messages pushed back into it, before
+        anything the transport reads next."""
+        if client.read_id:
+            held = self.read_pump.leave(client)
+            self.node.stats.net_in_bytes += len(held)
+            parser.feed(held)
+            writer.transport.resume_reading()
 
     async def _aof_ack_barrier(self) -> None:
         """fsync=always group commit before replies flush (no-op for

@@ -143,6 +143,10 @@ class NodeStats:
     # server/reply_pump.py): beside the reply sender's own counters, the
     # share of replies the sender took is posts / (posts + these)
     reply_transport_writes: int = 0
+    # reads a client connection's transport took (server/io.py): beside
+    # the reader's own counters (server/read_pump.py), the reads the
+    # reader did not take
+    read_transport_reads: int = 0
     serve_lat: deque = field(default_factory=lambda: deque(maxlen=2048))
     # overload governance (server/overload.py + server/io.py +
     # replica/link.py): client data writes shed at the maxmemory soft

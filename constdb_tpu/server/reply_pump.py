@@ -34,9 +34,10 @@ COUNTERS = ("reply_pump_posts", "reply_pump_bytes", "reply_transport_writes",
             "reply_pump_spills", "reply_pump_wakes", "reply_pump_send_us")
 
 
-def _has_state(client) -> bool:
+def has_transport_state(client) -> bool:
     """State whose writes go through the transport: RESP3 replies and
-    tracking pushes (server/tracking.py _send)."""
+    tracking pushes (server/tracking.py _send).  Its reads do too
+    (server/read_pump.py)."""
     return bool(client.tracking or client.resp3)
 
 
@@ -97,11 +98,11 @@ class ReplyPump:
         """`out` to the client on whichever path it is on, switching when
         its state asks for it."""
         if client.on_pump:
-            if not _has_state(client):
+            if not has_transport_state(client):
                 self.post(out, (client.reply_id,), (len(out),))
                 return
             self.to_transport(client)
-        elif client.reply_id and not _has_state(client) and \
+        elif client.reply_id and not has_transport_state(client) and \
                 client.writer.transport.get_write_buffer_size() == 0:
             held = self._ext.reply_resume(self._h, client.reply_id)
             if held is None:

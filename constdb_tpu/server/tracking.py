@@ -91,7 +91,7 @@ class ClientConn:
 
     __slots__ = ("cid", "addr", "writer", "resp3", "tracking", "prefixes",
                  "tracked", "pend", "_timer", "created", "reply_id",
-                 "on_pump")
+                 "on_pump", "read_id")
 
     def __init__(self, cid: int, addr: str, writer=None, created=0.0):
         self.cid = cid
@@ -108,6 +108,9 @@ class ClientConn:
         # its replies go through the sender now (server/reply_pump.py)
         self.reply_id = 0
         self.on_pump = False
+        # the reader's id for this connection while the reader reads it
+        # (server/read_pump.py; 0: its transport reads it)
+        self.read_id = 0
 
     def describe(self) -> str:
         mode = {TRACK_OFF: "off", TRACK_DEFAULT: "on",

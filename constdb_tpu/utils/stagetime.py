@@ -24,7 +24,8 @@ taken through it:
   `loop_poll` (`loop_poll_events` sums the ready fds it returned).  No
   other stage is open there, since none spans an `await`, so a window of
   the loop's thread is Σ stage self times + `loop_poll` + the rest
-  (asyncio's callbacks, the transports' recv/send, time off the CPU).
+  (asyncio's callbacks, the transports' recv/send where the extension's
+  reader and sender do not take them, time off the CPU).
   `gc` is entered from `gc.callbacks` on whichever thread collects,
   nested under the stage it interrupted, tagged with the generation.
   `loop_stats()` reads, at INFO time and from any thread, the loop
@@ -65,7 +66,10 @@ from time import perf_counter_ns
 # replication link (replica/link.py, replica/coalesce.py): a peer's stream
 # in (`repl_ingest` per socket read, `repl_flush` per landed batch) and the
 # node's own log out (`repl_push` per drained run and per wake-up's tail);
-# then the loop's poll (`loop_poll`, one entry per iteration) and the
+# then the reader's take (`read_take`, one entry a take of
+# server/read_pump.py: the eventfd read, the take call and the loop over
+# what it delivered, less the `intake` and pass work nested in it); then
+# the loop's poll (`loop_poll`, one entry per iteration) and the
 # garbage collector (`gc`, one entry per collection).  `list_index`: a list
 # key's ordered index brought up to date and read (store/keyspace.py
 # ListIndex) — by a push, LRANGE / LLEN / LREM on either path
@@ -73,7 +77,7 @@ STAGES = ("intake", "gather", "plan", "read_batch", "read_miss", "exec",
           "list_index", "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
           "mirror_rebuild", "mirror_patch", "state_alloc", "d2h_flush",
           "reply_write", "repl_ingest", "repl_flush", "repl_push",
-          "loop_poll", "gc")
+          "read_take", "loop_poll", "gc")
 MAX_ANNOTATION = 40     # benchmark/trace_reduce.py cuts a host name at 48
 
 _INDEX = {name: i for i, name in enumerate(STAGES)}

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Lay the twenty-one stage-clock metrics over a SCRATCH copy of the tree:
+"""Lay the twenty-four stage-clock metrics over a SCRATCH copy of the tree:
 their files into <tree>/benchmark/layers/, their `per_layer` entries at
 the end of <tree>/BENCHMARK.json (README.md beside this file says why
 they are not in the checkout's own manifest yet).

@@ -19,6 +19,7 @@
 #include "wire.cpp"    // REPLBATCH blob columns (py_wire_{pack,unpack}_blobs)
 #include "aof.cpp"     // AOF segment record scanner + frame decode (py_aof_scan)
 #include "reply.cpp"   // reply sender thread (py_reply_*)
+#include "read.cpp"    // client reader thread (py_read_*)
 
 // Build stamp over native/*.cpp, generated by the Makefile into
 // build/abi_stamp.h.  utils/native_tables.py recomputes the hash from
@@ -380,6 +381,24 @@ PyMethodDef methods[] = {
      "reply_take_spills(sender) -> [(id, bytes)] handed back to the loop"},
     {"reply_stats", py_reply_stats, METH_VARARGS,
      "reply_stats(sender) -> (posts, bytes, wakes, spills, send_us)"},
+    {"read_new", py_read_new, METH_NOARGS,
+     "read_new() -> client reader (server/read_pump.py)"},
+    {"read_start", py_read_start, METH_VARARGS,
+     "read_start(reader) -> eventfd the loop reads for bytes to take"},
+    {"read_stop", py_read_stop, METH_VARARGS,
+     "read_stop(reader): stop and join the reader thread"},
+    {"read_open", py_read_open, METH_VARARGS,
+     "read_open(reader, fd) -> connection id (the reader dups fd)"},
+    {"read_take", py_read_take, METH_VARARGS,
+     "read_take(reader) -> [(id, bytes | None)] delivered since the last"
+     " take (None: the end of the stream)"},
+    {"read_release", py_read_release, METH_VARARGS,
+     "read_release(reader, ids): the reader reads these connections again"},
+    {"read_detach", py_read_detach, METH_VARARGS,
+     "read_detach(reader, id, handback) -> bytes the reader held | None"},
+    {"read_stats", py_read_stats, METH_VARARGS,
+     "read_stats(reader) -> (takes, bytes, recvs, wakes, recv_us,"
+     " handbacks)"},
     {"abi_stamp", py_abi_stamp, METH_NOARGS,
      "abi_stamp() -> sha256 over the native/*.cpp sources this .so was "
      "built from"},

@@ -130,24 +130,28 @@ def msgs_of(seg) -> list:
 def record_gathered_order(app) -> tuple:
     """Wrap the app's hand-over and the gather's pass so the test learns
     the order the passes ran the commands in: -> (order, passes) where
-    order = [(cid, msg)] and passes = [messages a pass held]."""
-    order, passes, waiting = [], [], []
+    order = [(cid, msg)] and passes = [messages a pass held].  A pass
+    reads its segments as the reader joined them (their connection
+    beside them) or as tasks handed them over (their connection noted
+    here)."""
+    order, passes, owner = [], [], {}
     gather = app._gather
     run_chunk, run_pass = app._run_chunk, gather._run_pass
 
     async def recording_run_chunk(plane, g, seg, out, client):
-        mine = [(client.cid, m) for m in msgs_of(seg)]
         if g.keeps_own_path(client, *seg):
-            order.extend(mine)
+            order.extend((client.cid, m) for m in msgs_of(seg))
         else:
-            waiting.append(mine)
+            owner[id(seg[1])] = client.cid
         return await run_chunk(plane, g, seg, out, client)
 
     def recording_run_pass():
-        passes.append(sum(len(w) for w in waiting))
-        for w in waiting:
-            order.extend(w)
-        waiting.clear()
+        n = 0
+        for ops, payloads, fut, client in gather.segs:
+            cid = client.cid if fut is None else owner.pop(id(payloads))
+            order.extend((cid, m) for m in msgs_of((ops, payloads)))
+            n += len(payloads)
+        passes.append(n)
         run_pass()
 
     app._run_chunk = recording_run_chunk
@@ -195,6 +199,7 @@ async def drive_gathered(tmp_path, kind: str, work: list, shed_work: list):
         cids = [c for c in sorted(app.client_conns)]
         return {"raw": [bytes(r) for r in raw], "order": order, "cut": cut,
                 "passes": passes, "cids": cids,
+                "reader": app.read_pump is not None,
                 "canonical": node.canonical(), "repl": repl_entries(node),
                 "stats": node.stats, "info": info_of(node)}
     finally:
@@ -254,7 +259,9 @@ def test_gathered_node_equals_per_command_node_fed_the_gathered_order(
     assert st.serve_gather_conns * depth == total
     assert int(info["serve_gather_passes"]) == len(passes)
     assert int(info["span_gather_us"]) > 0
-    assert int(info["span_gather_n"]) == len(passes) + total // depth
+    # one entry a pass, and one a hand-over: none where the reader reads
+    hand_overs = 0 if got["reader"] else total // depth
+    assert int(info["span_gather_n"]) == len(passes) + hand_overs
     if n_conns == 1:
         assert set(passes) == {depth}     # its own chunks, as before
     elif n_conns >= 7:

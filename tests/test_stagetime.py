@@ -26,7 +26,7 @@ Pinned here:
     the stages are gone;
   * every counter a per-layer metric names — the twenty-four in
     BENCHMARK.json (the gather's four and the `reg` rows, and the list
-    index's and the plane grows' five among them) and the nineteen specs
+    index's and the plane grows' five among them) and the twenty-four specs
     of docs/stage_layers/ — is an INFO key of a
     device-engine node, and the existing readers turn each spec into a
     number.
@@ -80,6 +80,9 @@ CELLS = ["ycsb-b", "ycsb-a", "aa-3node-ycsb-a", "memtier-default"]
 # the reply write's three, in every cell since the reply sender
 REPLY_SPECS = {"reply_write_us_per_op.serve", "reply_pump_share.serve",
                "reply_sender_busy_share.serve"}
+# the reader's three, in all five cells
+READ_SPECS = {"read_pump_share.serve", "reader_busy_share.serve",
+              "read_take_us_per_op.serve"}
 
 
 @pytest.fixture
@@ -522,7 +525,7 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
     want += LINK_COUNTERS + GATHER_COUNTERS + MICRO_COUNTERS
     want += ["loop_poll_events"] + LIST_COUNTERS + GROW_COUNTERS
-    assert len(want) == 2 * 22 + 8 + 6 + 7 + 5 + 4 + 3 + 1 + 2 + 5
+    assert len(want) == 2 * 23 + 8 + 6 + 7 + 5 + 4 + 3 + 1 + 2 + 5
     assert STAGES.index("gather") == 1
     assert STAGES[-2:] == ("loop_poll", "gc")
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
@@ -574,14 +577,15 @@ def test_pipelined_chunk_through_a_socket_moves_the_loop_stages(tmp_path):
             await app.close()
 
     info, wall_us, st = asyncio.run(main())
-    for s in ("intake", "gather", "plan", "read_batch", "serve_flush",
-              "reply_write"):
+    for s in ("read_take", "intake", "gather", "plan", "read_batch",
+              "serve_flush", "reply_write"):
         assert info[f"span_{s}_us"] > 0 and info[f"span_{s}_n"] > 0, s
-    # one connection, three reads: three passes of 28 messages, each a
-    # hand-over and a pass of the gather
+    # one connection, three reads: three takes of the reader, each a pass
+    # of the gather of 28 messages (no hand-over: the take joins them)
     assert info["serve_gather_passes"] == info["serve_gather_conns"] == 3
     assert info["serve_gather_msgs"] == 3 * 28
-    assert info["span_gather_n"] == 6 and info["serve_lone_cmds"] == 0
+    assert info["span_gather_n"] == 3 and info["serve_lone_cmds"] == 0
+    assert info["span_read_take_n"] >= 3
     assert info["span_read_miss_n"] > 0
     assert info["span_serve_flush_n"] == st.serve_flushes
     total = sum(info[f"span_{s}_us"] for s in STAGES)
@@ -788,7 +792,7 @@ def test_documented_totals_still_read_above_zero():
 
 
 def layer_specs() -> list:
-    """Every per-layer metric BENCHMARK.json names, and the nineteen the
+    """Every per-layer metric BENCHMARK.json names, and the twenty-four the
     stage counters are for (docs/stage_layers/: a `benchmark` PR moves
     them under benchmark/layers/ — see docs/stage_layers/README.md)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -826,9 +830,9 @@ def test_every_counter_a_layer_file_names_is_in_info():
     specs = layer_specs()
     # the five of the replication link, the four of the gather, the five
     # of the list index and the engine (redis-benchmark's cell);
-    # of docs/stage_layers/ fifteen, the event loop's four and the reply
-    # sender's two
-    assert len(specs) == 10 + 5 + 4 + 5 + 15 + 4 + 2
+    # of docs/stage_layers/ fifteen, the event loop's four, the reply
+    # sender's two and the reader's three
+    assert len(specs) == 10 + 5 + 4 + 5 + 15 + 4 + 2 + 3
     mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
     assert sorted(s["name"] for s in mine) == [
         "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
@@ -859,8 +863,8 @@ def benchmark_module(name: str):
 
 
 def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
-    """docs/stage_layers/overlay.py on a scratch copy: 21 files beside the
-    19, 21 entries at the END of per_layer, nothing else changed — and
+    """docs/stage_layers/overlay.py on a scratch copy: 24 files beside the
+    24, 24 entries at the END of per_layer, nothing else changed — and
     the reason they are not in the checkout's own manifest: a traced
     line without them (the parent commit's) is refused."""
     import importlib.util
@@ -874,7 +878,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     added = mod.overlay(str(tmp_path))
-    assert len(added) == 21 and mod.overlay(str(tmp_path)) == []
+    assert len(added) == 24 and mod.overlay(str(tmp_path)) == []
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
     with open(tmp_path / "BENCHMARK.json") as f:
@@ -885,7 +889,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
     assert [m["name"] for m in after["per_layer"][n:]] == added
     assert {k: v for k, v in after.items() if k != "per_layer"} == \
         {k: v for k, v in before.items() if k != "per_layer"}
-    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == n + 21
+    assert len(os.listdir(tmp_path / "benchmark" / "layers")) == n + 24
     # the parent's traced line: the ten old metrics, none of the new
     line = {"correct": True, "attempted": 10, "failed": 0,
             "metrics": {m["name"]: {"value": 1.0, "unit": m["unit"]}
@@ -897,7 +901,7 @@ def test_overlay_makes_a_manifest_the_contract_accepts(tmp_path):
             "compared": {"reads_wrong": {"value": 0, "limit": 0}}}
     assert validate.check_line(line, before, "ycsb-b", True) == []
     refused = validate.check_line(line, after, "ycsb-b", True)
-    assert len(refused) == 21 and all("is missing" in e for e in refused)
+    assert len(refused) == 24 and all("is missing" in e for e in refused)
 
 
 def test_stage_layer_specs_read_through_the_benchmarks_readers():
@@ -924,21 +928,24 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
         assert os.path.basename(path) == spec["name"] + ".json"
         assert spec["workloads"] == (
             CELLS if spec["name"] in LOOP_SPECS | REPLY_SPECS
-            else ["ycsb-b"])
+            else CELLS + ["redis-benchmark-default"]
+            if spec["name"] in READ_SPECS else ["ycsb-b"])
         assert spec["moves"] == "served_ops"
         got[spec["name"]] = readers.read(spec, window, None, {})
     # no reply left this node by a socket: the sender's share has nothing
     # to read (test_loop_specs_read_their_counters reads it)
     assert got.pop("reply_pump_share.serve") is None
+    # nor did a byte reach it by a socket: the reader's share neither
+    assert got.pop("read_pump_share.serve") is None
     assert all(isinstance(v, float) for v in got.values()), got
     per_op = [v for k, v in got.items() if k.endswith("_us_per_op.serve")]
-    assert len(per_op) == 10
+    assert len(per_op) == 11
     assert got["device_merged_row_share.serve"] == 100.0
     assert got["mirror_patch_share.serve"] == 100.0     # 1 patch, 0 rebuilds
     assert got["read_scan_native_share.serve"] == 100.0  # 2 of 2 misses
-    # every stage is in exactly one of the ten per-op metrics (a patch in
-    # the engine's) or in the rebuild share, so the ten add up to the
-    # traced share less rebuilds
+    # every stage is in exactly one of the eleven per-op metrics (a patch
+    # in the engine's) or in the rebuild share, so the eleven add up to
+    # the traced share less rebuilds
     traced_us = got["loop_traced_share.serve"] * 2.0 * 1e4
     rebuild_us = got["mirror_rebuild_stall_share.serve"] * 2.0 * 1e4
     gc_us = got["gc_pause_share.serve"] * 2.0 * 1e4
@@ -953,25 +960,33 @@ def test_stage_layer_specs_read_through_the_benchmarks_readers():
 @pytest.mark.parametrize("name,expected", [
     ("reply_pump_share.serve", 100 * 2_970 / 3_000),  # 30 transport writes
     ("reply_sender_busy_share.serve", 100 * 0.8 / 2.0),
+    ("read_pump_share.serve", 100 * 95_000 / 100_000),  # 5 kB by a link
+    ("reader_busy_share.serve", 100 * 0.6 / 2.0),
+    ("read_take_us_per_op.serve", 48_000 / 24_000),
     ("loop_poll_share.serve", 100 * 0.5 / 2.0),       # 0.5 s of a 2 s window
     ("loop_cpu_share.serve", 100 * 1.2 / 2.0),
     ("loop_preempt_per_kop.serve", 1000 * 30 / 24_000),
     ("gc_pause_share.serve", 100 * 0.01 / 2.0),
-    ("loop_traced_share.serve", 100 * (0.3 + 0.01) / 2.0)])
+    ("loop_traced_share.serve", 100 * (0.3 + 0.01 + 0.048) / 2.0)])
 def test_loop_specs_read_their_counters(name, expected):
-    """Each event-loop and reply-sender spec through `readers.read` over a
-    window whose deltas are known: 2 s, 24,000 operations."""
+    """Each event-loop, reply-sender and reader spec through
+    `readers.read` over a window whose deltas are known: 2 s, 24,000
+    operations."""
     readers = benchmark_module("readers")
     with open(os.path.join(ROOT, "docs", "stage_layers",
                            f"{name}.json")) as f:
         spec = json.load(f)
     before = {f"span_{s}_us": 1_000 for s in STAGES}
     before.update(loop_cpu_us=5, loop_nivcsw=7, reply_pump_posts=30,
-                  reply_transport_writes=0, reply_pump_send_us=100)
+                  reply_transport_writes=0, reply_pump_send_us=100,
+                  read_pump_bytes=500, total_net_input_bytes=1_000,
+                  read_pump_recv_us=40)
     after = dict(before, span_loop_poll_us=501_000, span_gc_us=11_000,
                  span_plan_us=301_000, loop_cpu_us=1_200_005,
                  loop_nivcsw=37, reply_pump_posts=3_000,
-                 reply_transport_writes=30, reply_pump_send_us=800_100)
+                 reply_transport_writes=30, reply_pump_send_us=800_100,
+                 read_pump_bytes=95_500, total_net_input_bytes=101_000,
+                 read_pump_recv_us=600_040, span_read_take_us=49_000)
     window = {"info_before": before, "info_after": after, "ops": 24_000,
               "kops": 24.0, "seconds": 2.0}
     assert readers.read(spec, window, None, {}) == pytest.approx(expected)
