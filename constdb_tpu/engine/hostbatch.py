@@ -268,6 +268,48 @@ def _merge_el(store: KeySpace, rows: np.ndarray, at: np.ndarray,
             list(map(store.el_member.__getitem__, rws.tolist())))
 
 
+def _create_keys(store: KeySpace, batch: ColumnarBatch, kid_of: np.ndarray,
+                 missing: np.ndarray, n0: int, st: MergeStats,
+                 resident: bool) -> None:
+    """resolve_keys' creation block: intern the batch keys at `missing`
+    (the table holds `n0` keys), append one table row per new key and
+    write the new ids into `kid_of`."""
+    keys = batch.keys if len(missing) == len(kid_of) else \
+        list(map(batch.keys.__getitem__, missing.tolist()))
+    ids, n_new = store.key_index.get_or_insert_batch(keys)
+    kid_of[missing] = ids
+    # a raw op-stream batch may repeat a key: append one row per new
+    # id, values from its first occurrence (np.unique's sorted order
+    # IS insertion order — interner ids grow with first occurrence)
+    uniq_ids, first = np.unique(ids, return_index=True)
+    pos = missing[first]
+    # interner ids must be exactly the next table block — checked
+    # BEFORE the append mutates the table (CHECK-THEN-MUTATE: a
+    # failure after append_block would strand half-created rows;
+    # and a real raise, because python -O strips asserts)
+    if len(uniq_ids) != n_new or int(uniq_ids[0]) != n0 or \
+            int(uniq_ids[-1]) != n0 + n_new - 1:
+        span = f"[{int(uniq_ids[0])}, {int(uniq_ids[-1])}]" \
+            if len(uniq_ids) else "[]"
+        raise RuntimeError(
+            f"key interner issued non-contiguous new ids {span} "
+            f"(n={len(uniq_ids)}) for block [{n0}, {n0 + n_new - 1}]")
+    store.keys.append_block(
+        n_new,
+        enc=batch.key_enc[pos], ct=batch.key_ct[pos], mt=0,
+        dt=batch.key_dt[pos], expire=0, rv_t=0, rv_node=0, cnt_sum=0)
+    store.key_bytes.extend(map(batch.keys.__getitem__, pos.tolist()))
+    store.reg_val.extend([None] * n_new)
+    st.keys_created += n_new
+    if resident:
+        # created rows carry batch first-occurrence values on the
+        # host but neutral zeros on the device mirror; the batch rows
+        # merging in reconstruct them, EXCEPT for conflict-skipped
+        # duplicates — clear host values so both sides start neutral
+        store.keys.ct[uniq_ids] = 0
+        store.keys.dt[uniq_ids] = 0
+
+
 def resolve_keys(store: KeySpace, batch: ColumnarBatch, st: MergeStats,
                  resident: bool = False) -> np.ndarray:
     """batch key position -> local kid (-1 on type conflict); bulk-creates
@@ -283,40 +325,14 @@ def resolve_keys(store: KeySpace, batch: ColumnarBatch, st: MergeStats,
     if n == 0:
         return np.zeros(0, dtype=_I64)
     n0 = store.keys.n
-    # one native batch call: intern every key; new ids ARE the new rows
-    kid_of, n_new = store.key_index.get_or_insert_batch(batch.keys)
-    if n_new:
-        # a raw op-stream batch may repeat a key: append one row per new
-        # id, values from its first occurrence (np.unique's sorted order
-        # IS insertion order — interner ids grow with first occurrence)
-        created = np.nonzero(kid_of >= n0)[0]
-        uniq_ids, first = np.unique(kid_of[created], return_index=True)
-        pos = created[first]
-        # interner ids must be exactly the next table block — checked
-        # BEFORE the append mutates the table (CHECK-THEN-MUTATE: a
-        # failure after append_block would strand half-created rows;
-        # and a real raise, because python -O strips asserts)
-        if len(uniq_ids) != n_new or int(uniq_ids[0]) != n0 or \
-                int(uniq_ids[-1]) != n0 + n_new - 1:
-            span = f"[{int(uniq_ids[0])}, {int(uniq_ids[-1])}]" \
-                if len(uniq_ids) else "[]"
-            raise RuntimeError(
-                f"key interner issued non-contiguous new ids {span} "
-                f"(n={len(uniq_ids)}) for block [{n0}, {n0 + n_new - 1}]")
-        store.keys.append_block(
-            n_new,
-            enc=batch.key_enc[pos], ct=batch.key_ct[pos], mt=0,
-            dt=batch.key_dt[pos], expire=0, rv_t=0, rv_node=0, cnt_sum=0)
-        store.key_bytes.extend(map(batch.keys.__getitem__, pos.tolist()))
-        store.reg_val.extend([None] * n_new)
-        st.keys_created += n_new
-        if resident:
-            # created rows carry batch first-occurrence values on the
-            # host but neutral zeros on the device mirror; the batch rows
-            # merging in reconstruct them, EXCEPT for conflict-skipped
-            # duplicates — clear host values so both sides start neutral
-            store.keys.ct[uniq_ids] = 0
-            store.keys.dt[uniq_ids] = 0
+    # one native batch call resolves every key; the keys it did not find
+    # enter the table under the stage `key_create`: interned in one more
+    # call (new ids ARE the new rows), the table's block appended
+    kid_of = store.key_index.lookup_batch(batch.keys)
+    missing = np.flatnonzero(kid_of < 0)
+    if len(missing):
+        with store.stage("key_create"):
+            _create_keys(store, batch, kid_of, missing, n0, st, resident)
     # conflict check over ALL positions: duplicate occurrences of a key
     # created above must also match the enc the first occurrence chose
     bad = np.nonzero(store.keys.enc[kid_of] != batch.key_enc)[0]

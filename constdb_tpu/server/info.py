@@ -172,6 +172,8 @@ def _section_stats(node, out):
     # worker gauges (a shard worker's cache lives in its process)
     out.append(("serve_reads_coalesced", st.serve_reads_coalesced))
     out.append(("serve_read_flushes", st.serve_read_flushes))
+    out.append(("serve_read_flushes_created", st.serve_read_flushes_created))
+    out.append(("serve_keys_created", st.serve_keys_created))
     # native intake stage (native/intake.cpp + server/io.py): chunks the
     # C scanner split+classified, and the frames it emitted as opcodes.
     # Both stay zero with CONSTDB_NATIVE_INTAKE=0 / CONSTDB_NO_NATIVE=1

@@ -108,6 +108,12 @@ class NodeStats:
     # deltas into the parent's cache counters (server/serve_shards.py).
     serve_reads_coalesced: int = 0
     serve_read_flushes: int = 0
+    # ... of which the lands forced because a key the read batch read was
+    # CREATED by the pending run (it has no row in the landed table yet)
+    serve_read_flushes_created: int = 0
+    # keys the coalesced served path created (server/serve.py run_chunk:
+    # its runs' landings and its per-command executions alike)
+    serve_keys_created: int = 0
     # planned reads the reply cache could not answer whose reply the
     # stitch wrote as wire bytes straight from the gathers (resp/codec.py
     # encode_rows_into and its single-value twins; absent-key constants
@@ -255,7 +261,6 @@ class Node:
         self.alias = alias
         self.addr = addr
         self.hlc = HLC() if clock is None else HLC(clock)
-        self.ks = self._make_keyspace()
         self.repl_log = ReplLog(repl_log_cap)
         self.events = EventBus()
         self.engine = engine if engine is not None else CpuMergeEngine()
@@ -263,6 +268,7 @@ class Node:
         # span_<name>_us / span_<name>_n): a device engine brings one
         # that also writes trace spans, any other engine gets counters
         self.stages = getattr(self.engine, "stages", None) or StageClock()
+        self.ks = self._make_keyspace()
         self.stats = NodeStats()
         # undoable local counter ops (CNTUNDO — server/commands.py)
         self.undo = CounterUndoLog()
@@ -328,6 +334,7 @@ class Node:
         ks = KeySpace()
         from .events import EVENT_DELETED
         ks.on_key_delete = lambda: self.events.trigger(EVENT_DELETED)
+        ks.stage = self.stages.stage
         return ks
 
     # ------------------------------------------------------------ execution

@@ -299,8 +299,18 @@ class ServeCoalescer:
         `solo`: one byte per message, non-zero where the message arrived
         alone on its connection (the module docstring's company rule),
         or None where none did."""
+        self._plan_counted(self._plan_chunk, msgs, out, uuids, spans, solo)
+
+    def _plan_counted(self, plan, *args) -> None:
+        """One chunk's plan under its `plan` stage; the keys it created,
+        by its runs' landings and its per-command executions alike, count
+        in INFO serve_keys_created."""
+        keys = self.node.ks.keys
+        n0 = keys.n
         with self._stage("plan"):
-            self._plan_chunk(msgs, out, uuids, spans, solo)
+            plan(*args)
+        if self.node.ks.keys is keys:
+            self.node.stats.serve_keys_created += keys.n - n0
 
     def _plan_chunk(self, msgs: list, out: bytearray, uuids: list,
                     spans: list, solo: bytes = None) -> None:
@@ -447,8 +457,8 @@ class ServeCoalescer:
         pins the differential).  `spans` and `solo` as run_chunk's.
         Never used on the sharded plane — io.py builds a coalescer only
         when no plane is active — so there are no pre-minted uuids."""
-        with self._stage("plan"):
-            self._plan_native_chunk(ops, payloads, out, spans, solo)
+        self._plan_counted(self._plan_native_chunk, ops, payloads, out,
+                           spans, solo)
 
     def _plan_native_chunk(self, ops: bytes, payloads: list,
                            out: bytearray, spans: list = None,
@@ -981,6 +991,10 @@ class ServeCoalescer:
                        for sp in map(specs.__getitem__, mine)):
                     over = frozenset(mine)
                 else:
+                    # a key no landed row holds yet was created by the run
+                    lookup = self.ks.key_index.lookup
+                    if any(lookup(specs[j][4]) < 0 for j in mine):
+                        st.serve_read_flushes_created += 1
                     self.flush()
                     st.serve_read_flushes += 1
         ks = self.ks

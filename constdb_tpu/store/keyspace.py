@@ -35,6 +35,7 @@ from __future__ import annotations
 import heapq
 import threading
 import zlib
+from contextlib import nullcontext
 from typing import Iterator, Optional
 
 import numpy as np
@@ -302,6 +303,10 @@ class _ElCols(Columns):
         super().__init__({"kid": _I64, "add_t": _I64, "add_node": _I64, "del_t": _I64}, cap=8192)
 
 
+def _untimed(name: str, tag: str = "") -> nullcontext:
+    return nullcontext()
+
+
 class KeySpace:
     NODE_RANK_BITS = 20  # up to ~1M distinct node ids per cluster lifetime
     MEMBER_BITS = 32     # up to ~4G distinct member byte-strings
@@ -314,6 +319,11 @@ class KeySpace:
 
     def __init__(self) -> None:
         self.keys = _KeyCols()
+        # the owning node's stage clock entry (utils/stagetime.py
+        # StageClock.stage): keys entering the table are the stage
+        # `key_create`, here and in engine/hostbatch.py resolve_keys.
+        # Untimed until a node adopts the keyspace
+        self.stage = _untimed
         # exact byte total of every blob side list (key bytes, register
         # values, element members/values) — maintained incrementally by
         # BlobList through every mutation path; `used_bytes` folds it
@@ -460,11 +470,12 @@ class KeySpace:
         return self.keys.n
 
     def create_key(self, key: bytes, enc: int, ct: int, dt: int = 0) -> int:
-        kid = self.keys.append(enc=enc, ct=ct, mt=0, dt=dt, expire=0,
-                               rv_t=0, rv_node=0, cnt_sum=0)
-        self.key_bytes.append(key)
-        self.reg_val.append(None)
-        iid = self.key_index.get_or_insert(key)
+        with self.stage("key_create"):
+            kid = self.keys.append(enc=enc, ct=ct, mt=0, dt=dt, expire=0,
+                                   rv_t=0, rv_node=0, cnt_sum=0)
+            self.key_bytes.append(key)
+            self.reg_val.append(None)
+            iid = self.key_index.get_or_insert(key)
         assert iid == kid, f"key index desync: {iid} != {kid}"
         return kid
 

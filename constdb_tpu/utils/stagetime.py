@@ -72,10 +72,15 @@ from time import perf_counter_ns
 # the loop's poll (`loop_poll`, one entry per iteration) and the
 # garbage collector (`gc`, one entry per collection).  `list_index`: a list
 # key's ordered index brought up to date and read (store/keyspace.py
-# ListIndex) — by a push, LRANGE / LLEN / LREM on either path
+# ListIndex) — by a push, LRANGE / LLEN / LREM on either path.
+# `key_create`: keys entering the key table — the creation block of a
+# merge's key resolution (engine/hostbatch.py resolve_keys: the interner's
+# insert of the new keys, the table's block append) and a per-command
+# create (store/keyspace.py KeySpace.create_key)
 STAGES = ("intake", "gather", "plan", "read_batch", "read_miss", "exec",
-          "list_index", "serve_flush", "stage_rows", "h2d", "dispatch", "host_twin",
-          "mirror_rebuild", "mirror_patch", "state_alloc", "d2h_flush",
+          "list_index", "key_create", "serve_flush", "stage_rows", "h2d",
+          "dispatch", "host_twin", "mirror_rebuild", "mirror_patch",
+          "state_alloc", "d2h_flush",
           "reply_write", "repl_ingest", "repl_flush", "repl_push",
           "read_take", "loop_poll", "gc")
 MAX_ANNOTATION = 40     # benchmark/trace_reduce.py cuts a host name at 48

@@ -73,6 +73,9 @@ MICRO_COUNTERS = ["micro_win_scatters", "micro_src_scatters",
 LIST_COUNTERS = ["list_inserts", "list_pos_bytes_sum"]
 GROW_COUNTERS = [f"mirror_grows_{f}" for f in ("cnt", "el", "env", "reg",
                                                "tns")]
+# keys created under load (server/serve.py run_chunk) and the read batches
+# that landed their run to read a key it created, beside `key_create`
+KEY_COUNTERS = ["serve_keys_created", "serve_read_flushes_created"]
 # the event loop's four metrics (PR 39), specified for every cell
 LOOP_SPECS = {"loop_poll_share.serve", "loop_cpu_share.serve",
               "loop_preempt_per_kop.serve", "gc_pause_share.serve"}
@@ -525,7 +528,8 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
              for f in JOURNAL_FAMILIES] + ["mirror_patch_overflows"]
     want += LINK_COUNTERS + GATHER_COUNTERS + MICRO_COUNTERS
     want += ["loop_poll_events"] + LIST_COUNTERS + GROW_COUNTERS
-    assert len(want) == 2 * 23 + 8 + 6 + 7 + 5 + 4 + 3 + 1 + 2 + 5
+    want += KEY_COUNTERS
+    assert len(want) == 2 * 24 + 8 + 6 + 7 + 5 + 4 + 3 + 1 + 2 + 5 + 2
     assert STAGES.index("gather") == 1
     assert STAGES[-2:] == ("loop_poll", "gc")
     assert {k: info.get(k) for k in want} == dict.fromkeys(want, 0)
@@ -537,7 +541,7 @@ def test_info_of_a_fresh_node_lists_every_counter_at_zero():
     cpu = info_of(Node(node_id=2))
     assert all(cpu[f"span_{s}_us"] == 0 for s in STAGES)
     assert all(cpu[k] == 0 for k in LINK_COUNTERS + GATHER_COUNTERS
-               + LIST_COUNTERS)
+               + LIST_COUNTERS + KEY_COUNTERS)
     assert "merge_rows_dev_el" not in cpu
     assert not any(k in cpu for k in MICRO_COUNTERS)
 
@@ -829,10 +833,10 @@ def test_every_counter_a_layer_file_names_is_in_info():
         conf.COMPILE_CACHE["dir"] = had
     specs = layer_specs()
     # the five of the replication link, the four of the gather, the five
-    # of the list index and the engine (redis-benchmark's cell);
-    # of docs/stage_layers/ fifteen, the event loop's four, the reply
-    # sender's two and the reader's three
-    assert len(specs) == 10 + 5 + 4 + 5 + 15 + 4 + 2 + 3
+    # of the list index and the engine (redis-benchmark's cell), the two
+    # of keys created under load (ycsb-d); of docs/stage_layers/ fifteen,
+    # the event loop's four, the reply sender's two and the reader's three
+    assert len(specs) == 10 + 5 + 4 + 5 + 2 + 15 + 4 + 2 + 3
     mine = [s for s in specs if s["workloads"] == ["memtier-default"]]
     assert sorted(s["name"] for s in mine) == [
         "gather_us_per_op.serve", "gathered_ops_per_pass.serve",
@@ -843,6 +847,9 @@ def test_every_counter_a_layer_file_names_is_in_info():
         "cnt_rows_dev_share.serve", "el_rows_dev_share.serve",
         "list_index_us_per_op.serve", "list_pos_bytes_per_insert.serve",
         "mirror_grows_per_kop.serve"]
+    assert sorted(s["name"] for s in specs if s["workloads"] == ["ycsb-d"]
+                  ) == ["created_read_flushes_per_kop.serve",
+                        "key_create_us_per_op.serve"]
     link = [s for s in specs if s["layer"] == "replication link"]
     assert len(link) == 5 and all(
         s["workloads"] == ["aa-3node-ycsb-a"] for s in link)
