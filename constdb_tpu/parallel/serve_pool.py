@@ -254,18 +254,18 @@ def _serve_worker_main(conn, shard: int, n_shards: int,
                 node.node_id = msg[1]
                 node.alias = msg[2]
                 conn.send(("ok", None))
+            elif cmd == "freeze":
+                # a restore or full sync has landed in this shard: its
+                # table leaves every later collection (utils/stagetime.py)
+                conn.send(("ok", node.freeze_heap()))
             elif cmd == "reset":
                 # state-clearing full resync: fresh keyspace, tap kept
-                eng = node.engine
-                if hasattr(eng, "discard_resident"):
-                    eng.discard_resident()
-                node.ks = node._make_keyspace()
+                # ("freeze" follows once the new state lands)
+                node.replace_keyspace()
                 wire_ks()
                 node.repl_log = _TapLog()
-                # cached replies describe the wiped shard state
-                node.read_cache.clear()
                 if coal is not None:
-                    coal._reset_caches()
+                    coal.reset_caches()
                 conn.send(("ok", None))
             elif cmd == "ping":
                 conn.send(("ok", None))

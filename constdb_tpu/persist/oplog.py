@@ -2091,9 +2091,7 @@ def recover(node, aof_dir: str, boot_snapshot: str = "",
             info.source = f"{label}-snapshot"
             break
         except _SNAPSHOT_LOAD_ERRORS as e:
-            if hasattr(node.engine, "discard_resident"):
-                node.engine.discard_resident()
-            node.ks = node._make_keyspace()
+            node.replace_keyspace()
             _quarantine_snapshot(node, candidate, e)
             if candidate == base:
                 # the base covered every pre-rewrite frame the log's

@@ -1641,6 +1641,9 @@ class ReplicaLink:
                 path, size)
             self._finish_sync(path, applied_rows, replica_rows, repl_last,
                               "snapshot")
+            # the synced table is the node's until a wipe: no later
+            # collection walks it again
+            await self.app.freeze_heap()
         finally:
             # per-download spill names are never overwritten by a retry,
             # so EVERY exit — a torn download included — must drop the

@@ -205,8 +205,10 @@ class _Reader:
 
 def _stub_items(entry) -> tuple:
     """Synthetic wire-frame items for the group encoders: they index
-    items[5] (key) and items[6:] (args), exactly `[.., key, *rest]`."""
-    return (None, None, None, None, None, *entry.args)
+    items[5] (key) and items[6:] (args), exactly `[.., key, *rest]` —
+    the entry's `vals`, plain bytes where the log kept them so (the
+    encoders take a raw-scanned frame's arguments the same way)."""
+    return (None, None, None, None, None, *entry.vals)
 
 
 def build_wire_batch(entries: list, origin: int) -> Optional[bytes]:
@@ -220,7 +222,7 @@ def build_wire_batch(entries: list, origin: int) -> Optional[bytes]:
     buf: dict[bytes, list] = {}
     try:
         for e in entries:
-            key = as_bytes(e.args[0])
+            key = as_bytes(e.vals[0])
             recs = buf.get(e.name)
             if recs is None:
                 recs = buf[e.name] = []

@@ -617,6 +617,10 @@ class ServerApp:
                 self.node.cluster.my_gid, self.advertised_addr)
         if self._boot_restore is not None:
             await self._boot_restore()
+        # every boot path has landed its table by now (start_node's
+        # snapshot or op-log recovery, or the plane's restore hook just
+        # above): it leaves every later collection before a client comes
+        await self.freeze_heap()
         if self._gather is not None or self.serve_plane is not None:
             from ..utils.native_tables import load_ext
             ext = load_ext()
@@ -635,6 +639,15 @@ class ServerApp:
             self.ensure_link(m)
         log.info("node %d listening on %s", self.node.node_id,
                  self.advertised_addr)
+
+    async def freeze_heap(self) -> None:
+        """Freeze the heap of every process that holds this node's table,
+        once a boot restore or a full sync has landed it
+        (utils/stagetime.py StageClock.freeze_heap): the serve plane's
+        workers, then this one."""
+        if self.serve_plane is not None:
+            await self.serve_plane.pool.call_all("freeze")
+        self.node.freeze_heap()
 
     async def close(self) -> None:
         self._closing = True
@@ -1367,9 +1380,7 @@ async def start_node(node: Node, **kwargs) -> ServerApp:
             # whatever partial state landed (fresh keyspace + resident
             # mirrors) so the quarantined boot is really empty, not a
             # silent partial restore a peer would then merge against
-            if hasattr(node.engine, "discard_resident"):
-                node.engine.discard_resident()
-            node.ks = node._make_keyspace()
+            node.replace_keyspace()
             _quarantine_snapshot(node, app.snapshot_path, e)
         else:
             if meta.node_id and not node.node_id:

@@ -9,6 +9,8 @@ IO concurrency lives in server/io.py, bulk merge compute in engine/.
 
 from __future__ import annotations
 
+import gc
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
@@ -269,6 +271,9 @@ class Node:
         # that also writes trace spans, any other engine gets counters
         self.stages = getattr(self.engine, "stages", None) or StageClock()
         self.ks = self._make_keyspace()
+        # a weak reference to the keyspace replace_keyspace last dropped
+        # (freeze_heap skips its freeze while that one is still alive)
+        self._dropped_ks = None
         self.stats = NodeStats()
         # undoable local counter ops (CNTUNDO — server/commands.py)
         self.undo = CounterUndoLog()
@@ -336,6 +341,35 @@ class Node:
         ks.on_key_delete = lambda: self.events.trigger(EVENT_DELETED)
         ks.stage = self.stages.stage
         return ks
+
+    def replace_keyspace(self) -> KeySpace:
+        """Drop this node's keyspace for a fresh one: a state-clearing
+        resync, or a boot restore that failed part-way.  The one place
+        that knows what a dropped table needs: its resident mirrors and
+        cached replies go, the gather's coalescer lets go of the object,
+        and the heap is thawed first — a frozen keyspace is never freed
+        (its blob planes point back at it: a cycle).  The freeze comes
+        again once the new state has landed (`freeze_heap`)."""
+        if hasattr(self.engine, "discard_resident"):
+            self.engine.discard_resident()
+        self.read_cache.clear()
+        self._dropped_ks = weakref.ref(self.ks)
+        gc.unfreeze()
+        self.ks = self._make_keyspace()
+        gather = getattr(self.app, "_gather", None)
+        if gather is not None:
+            gather.coal.reset_caches()
+        return self.ks
+
+    def freeze_heap(self) -> int:
+        """Freeze the heap once this node's table has landed
+        (utils/stagetime.py StageClock.freeze_heap), with the table's
+        per-key element row lists built first: the first read would
+        build them, one `list` a key, after the freeze.  Skipped while
+        the keyspace last dropped is still alive.
+        -> `gc.get_freeze_count()`."""
+        self.ks._sync_el_lists()
+        return self.stages.freeze_heap(self._dropped_ks)
 
     # ------------------------------------------------------------ execution
 
@@ -545,20 +579,16 @@ class Node:
         the zeroed watermark past ops the wipe discarded — the epoch bump
         makes links drop such stale-stream beacons (replica/link.py).
         `keep_link` (the link delivering the reset snapshot) stays up."""
-        engine = self.engine
-        if hasattr(engine, "discard_resident"):
-            engine.discard_resident()
-        # every cached reply describes wiped state (and its stamps hold
-        # kids of the discarded keyspace object)
-        self.read_cache.clear()
-        # ... and so does every tracked client's near-cache: flush-all
-        # push before the wipe is visible (server/tracking.py)
+        # every tracked client's near-cache describes wiped state:
+        # flush-all push before the wipe is visible (server/tracking.py)
         tr = self.tracking
         if tr is not None and tr.active:
             tr.flush_all()
         cap = self.repl_log.cap
         fence = max(self.repl_log.last_uuid, self.hlc.current)
-        self.ks = self._make_keyspace()
+        # the full sync that follows freezes the heap again once the new
+        # state has landed (replica/link.py _receive_snapshot)
+        self.replace_keyspace()
         self.repl_log = ReplLog(cap)
         # Fence the fresh (empty) log at the pre-wipe watermark: a peer
         # resuming below it must get a FULL snapshot of the post-reset

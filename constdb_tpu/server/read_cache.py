@@ -39,6 +39,11 @@ Two mechanisms keep a cached reply exact, and both must hold
 GC and element-table compaction never invalidate: they preserve visible
 state by construction, and entries hold finished bytes, not row ids.
 
+Entries and the by-key index hold atoms only — tuples of ints and bytes,
+which the collector stops tracking at the first collection that sees
+them — so a warm cache adds nothing to what a later collection walks
+(docs/INVARIANTS.md, "The frozen heap").
+
 Bounded: LRU over payload bytes (`CONSTDB_READ_CACHE_MB`; 0 disables),
 a single entry never exceeds 1/8 of the cap, and the resident bytes are
 a `used_memory` source for the overload governor, whose hard-watermark
@@ -58,6 +63,12 @@ _I64 = np.int64
 # per-entry bookkeeping overhead charged on top of the payload bytes
 # (dict slots, the stamp tuple, the by-key index entry)
 _ENTRY_OVERHEAD = 200
+
+# a key's index stays a tuple up to this many entries (a tuple of atoms
+# leaves the collector's lists at its first collection; a set never
+# does), and turns into a set past it, where a tuple's copy per add or
+# drop would cost more than the set's walk
+_INDEX_TUPLE_MAX = 16
 
 
 def _noop(*_a) -> None:
@@ -83,9 +94,11 @@ class ReadReplyCache:
         self.hits = 0
         self.misses = 0
         self.invalidations = 0
-        # (name, key, extra) -> [kid, ct, mt, dt, payload]
-        self._map: OrderedDict[tuple, list] = OrderedDict()
-        self._by_key: dict[bytes, set] = {}
+        # (name, key, extra) -> (kid, ct, mt, dt, payload)
+        self._map: OrderedDict[tuple, tuple] = OrderedDict()
+        # key -> its entries' (name, key, extra): a tuple while small, a
+        # set past _INDEX_TUPLE_MAX (module docstring: atoms only)
+        self._by_key: dict[bytes, tuple | set] = {}
 
     def configure(self, cap_bytes: int) -> None:
         self.cap_bytes = max(0, cap_bytes)
@@ -225,25 +238,33 @@ class ReadReplyCache:
             # expiry arming must always drop.
             if name in _MEMBER_SCOPED:
                 if env is not None:
-                    ent = [kid, -1, -1, env[1], payload]
+                    ent = (kid, -1, -1, env[1], payload)
                 elif int(keys.expire[kid]) != 0:
                     return  # time-dependent visibility — never cached
                 else:
-                    ent = [kid, -1, -1, int(keys.dt[kid]), payload]
+                    ent = (kid, -1, -1, int(keys.dt[kid]), payload)
             elif env is not None:
-                ent = [kid, env[0], int(keys.mt[kid]), env[1], payload]
+                ent = (kid, env[0], int(keys.mt[kid]), env[1], payload)
             else:
                 if int(keys.expire[kid]) != 0:
                     return  # time-dependent visibility — never cached
-                ent = [kid, int(keys.ct[kid]), int(keys.mt[kid]),
-                       int(keys.dt[kid]), payload]
+                ent = (kid, int(keys.ct[kid]), int(keys.mt[kid]),
+                       int(keys.dt[kid]), payload)
         else:
-            ent = [-1, 0, 0, 0, payload]
+            ent = (-1, 0, 0, 0, payload)
         k = (name, key, extra)
         if k in self._map:
             self._drop(k)
         self._map[k] = ent
-        self._by_key.setdefault(key, set()).add(k)
+        idx = self._by_key.get(key)
+        if idx is None:
+            self._by_key[key] = (k,)
+        elif type(idx) is set:
+            idx.add(k)
+        elif len(idx) < _INDEX_TUPLE_MAX:
+            self._by_key[key] = idx + (k,)
+        else:
+            self._by_key[key] = {*idx, k}
         self.bytes += len(payload) + _ENTRY_OVERHEAD
         self._shrink()
 
@@ -284,9 +305,7 @@ class ReadReplyCache:
             ent = m.pop(k, None)
             if ent is not None:
                 self.bytes -= len(ent[4]) + _ENTRY_OVERHEAD
-            ks.discard(k)
-        if not ks:
-            del self._by_key[key]
+        self._unindex(key, dead)
 
     def invalidate_keys(self, keys) -> None:
         """Bulk intake (a merged ColumnarBatch's key lists).  When the
@@ -327,11 +346,21 @@ class ReadReplyCache:
         if ent is None:
             return
         self.bytes -= len(ent[4]) + _ENTRY_OVERHEAD
-        s = self._by_key.get(k[1])
-        if s is not None:
-            s.discard(k)
-            if not s:
-                del self._by_key[k[1]]
+        self._unindex(k[1], (k,))
+
+    def _unindex(self, key: bytes, gone) -> None:
+        """Take the entries `gone` out of `key`'s index."""
+        idx = self._by_key.get(key)
+        if idx is None:
+            return
+        if type(idx) is set:
+            idx.difference_update(gone)
+        else:
+            idx = tuple([k for k in idx if k not in gone])
+        if idx:
+            self._by_key[key] = idx
+        else:
+            del self._by_key[key]
 
     def _shrink(self) -> None:
         while self.bytes > self.cap_bytes and self._map:

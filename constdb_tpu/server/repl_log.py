@@ -23,14 +23,59 @@ from ..resp.message import Arr, Bulk, Msg, msg_size
 
 
 class ReplEntry:
-    __slots__ = ("uuid", "prev_uuid", "name", "args", "size")
+    """One logged write as the log's readers see it (`next_after`,
+    `run_after`, `at`, `entries`): made on each read from the ring's
+    record, never kept by the ring.
 
-    def __init__(self, uuid: int, prev_uuid: int, name: bytes, args: list, size: int):
+    `vals` is what the ring keeps of the arguments: their bytes, as a
+    tuple, when every argument is a Bulk (a write from a client always
+    is); the pushed list of messages otherwise.  `args` gives them back
+    as messages.  The wire encoders read `vals` itself: they take
+    plain-bytes arguments (server/commands.py `columnar`)."""
+    __slots__ = ("uuid", "prev_uuid", "name", "vals", "size")
+
+    def __init__(self, uuid: int, prev_uuid: int, name: bytes, vals,
+                 size: int):
         self.uuid = uuid
         self.prev_uuid = prev_uuid
         self.name = name
-        self.args = args
+        self.vals = vals
         self.size = size
+
+    @property
+    def args(self) -> list:
+        v = self.vals
+        return [Bulk(x) for x in v] if type(v) is tuple else v
+
+
+def _record(uuid: int, prev: int, name: bytes, args: list) -> tuple:
+    """The ring's record of one write: `(uuid, prev_uuid, name, size,
+    *vals)` where every argument is a Bulk — one flat tuple of atoms,
+    which the collector stops tracking at the first collection that sees
+    it, so the ring (seconds of the newest writes) is never walked by a
+    later collection and never promotes anything toward a full one
+    (docs/INVARIANTS.md, "The frozen heap") — else `(uuid, prev_uuid,
+    name, size, args)` with the pushed list."""
+    size = len(name)
+    bulk = True
+    for a in args:
+        # Bulk is ~every argument; dodge the getattr probe
+        if type(a) is Bulk:
+            size += len(a.val)
+        else:
+            bulk = False
+            v = getattr(a, "val", None)
+            size += len(v) if type(v) is bytes else msg_size(a)
+    if bulk:
+        return (uuid, prev, name, size, *[a.val for a in args])
+    return (uuid, prev, name, size, args)
+
+
+def _entry(rec: tuple) -> ReplEntry:
+    vals = rec[4:]
+    if len(vals) == 1 and type(vals[0]) is list:
+        vals = vals[0]
+    return ReplEntry(rec[0], rec[1], rec[2], vals, rec[3])
 
 
 class ReplLog:
@@ -39,7 +84,7 @@ class ReplLog:
 
     def __init__(self, cap_bytes: int = DEFAULT_CAP):
         self.cap = cap_bytes
-        self._entries: deque[ReplEntry] = deque()
+        self._entries: deque[tuple] = deque()   # _record()s
         self._uuids: deque[int] = deque()  # parallel, for bisect
         self._bytes = 0
         self.evicted_up_to = 0  # uuid of the newest evicted entry (0 = none)
@@ -75,13 +120,9 @@ class ReplLog:
     def push(self, uuid: int, name: bytes, args: list) -> None:
         if uuid <= self.last_uuid:
             raise ValueError(f"repl_log uuids must be increasing: {uuid} <= {self.last_uuid}")
-        # args are almost always Bulk; avoid the recursive msg_size call on
-        # the op hot path
-        size = len(name)
-        for a in args:
-            v = getattr(a, "val", None)
-            size += len(v) if type(v) is bytes else msg_size(a)
-        self._entries.append(ReplEntry(uuid, self.last_uuid, name, args, size))
+        rec = _record(uuid, self.last_uuid, name, args)
+        size = rec[3]
+        self._entries.append(rec)
         self._uuids.append(uuid)
         self._bytes += size
         self.last_uuid = uuid
@@ -90,8 +131,8 @@ class ReplLog:
         while self._bytes > self.cap and len(self._entries) > 1:
             ev = self._entries.popleft()
             self._uuids.popleft()
-            self._bytes -= ev.size
-            self.evicted_up_to = ev.uuid
+            self._bytes -= ev[3]
+            self.evicted_up_to = ev[0]
 
     def push_many(self, cmds: list) -> None:
         """Append a planned run of `(uuid, name, args)` tuples in one pass
@@ -110,15 +151,9 @@ class ReplLog:
             if uuid <= prev:
                 raise ValueError(
                     f"repl_log uuids must be increasing: {uuid} <= {prev}")
-            size = len(name)
-            for a in args:
-                # Bulk is ~every argument; dodge the getattr probe
-                if type(a) is Bulk:
-                    size += len(a.val)
-                else:
-                    v = getattr(a, "val", None)
-                    size += len(v) if type(v) is bytes else msg_size(a)
-            entries.append(ReplEntry(uuid, prev, name, args, size))
+            rec = _record(uuid, prev, name, args)
+            size = rec[3]
+            entries.append(rec)
             uuids.append(uuid)
             added += size
             prev = uuid
@@ -130,8 +165,8 @@ class ReplLog:
         while self._bytes > self.cap and len(entries) > 1:
             ev = entries.popleft()
             uuids.popleft()
-            self._bytes -= ev.size
-            self.evicted_up_to = ev.uuid
+            self._bytes -= ev[3]
+            self.evicted_up_to = ev[0]
 
     def can_resume_from(self, uuid: int) -> bool:
         """Is an incremental stream starting after `uuid` gap-free?
@@ -144,12 +179,12 @@ class ReplLog:
         i = bisect_right(self._uuids, uuid)
         if i >= len(self._entries):
             return None
-        e = self._entries[i]
+        rec = self._entries[i]
         if self.floor is not None:
             f = self.floor()
-            if f is not None and e.uuid >= f:
+            if f is not None and rec[0] >= f:
                 return None
-        return e
+        return _entry(rec)
 
     def run_after(self, uuid: int, max_n: int,
                   max_bytes: Optional[int] = None) -> list:
@@ -176,7 +211,7 @@ class ReplLog:
         entries.rotate(-i)
         # cap at n - i: the rotation parks the first i entries at the
         # BACK, and an uncapped islice would wrap onto them
-        run = list(islice(entries, 0, min(max_n, n - i)))
+        run = [_entry(rec) for rec in islice(entries, 0, min(max_n, n - i))]
         entries.rotate(i)
         if self.floor is not None:
             f = self.floor()
@@ -198,8 +233,12 @@ class ReplLog:
         """Exact-uuid lookup (REPLLOG AT — reference server.rs:318-350)."""
         i = bisect_left(self._uuids, uuid)
         if i < len(self._uuids) and self._uuids[i] == uuid:
-            return self._entries[i]
+            return _entry(self._entries[i])
         return None
+
+    def entries(self) -> list[ReplEntry]:
+        """Every entry the ring holds, oldest first."""
+        return [_entry(rec) for rec in self._entries]
 
     def uuids(self) -> list[int]:
         return list(self._uuids)

@@ -317,7 +317,7 @@ class ServeCoalescer:
         """run_chunk's body, under its `plan` stage (whose self time
         excludes the read batches, per-command executions and flushes
         nested in it)."""
-        self._reset_caches()
+        self.reset_caches()
         if len(msgs) == 1:
             # lone command: the exact per-command path, zero overhead
             # (no invalidation needed — the next chunk resets anyway)
@@ -464,7 +464,7 @@ class ServeCoalescer:
                            out: bytearray, spans: list = None,
                            solo: bytes = None) -> None:
         """run_native_chunk's body, under its `plan` stage."""
-        self._reset_caches()
+        self.reset_caches()
         n = len(ops)
         if n == 1:
             # lone command: the exact per-command path, zero overhead
@@ -890,7 +890,7 @@ class ServeCoalescer:
                     if m not in d:
                         d[m] = a
 
-    def _reset_caches(self) -> None:
+    def reset_caches(self) -> None:
         self._keys.clear()
         self.regs.clear()
         self.cnts.clear()
@@ -1371,7 +1371,7 @@ class ServeCoalescer:
             # control commands take subcommands, not keys (NODE ID even
             # changes the identity the counter overlays are tracked
             # under) — drop everything rather than mis-scope
-            self._reset_caches()
+            self.reset_caches()
             return
         if cmd.flags & CMD_READONLY or not cmd.families:
             return
@@ -1384,7 +1384,7 @@ class ServeCoalescer:
             self.tns.pop(key, None)
             self.lists.pop(key, None)
             return
-        self._reset_caches()
+        self.reset_caches()
 
     # ------------------------------------------------------ planner surface
 

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import heapq
 import threading
+from array import array
 import zlib
 from contextlib import nullcontext
 from typing import Iterator, Optional
@@ -83,12 +84,15 @@ class RowJournal:
     A journal is born whole — no mirror yet, nothing to keep rows for —
     so a store no device engine ever mirrors pays one flag test a write.
     `epoch` counts resets: an engine whose mirror was synced at another
-    epoch (a second engine on the same store) must not trust the rows."""
+    epoch (a second engine on the same store) must not trust the rows.
+    `rows` is an `array('q')`: up to 2 x JOURNAL_MAX_ROWS of them live
+    for a whole flush interval, and a collection walks a `list` of them
+    entry by entry; an array it does not walk at all."""
 
     __slots__ = ("rows", "whole", "over", "epoch")
 
     def __init__(self) -> None:
-        self.rows: list[int] = []
+        self.rows = array("q")
         self.whole = True
         self.over = False
         self.epoch = 0
@@ -101,22 +105,22 @@ class RowJournal:
 
     def add_rows(self, rows: np.ndarray) -> None:
         if not self.whole and len(rows):
-            self.rows.extend(rows.tolist())
+            self.rows.frombytes(rows.astype(_I64, copy=False).tobytes())
             if len(self.rows) > 2 * JOURNAL_MAX_ROWS:
                 self._dedupe()
 
     def _dedupe(self) -> np.ndarray:
-        uniq = np.unique(np.asarray(self.rows, dtype=_I64))
+        uniq = np.unique(np.frombuffer(self.rows, dtype=_I64))
         if len(uniq) > JOURNAL_MAX_ROWS:
             self.mark_whole(over=True)
         else:
-            self.rows = uniq.tolist()
+            self.rows = array("q", uniq.tobytes())
         return uniq
 
     def mark_whole(self, over: bool = False) -> None:
         self.whole = True
         self.over = self.over or over
-        self.rows = []
+        self.rows = array("q")
 
     def take(self) -> Optional[np.ndarray]:
         """Sorted distinct rows, or None when the journal is whole."""
@@ -128,7 +132,7 @@ class RowJournal:
     def reset(self) -> int:
         """The mirror equals the host again (just built or patched):
         start over, row-scoped.  -> the new epoch."""
-        self.rows = []
+        self.rows = array("q")
         self.whole = self.over = False
         self.epoch += 1
         return self.epoch
@@ -147,12 +151,11 @@ class BlobList(list):
     stays exact through EVERY mutation path — the op-path setters, the
     engines' winner-assignment loops, and the flush path's slice writes
     — without instrumenting each call site (there are a dozen across
-    engine/hostbatch.py and engine/tpu.py alone, all hot).  Two escape
-    hatches exist, both fenced: rebinding the attribute to a plain list
-    (only `_compact_elements` does it, adjusting the gauge itself), and
-    the list mutators no blob plane uses — those raise loudly below
-    instead of silently drifting the gauge, so a future call site must
-    add its accounting here first.
+    engine/hostbatch.py and engine/tpu.py alone, all hot).  A plane is
+    never rebound (`_compact_elements` rewrites it in place); the list
+    mutators no blob plane uses raise loudly below instead of silently
+    drifting the gauge, so a future call site must add its accounting
+    here first.
 
     Pickles as a plain list (shard workers ship copies of these in
     `keyspace_state_bytes`; the receiving side owns no gauge)."""
@@ -1446,27 +1449,36 @@ class KeySpace:
                             add_t=self.el.add_t[live],
                             add_node=self.el.add_node[live],
                             del_t=self.el.del_t[live])
-        # rebinding the blob planes bypasses BlobList accounting: retire
-        # the old lists' bytes, and the fresh BlobLists re-add their own
-        # (net zero — gc() already nulled every dead row's blobs)
-        self.blob_bytes -= sum(map(_blen, self.el_member)) + \
-            sum(map(_blen, self.el_val))
-        members = BlobList(self, (self.el_member[r] for r in live.tolist()))
-        self.el_val = BlobList(self, (self.el_val[r] for r in live.tolist()))
-        self.el_member = members
+        # the planes and the per-key row lists are rewritten in place,
+        # never rebound: a heap frozen once the table landed
+        # (utils/stagetime.py StageClock.freeze_heap) keeps them frozen,
+        # so no later collection walks their entries.  The BlobList slice
+        # write keeps the gauge (net zero: gc() nulled every dead row's
+        # blobs)
+        live_rows = live.tolist()
+        members = [self.el_member[r] for r in live_rows]
+        self.el_val[:] = [self.el_val[r] for r in live_rows]
+        self.el_member[:] = members
         self.el = new_el
         self.el_dead = 0
         # rebuild combo index + per-kid lists with the new row ids
         self.el_index = I64Dict(max(len(live), 16))
-        by_kid: dict[int, list[int]] = {}
+        by_kid = self.el_rows_by_kid
+        for rows in by_kid.values():
+            rows.clear()
         kids = new_el.kid[: new_el.n].tolist()
         if members:
             mids, _ = self.member_index.get_or_insert_batch(members)
             combos = (np.asarray(kids, dtype=_I64) << self.MEMBER_BITS) | mids
             self.el_index.put_batch(combos, np.arange(len(live), dtype=_I64))
         for row, kid in enumerate(kids):
-            by_kid.setdefault(kid, []).append(row)
-        self.el_rows_by_kid = by_kid
+            rows = by_kid.get(kid)
+            if rows is None:
+                by_kid[kid] = [row]
+            else:
+                rows.append(row)
+        for kid in [k for k, rows in by_kid.items() if not rows]:
+            del by_kid[kid]
         self._el_synced = new_el.n
 
     # ------------------------------------------------------------ inspection

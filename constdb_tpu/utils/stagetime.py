@@ -29,8 +29,18 @@ taken through it:
   `gc` is entered from `gc.callbacks` on whichever thread collects,
   nested under the stage it interrupted, tagged with the generation.
   `loop_stats()` reads, at INFO time and from any thread, the loop
-  thread's CPU time and context switches, and the collections by
-  generation.
+  thread's CPU time and context switches, the collections by
+  generation, and the heap's frozen share.
+* **The frozen heap.**  `freeze_heap()` collects once, then moves every
+  object alive to CPython's permanent generation (`gc.freeze()`), which
+  no later collection walks.  The node calls it once a table it will
+  keep has landed — the boot restore, a full sync (server/node.py
+  Node.freeze_heap) — so a full collection walks what was made since,
+  not the restored table's blob planes and per-key row lists (a `list`
+  entry per row).  A frozen cycle is never freed: the node thaws the
+  heap before it drops a keyspace (Node.replace_keyspace), and a freeze
+  is skipped while the dropped one is still alive (docs/INVARIANTS.md,
+  "The frozen heap").
 * **The device trace's clock.**  While a profiler trace runs (the enabled
   check the engine hands in with `jax.profiler.TraceAnnotation`), every
   stage also opens an annotation `cst.<name>[.<tag>]` in the `/host:` plane
@@ -181,6 +191,14 @@ class StageClock:
         self.poll_events = 0
         self._loop_last = (0, 0, 0)  # loop_stats' last reading
         self._gc_open = None
+        # seconds in generation-2 collections (INFO gc_full_us): the
+        # part of `gc` a frozen heap shortens, told from the young ones
+        self._gc_full_s = [0.0]
+        # freeze_heap: freezes made, freezes skipped (the dropped
+        # keyspace still alive), seconds spent (collect + freeze)
+        self.freezes = 0
+        self.freeze_skips = 0
+        self.freeze_s = 0.0
         self._own_thread()           # the loop's until one attaches
 
     def _new_thread(self) -> _Thread:
@@ -224,20 +242,40 @@ class StageClock:
     def _gc_hook(self, phase: str, info: dict) -> None:
         if phase == "start":
             gen = info["generation"]
-            st = _Stage(self, "gc", _GEN[gen], span=gen == 2)
+            st = _Stage(self, "gc", _GEN[gen], span=gen == 2,
+                        total=(self._gc_full_s, 0) if gen == 2 else None)
             st.__enter__()
             self._gc_open = st
         elif self._gc_open is not None:
             st, self._gc_open = self._gc_open, None
             st.__exit__(None, None, None)
 
+    def freeze_heap(self, dropped=None) -> int:
+        """Collect, then freeze every object alive (the module docstring:
+        the frozen heap) — unless `dropped`, a weak reference to the
+        keyspace the node last dropped, is still alive after the
+        collection: something holds the old table, which a freeze would
+        keep for good, so the freeze is skipped and counted.
+        -> `gc.get_freeze_count()` after it."""
+        t0 = perf_counter_ns()
+        gc.collect()
+        if dropped is not None and dropped() is not None:
+            self.freeze_skips += 1
+        else:
+            gc.freeze()
+            self.freezes += 1
+        self.freeze_s += (perf_counter_ns() - t0) * 1e-9
+        return gc.get_freeze_count()
+
     def loop_stats(self) -> list:
         """[(INFO field, value)]: the loop thread's CPU time in whole
         microseconds, its voluntary and involuntary context switches
         (`/proc/self/task/<tid>/status`), and the collections of each
-        generation (`gc.get_stats()`).  Where a clock or the file cannot
-        be read (not Linux, or the thread is gone) the last reading stays,
-        0 from boot."""
+        generation (`gc.get_stats()`) and the time in generation-2 ones,
+        the objects frozen now, and this clock's `freeze_heap` freezes,
+        skips and time.  Where a clock or the file cannot be
+        read (not Linux, or the thread is gone) the last reading stays, 0
+        from boot."""
         cpu_id, tid = self._loop
         cpu_us, nv, niv = self._loop_last
         try:
@@ -257,6 +295,11 @@ class StageClock:
                ("loop_nivcsw", niv)]
         out += [(f"gc_collections_gen{g}", st["collections"])
                 for g, st in enumerate(gc.get_stats())]
+        out += [("gc_full_us", int(self._gc_full_s[0] * 1e6)),
+                ("gc_frozen_objects", gc.get_freeze_count()),
+                ("gc_freezes", self.freezes),
+                ("gc_freeze_skips", self.freeze_skips),
+                ("gc_freeze_us", int(self.freeze_s * 1e6))]
         return out
 
 

@@ -8,8 +8,11 @@ hardware.  Set CONSTDB_TEST_TPU=1 to run against the real chip instead
 (through the chip tool: one pytest process holds the chip).
 """
 
+import gc
 import os
 import sys
+
+import pytest
 
 if not os.environ.get("CONSTDB_TEST_TPU"):
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -77,3 +80,13 @@ def cpu_mesh_subprocess_env() -> dict:
     env = dict(os.environ)
     env.update(CPU_MESH_ENV)
     return env
+
+
+@pytest.fixture(autouse=True)
+def _thawed_heap():
+    """A node freezes its process's heap once its table has landed
+    (utils/stagetime.py StageClock.freeze_heap).  One test process hosts
+    many nodes, so every test leaves the heap thawed: what it dropped is
+    collected, and no later test inherits its frozen objects."""
+    yield
+    gc.unfreeze()

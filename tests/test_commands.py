@@ -35,7 +35,7 @@ def run(n, *parts):
 
 def replay(src: Node, dst: Node):
     """Feed src's repl_log to dst the way a Puller would."""
-    for e in list(src.repl_log._entries):
+    for e in list(src.repl_log.entries()):
         dst.apply_replicated(e.name, e.args, src.node_id, e.uuid)
 
 
@@ -113,7 +113,7 @@ def test_del_bytes_tombstones_and_rewrites():
     run(n, "set", "k", "v")
     assert run(n, "del", "k") == Int(1)
     assert run(n, "get", "k") == NIL
-    names = [e.name for e in n.repl_log._entries]
+    names = [e.name for e in n.repl_log.entries()]
     assert names == [b"set", b"delbytes"]
 
 
@@ -123,7 +123,7 @@ def test_del_counter_tombstones_and_rewrites():
     run(n, "incr", "c")
     assert run(n, "del", "c") == Int(1)
     assert run(n, "get", "c") == NIL
-    e = [e for e in n.repl_log._entries if e.name == b"delcnt"]
+    e = [e for e in n.repl_log.entries() if e.name == b"delcnt"]
     assert len(e) == 1 and e[0].args[0].val == b"c"
     # resurrect: a later incr counts from 0 (dt gated out the old slots)
     assert run(n, "incr", "c") == Int(1)
@@ -250,7 +250,7 @@ def test_spop_replicates_deterministic_srem():
     ra = sorted(m.val for m in run(a, "smembers", "s").items)
     rb = sorted(m.val for m in run(b, "smembers", "s").items)
     assert ra == rb and len(ra) == 2 and popped.val not in ra
-    names = [e.name for e in a.repl_log._entries]
+    names = [e.name for e in a.repl_log.entries()]
     assert b"spop" not in names and names.count(b"srem") == 1
 
 
@@ -297,7 +297,7 @@ def test_expiry_replicates_absolute_deadline():
     n = mknode()
     run(n, "set", "k", "v")
     run(n, "expire", "k", 10)
-    names = [e.name for e in n.repl_log._entries]
+    names = [e.name for e in n.repl_log.entries()]
     assert names == [b"set", b"expireat"]
 
 

@@ -190,7 +190,7 @@ def test_index_equals_sorted_rows_through_every_writer(engine, seed):
 
 def _log(node: Node) -> list:
     return [(e.uuid, e.name, tuple((type(a).__name__, a.val) for a in e.args))
-            for e in node.repl_log._entries]
+            for e in node.repl_log.entries()]
 
 
 def _chunk(rng, step: int) -> list:

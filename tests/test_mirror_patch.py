@@ -76,7 +76,7 @@ def assert_mirror_is_host(eng, ks, fam: str) -> None:
                                       err_msg=f"{fam}.{c}")
         assert (got[c][n:] == fill).all(), f"{fam}.{c} padding"
     j = ks.journal[fam]
-    assert not j.whole and j.rows == []
+    assert not j.whole and len(j.rows) == 0
 
 
 def repair_and_check(node) -> None:
@@ -115,7 +115,7 @@ def test_journal_is_born_whole_and_a_cpu_node_never_journals():
     j = RowJournal()
     j.add(3)
     j.add_rows(np.array([4, 5]))
-    assert j.whole and j.rows == [] and j.take() is None
+    assert j.whole and len(j.rows) == 0 and j.take() is None
     node = Node(node_id=1)
     for i in range(5):
         node.execute(req(b"hset", b"h", b"f%d" % i, b"v"))
@@ -133,8 +133,8 @@ def test_journal_dedupes_sorts_and_counts_resets():
     j.add_rows(np.array([5, 7, 2], dtype=np.int64))
     j.add_rows(np.array([], dtype=np.int64))
     assert j.take().tolist() == [2, 5, 7, 9]
-    assert j.rows == [2, 5, 7, 9]          # taking does not clear
-    assert j.reset() == 2 and j.rows == [] and j.take().tolist() == []
+    assert list(j.rows) == [2, 5, 7, 9]        # taking does not clear
+    assert j.reset() == 2 and j.rows.tolist() == [] and j.take().tolist() == []
 
 
 @pytest.mark.parametrize("distinct, whole", [(JOURNAL_MAX_ROWS, False),
@@ -332,7 +332,7 @@ def test_a_version_that_moved_with_no_row_written_patches_nothing():
     # a replicated add OLDER than the one that landed: it loses the LWW,
     # writes no column, and still bumps the plane's version
     node.apply_replicated(b"sadd", req(b"warm", b"x"), 7, u(10))
-    assert node.ks.journal["el"].rows == []
+    assert node.ks.journal["el"].rows.tolist() == []
     assert eng._res["el"]["ver"] != node.ks.fam_ver["el"]
     h2d = eng.bytes_h2d
     repair_and_check(node)
@@ -446,11 +446,12 @@ def test_bulk_ingest_and_flush_leave_the_journal_empty():
     node.merge_batch(batch_from_keyspace(src.ks))
     assert set(JOURNAL_FAMILIES) <= set(eng._res)
     for fam in JOURNAL_FAMILIES:                 # built: row-scoped, empty
-        assert not ks.journal[fam].whole and ks.journal[fam].rows == []
+        j = ks.journal[fam]
+        assert not j.whole and j.rows.tolist() == []
     assert eng.needs_flush
     node.ensure_flushed()
     for fam in JOURNAL_FAMILIES:
-        assert ks.journal[fam].rows == []
+        assert ks.journal[fam].rows.tolist() == []
         assert_mirror_is_host(eng, ks, fam)
     # a second ingest over resident planes: merged on the device, flushed
     # down — still nothing journaled, nothing patched or rebuilt
@@ -460,7 +461,7 @@ def test_bulk_ingest_and_flush_leave_the_journal_empty():
     node.merge_batch(batch_from_keyspace(src.ks))
     node.ensure_flushed()
     for fam in JOURNAL_FAMILIES:
-        assert ks.journal[fam].rows == []
+        assert ks.journal[fam].rows.tolist() == []
         assert_mirror_is_host(eng, ks, fam)
     assert sum(eng.mirror_patches.values()) == 0
     assert sum(eng.mirror_rebuilds.values()) == 0

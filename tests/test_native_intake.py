@@ -104,7 +104,7 @@ def mixed_chunks(seed: int, rounds: int = 30):
 def logview(node):
     return [(e.uuid, e.prev_uuid, e.name, e.size,
              tuple((type(a).__name__, a.val) for a in e.args))
-            for e in node.repl_log._entries]
+            for e in node.repl_log.entries()]
 
 
 # ------------------------------------------------------------ the scanner

@@ -143,7 +143,7 @@ async def drive_node(tmp_path, serve_batch, work, serve_shards=1):
             canonical = node.canonical()
             repl = [(e.uuid, e.prev_uuid, e.name, e.size,
                      tuple((type(a).__name__, a.val) for a in e.args))
-                    for e in node.repl_log._entries]
+                    for e in node.repl_log.entries()]
         return [bytes(r) for r in raw], canonical, repl, node
     finally:
         for c in conns:

@@ -158,7 +158,7 @@ async def drive_node(tmp_path, serve_batch, work, engine=None):
         canonical = node.canonical()
         repl = [(e.uuid, e.prev_uuid, e.name, e.size,
                  tuple((type(a).__name__, a.val) for a in e.args))
-                for e in node.repl_log._entries]
+                for e in node.repl_log.entries()]
         return [bytes(r) for r in raw], canonical, repl, node.stats
     finally:
         for c in conns:
@@ -332,7 +332,7 @@ def test_push_many_equals_loop():
     def entries(log):
         return [(e.uuid, e.prev_uuid, e.name, e.size,
                  tuple(a.val for a in e.args))
-                for e in log._entries]
+                for e in log.entries()]
 
     cmds = [(u(i), b"set" if i % 3 else b"cntset",
              [Bulk(b"k%d" % (i % 5)), Bulk(b"v" * (i % 23)) if i % 3
@@ -442,7 +442,7 @@ def test_replies_flush_before_sync_upgrade(tmp_path, serve_batch):
             # the writes really landed and were logged (a third entry is
             # the handshake's replicated MEET introduction)
             assert node.ks.lookup(b"k") >= 0
-            assert [e.name for e in node.repl_log._entries][:2] == \
+            assert [e.name for e in node.repl_log.entries()][:2] == \
                 [b"set", b"cntset"]
         finally:
             writer.close()

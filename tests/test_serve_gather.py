@@ -169,7 +169,7 @@ def shed_from_now_on(node: Node) -> None:
 def repl_entries(node: Node) -> list:
     return [(e.uuid, e.prev_uuid, e.name,
              tuple((type(a).__name__, a.val) for a in e.args))
-            for e in node.repl_log._entries]
+            for e in node.repl_log.entries()]
 
 
 async def drive_gathered(tmp_path, kind: str, work: list, shed_work: list):

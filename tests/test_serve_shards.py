@@ -55,10 +55,10 @@ def log_entries(node):
     single log's by design, so prev is not compared)."""
     log = node.repl_log
     if isinstance(log, MergedReplLog):
-        ents = sorted((e for s in log.segments for e in s._entries),
+        ents = sorted((e for s in log.segments for e in s.entries()),
                       key=lambda e: e.uuid)
     else:
-        ents = list(log._entries)
+        ents = log.entries()
     return [(e.uuid, e.name, e.size,
              tuple((type(a).__name__, a.val) for a in e.args))
             for e in ents]

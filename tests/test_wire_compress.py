@@ -43,6 +43,7 @@ from constdb_tpu.replica.manager import ReplicaMeta  # noqa: E402
 from constdb_tpu.resp.message import (Arr, Bulk, Int,  # noqa: E402
                                       as_bytes)
 from constdb_tpu.server.node import Node  # noqa: E402
+from constdb_tpu.server.repl_log import ReplEntry  # noqa: E402
 from constdb_tpu.store.keyspace import KeySpace  # noqa: E402
 from constdb_tpu.utils import compressio as zio  # noqa: E402
 
@@ -147,15 +148,10 @@ def _compressed_batch_frame(node):
     from constdb_tpu.replica import wire
     entries = []
 
-    class _E:
-        __slots__ = ("uuid", "prev_uuid", "name", "args")
-
     prev = 0
     for i in range(1, 9):
-        e = _E()
-        e.uuid, e.prev_uuid = u(i), prev
-        e.name = b"set"
-        e.args = [Bulk(b"k%d" % i), Bulk(b"v" * 64)]
+        e = ReplEntry(u(i), prev, b"set",
+                      [Bulk(b"k%d" % i), Bulk(b"v" * 64)], 0)
         prev = e.uuid
         entries.append(e)
     payload = wire.build_wire_batch(entries, 7)
